@@ -1,0 +1,462 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+One process drives the normal path once, through the entry points a user
+calls, at the full width of the one model this repo has run at width
+(the dim-2048 transformer LM of bench.py): train a few steps, score with
+the model ``fit`` returned, serve a classifier of the same trunk over
+HTTP, boost a HIGGS-shaped forest at 63 and 255 bins, and run a fused
+featurize -> booster pipeline. Weights are random from a seed, data is
+synthetic, nothing touches the network. Every leg is fatal: a failure
+propagates, the exit code is non-zero and no result line is printed.
+
+    python chip_smoke.py
+
+needs a TPU (there is no CPU mode; tests/test_chip_smoke.py runs the leg
+functions at tiny sizes instead). With several local devices the same
+process uses all of them: learner mesh data x fsdp, data-parallel GBDT,
+TPUModel over the default mesh. The last line of stdout is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}
+
+preceded by one JSON line with each leg's wall and facts. Speeds printed
+on the way are information, not thresholds.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import socket
+import sys
+import time
+import urllib.request
+
+import numpy as np
+
+# bench.py's LM_SPEC / HIGGS shape, restated so this file stands alone
+FULL = {
+    "on_chip": True,
+    "lm_spec": {"type": "transformer", "vocab_size": 32000, "dim": 2048,
+                "depth": 8, "heads": 16, "max_len": 1024,
+                "head_dtype": "bfloat16"},
+    "lm_batch": 8,
+    "lm_steps_per_epoch": 4,      # one epoch is one device dispatch
+    "lm_epochs": 2,               # the first compiles, the second is timed
+    "transform_rows": 4,
+    "serve_classes": 16,
+    "serve_requests": 6,
+    "gbdt_rows": 1_000_000,
+    "gbdt_valid_rows": 100_000,
+    "gbdt_features": 28,
+    "gbdt_leaves": 63,
+    "gbdt_iterations": 8,
+    "gbdt_max_bins": (63, 255),   # the direct kernel, the nibble kernel
+    "gbdt_hist_method": "auto",
+    "gbdt_min_auc": 0.75,
+    "pipeline_rows": 2000,
+}
+
+# a bf16 forward against the float32 reference, as relative L2 error of
+# the logits: 8 layers of bf16 matmuls measured 3e-3 on a v5e (PR 21)
+BF16_REL_TOL = 2e-2
+# a served class may differ from the reference's only where the
+# reference itself is that close to a tie
+BF16_TIE_MARGIN = 0.1
+
+
+def _log(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+# ---------------------------------------------------------------------------
+# leg 1: the device
+# ---------------------------------------------------------------------------
+
+
+def leg_device() -> dict:
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    _log(f"jax={jax.__version__} jaxlib={jaxlib.__version__} "
+         f"libtpu={libtpu} platform={device['platform']} "
+         f"device_kind={device['kind']!r} devices={device['count']}")
+    if device["platform"] != "tpu":
+        raise SystemExit(
+            f"chip_smoke needs a TPU; jax found platform "
+            f"{device['platform']!r}")
+    return device
+
+
+# ---------------------------------------------------------------------------
+# the float32 reference: same weights, dense attention, exact matmuls
+# ---------------------------------------------------------------------------
+
+
+def reference_forward(spec: dict, variables, tokens: np.ndarray
+                      ) -> np.ndarray:
+    """The plain jax.numpy path of the same network: float32 module,
+    ``dense_attention`` (FLASH_MIN_LEN is the documented switch that
+    keeps ``attention`` off the kernel), full-precision matmuls."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.networks import build_network
+    from mmlspark_tpu.parallel import ring_attention as ra
+
+    module = build_network({**spec, "dtype": "float32",
+                            "head_dtype": "float32"})
+    fn = jax.jit(lambda v, t: module.apply(v, t))
+    flash_min = ra.FLASH_MIN_LEN
+    ra.FLASH_MIN_LEN = 1 << 62
+    tokens = jnp.asarray(tokens, jnp.int32)
+    try:
+        with jax.default_matmul_precision("highest"):
+            lowered = fn.lower(variables, tokens)
+            assert "tpu_custom_call" not in lowered.as_text(), \
+                "the reference must not run the kernel it checks"
+            return np.asarray(lowered.compile()(variables, tokens))
+    finally:
+        ra.FLASH_MIN_LEN = flash_min
+
+
+# ---------------------------------------------------------------------------
+# leg 2: train
+# ---------------------------------------------------------------------------
+
+
+def leg_train(cfg: dict, n_dev: int):
+    """``TPULearner.fit`` on the LM; returns (facts, model, tokens)."""
+    from mmlspark_tpu.core.table import DataTable
+    from mmlspark_tpu.models.learner import TPULearner
+
+    spec = cfg["lm_spec"]
+    seq, vocab = spec["max_len"], spec["vocab_size"]
+    steps = cfg["lm_steps_per_epoch"] * cfg["lm_epochs"]
+    rng = np.random.default_rng(0)
+    n = cfg["lm_batch"] * cfg["lm_steps_per_epoch"]
+    toks = rng.integers(0, vocab, size=(n, seq)).astype(np.float32)
+    tgts = np.roll(toks.astype(np.int64), -1, axis=1)
+    sharded = {"meshAxes": {"data": n_dev // 2, "fsdp": 2},
+               "paramSharding": "fsdp"} if n_dev > 1 else {}
+    learner = TPULearner(
+        networkSpec=spec, loss="token_cross_entropy",
+        batchSize=cfg["lm_batch"], learningRate=1e-3, optimizer="adamw",
+        computeDtype="bfloat16", epochs=cfg["lm_epochs"], logEvery=1,
+        dataFeed="device", **sharded)
+    model = learner.fit(DataTable({"features": toks, "label": tgts}))
+
+    losses = [h["loss"] for h in learner.history]
+    assert len(losses) == steps, (len(losses), steps)
+    assert all(math.isfinite(v) for v in losses), losses
+    # random weights on random tokens: the first loss is ln(vocab) give
+    # or take the logits' variance
+    assert abs(losses[0] - math.log(vocab)) < 1.0, losses
+    hlo = learner.step_lowered.as_text()
+    if cfg["on_chip"] and seq >= 512:
+        assert "tpu_custom_call" in hlo, \
+            "the train step lowered without the flash kernel"
+    timing = learner.timing
+    assert timing.get("steps_timed", 0) > 0 and \
+        timing.get("examples_per_sec", 0) > 0, timing
+    assert not timing.get("includes_compile"), timing
+    facts = {
+        "steps": steps,
+        "loss_first": round(losses[0], 4),
+        "loss_last": round(losses[-1], 4),
+        "flash_in_step": "tpu_custom_call" in hlo,
+        "tokens_per_sec_per_chip":
+            round(timing["examples_per_sec"] * seq / n_dev, 1),
+        "mfu_xla_cost_analysis":
+            round(timing["mfu"], 4) if "mfu" in timing else None,
+    }
+    _log(f"train: information only, no threshold: "
+         f"{facts['tokens_per_sec_per_chip']} tokens/s/chip, MFU "
+         f"{facts['mfu_xla_cost_analysis']} (XLA cost analysis; counts "
+         f"the flash call as zero)")
+    return facts, model, toks
+
+
+# ---------------------------------------------------------------------------
+# leg 3: transform
+# ---------------------------------------------------------------------------
+
+
+def leg_transform(cfg: dict, model, toks: np.ndarray, n_dev: int) -> dict:
+    import jax
+
+    from mmlspark_tpu.core.table import DataTable
+
+    spec = cfg["lm_spec"]
+    rows = toks[:cfg["transform_rows"]]
+    scores = np.asarray(
+        model.transform(DataTable({"features": rows}))["scores"])
+    assert scores.shape == (len(rows), spec["max_len"],
+                            spec["vocab_size"]), scores.shape
+    assert np.isfinite(scores).all()
+    ref = reference_forward(spec, model.get("weights"), rows[:1])
+    rel = float(np.linalg.norm(scores[0] - ref[0])
+                / np.linalg.norm(ref[0]))
+    assert rel < BF16_REL_TOL, \
+        f"logits off the float32 reference: rel L2 {rel:.3e}"
+    facts = {"rows": len(rows), "rel_l2_vs_f32": round(rel, 5),
+             "tolerance": BF16_REL_TOL,
+             "argmax_agreement": round(float(np.mean(
+                 scores[0].argmax(-1) == ref[0].argmax(-1))), 4)}
+    if n_dev > 1:
+        # replicated over the default mesh: one copy on every device
+        host_bytes = sum(
+            int(np.asarray(a).nbytes) for a in
+            jax.tree_util.tree_leaves(model.get("weights")))
+        assert model.resident_bytes() == n_dev * host_bytes, \
+            (model.resident_bytes(), n_dev, host_bytes)
+        facts["weight_copies"] = n_dev
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# leg 4: serve
+# ---------------------------------------------------------------------------
+
+
+def _post(address: str, body: dict) -> dict:
+    req = urllib.request.Request(
+        address, data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as resp:
+        assert resp.status == 200, resp.status
+        return json.loads(resp.read())
+
+
+def leg_serve(cfg: dict) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.networks import build_network
+    from mmlspark_tpu.models.tpu_model import TPUModel
+    from mmlspark_tpu.serving.fleet import json_scoring_pipeline
+    from mmlspark_tpu.serving.server import serve_model
+
+    spec = {**cfg["lm_spec"], "num_classes": cfg["serve_classes"],
+            "dtype": "bfloat16"}
+    seq = spec["max_len"]
+    module = build_network(spec)
+    variables = jax.jit(module.init)(jax.random.PRNGKey(1),
+                                     jnp.zeros((1, seq), jnp.int32))
+    model = TPUModel.from_flax(module, variables, inputCol="features",
+                               outputCol="scores",
+                               batchSize=cfg["lm_batch"])
+    rng = np.random.default_rng(1)
+    toks = rng.integers(0, spec["vocab_size"],
+                        size=(cfg["serve_requests"], seq))
+    compiles = model.warmup({"features": toks[:1].astype(np.float32)})
+    assert compiles == len(model.bucket_sizes()), compiles
+    misses = model.jit_cache_misses
+
+    engine = serve_model(json_scoring_pipeline(model, field="features"),
+                         port=0, batch_size=cfg["lm_batch"])
+    try:
+        address = engine.source.address
+        preds = [_post(address, {"features": row.tolist()})["prediction"]
+                 for row in toks]
+    finally:
+        engine.stop()
+    assert not engine.is_alive()
+    with socket.socket() as s:
+        assert s.connect_ex(("127.0.0.1", engine.source.port)) != 0, \
+            "the server still listens after stop()"
+    recompiles = model.jit_cache_misses - misses
+    assert recompiles == 0, f"{recompiles} recompiles under traffic"
+
+    ref = reference_forward(spec, variables, toks)      # (n, classes)
+    top2 = np.sort(ref, axis=-1)[:, -2:]
+    margin = top2[:, 1] - top2[:, 0]
+    agree = np.asarray(preds) == ref.argmax(-1)
+    assert (agree | (margin < BF16_TIE_MARGIN)).all(), \
+        (preds, ref.argmax(-1).tolist(), margin.tolist())
+    assert agree.sum() * 2 > len(preds), (preds, ref.argmax(-1).tolist())
+    return {"requests": len(preds),      # every one answered 200
+            "predictions_equal_reference": int(agree.sum()),
+            "warmup_compiles": compiles,
+            "recompiles_under_traffic": recompiles}
+
+
+# ---------------------------------------------------------------------------
+# leg 5: GBDT + fused pipeline
+# ---------------------------------------------------------------------------
+
+
+def _auc(y: np.ndarray, score: np.ndarray) -> float:
+    """Mann-Whitney AUC (ties are measure-zero for float scores)."""
+    ranks = np.empty(len(score))
+    ranks[np.argsort(score)] = np.arange(1, len(score) + 1)
+    pos = y > 0
+    n_pos, n_neg = int(pos.sum()), int((~pos).sum())
+    return float((ranks[pos].sum() - n_pos * (n_pos + 1) / 2)
+                 / (n_pos * n_neg))
+
+
+def leg_gbdt(cfg: dict, n_dev: int) -> dict:
+    from mmlspark_tpu import gbdt
+    from mmlspark_tpu.parallel import mesh as mesh_lib
+
+    rng = np.random.default_rng(0)
+    n_tr, n = cfg["gbdt_rows"], cfg["gbdt_rows"] + cfg["gbdt_valid_rows"]
+    X = rng.normal(size=(n, cfg["gbdt_features"])).astype(np.float32)
+    logit = (X[:, 0] * 1.5 + X[:, 1] * X[:, 2]
+             + 0.5 * np.sin(3 * X[:, 3])
+             + rng.normal(scale=0.5, size=n))
+    y = (logit > 0).astype(np.float64)
+    mesh = mesh_lib.make_mesh({"data": n_dev}) if n_dev > 1 else None
+
+    facts = {}
+    for max_bin in cfg["gbdt_max_bins"]:
+        params = {"objective": "binary",
+                  "num_iterations": cfg["gbdt_iterations"],
+                  "boost_chunk": cfg["gbdt_iterations"],
+                  "num_leaves": cfg["gbdt_leaves"], "max_bin": max_bin,
+                  "min_data_in_leaf": 50,
+                  "hist_method": cfg["gbdt_hist_method"]}
+        if n_dev > 1:
+            params["parallelism"] = "data"
+        t0 = time.perf_counter()
+        booster = gbdt.train(params, X[:n_tr], y[:n_tr], mesh=mesh)
+        wall = time.perf_counter() - t0
+        assert booster.params["hist_method"] == "pallas", \
+            booster.params["hist_method"]
+        assert booster.train_info["bin_path"] == "device", \
+            booster.train_info
+        assert booster.train_info["bins_devices"] == n_dev, \
+            booster.train_info
+        pred = np.asarray(booster.predict(X[n_tr:]))
+        assert pred.shape == (n - n_tr,) and np.isfinite(pred).all()
+        auc = _auc(y[n_tr:], pred)
+        assert auc > cfg["gbdt_min_auc"], f"holdout AUC {auc:.4f}"
+        facts[f"max_bin_{max_bin}"] = {
+            "wall_s": round(wall, 2), "holdout_auc": round(auc, 4),
+            "phases": booster.train_timing,
+            "bins_devices": booster.train_info["bins_devices"]}
+    facts["pipeline"] = _fused_pipeline(cfg)
+    return facts
+
+
+def _fused_pipeline(cfg: dict) -> dict:
+    from mmlspark_tpu import DataTable, Pipeline
+    from mmlspark_tpu.automl.featurize import Featurize
+    from mmlspark_tpu.gbdt import TPUBoostClassifier
+
+    def table(n, seed):
+        rng = np.random.default_rng(seed)
+        num1, num2 = rng.normal(size=n), rng.normal(size=n)
+        icol = rng.integers(-5, 5, n)
+        return DataTable({
+            "num1": num1, "num2": num2, "icol": icol,
+            "label": (num1 + 0.5 * num2 + 0.1 * icol > 0).astype(float)})
+
+    rows = cfg["pipeline_rows"]
+    pm = Pipeline(stages=[
+        Featurize(featureColumns=["num1", "num2", "icol"],
+                  numberOfFeatures=8),
+        TPUBoostClassifier(featuresCol="features", labelCol="label",
+                           numIterations=8, numLeaves=7, minDataInLeaf=4,
+                           histMethod=cfg["gbdt_hist_method"]),
+    ]).fit(table(rows, 11))
+    fused = pm.fused()
+    scoring = table(rows // 2, 12)
+    plan = fused.plan_for(scoring.schema)
+    out = fused.transform(scoring)
+    roundtrips = plan.last_roundtrips
+    staged = fused.transform_staged(scoring)
+    described = plan.describe()
+    # the booster must sit INSIDE a fused segment ([A+B]), not beside
+    # one: stage_device_op swallows a failing device_op and runs the
+    # stage on the host
+    segments = [seg for seg in described.split(" -> ")
+                if seg.startswith("[")]
+    assert len(segments) == 1 and "TPUBoost" in segments[0], described
+    assert roundtrips == 1, (roundtrips, described)
+    assert np.array_equal(np.asarray(out["prediction"]),
+                          np.asarray(staged["prediction"]))
+    prob, prob_staged = (np.asarray(t["probability"])
+                         for t in (out, staged))
+    assert np.allclose(prob, prob_staged, atol=1e-5)
+    accuracy = float(np.mean(
+        np.asarray(out["prediction"]) == np.asarray(scoring["label"])))
+    assert accuracy > 0.8, accuracy
+    return {"plan": described, "roundtrips": roundtrips,
+            "bit_identical_to_staged": bool(
+                np.array_equal(prob, prob_staged)),
+            "accuracy": round(accuracy, 4)}
+
+
+# ---------------------------------------------------------------------------
+# placement on several devices, and the report
+# ---------------------------------------------------------------------------
+
+
+def device_peaks() -> list:
+    import jax
+    return [int(d.memory_stats()["peak_bytes_in_use"])
+            for d in jax.devices()]
+
+
+def assert_balanced(peaks: list, what: str) -> None:
+    """Every device did a comparable share: no device's peak is under
+    half the largest (device 0 alone doing the work leaves the others
+    near zero)."""
+    assert min(peaks) * 2 >= max(peaks), f"{what}: peaks {peaks}"
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    legs = {}
+
+    def run(name, fn, *args):
+        t0 = time.perf_counter()
+        out = fn(*args)
+        facts = out[0] if isinstance(out, tuple) else out
+        legs[name] = {"ok": True,
+                      "wall_s": round(time.perf_counter() - t0, 2),
+                      **facts}
+        _log(f"leg {name} ok in {legs[name]['wall_s']} s")
+        return out
+
+    device = run("device", leg_device)
+    n_dev = device["count"]
+
+    from mmlspark_tpu.native import loader as native
+    from mmlspark_tpu.utils.compile_cache import configure_compile_cache
+    cache_dir = configure_compile_cache()
+
+    cfg = FULL
+    _, model, toks = run("train", leg_train, cfg, n_dev)
+    if n_dev > 1:
+        legs["train"]["device_peaks"] = device_peaks()
+        assert_balanced(legs["train"]["device_peaks"], "train")
+    run("transform", leg_transform, cfg, model, toks, n_dev)
+    del model
+    run("serve", leg_serve, cfg)
+    run("gbdt", leg_gbdt, cfg, n_dev)
+
+    peaks = device_peaks()      # device 0 also ran the references
+    print(json.dumps({
+        "legs": legs,
+        "native": "loaded" if native.available() else "absent",
+        "compile_cache_dir": cache_dir,
+        "peak_hbm_bytes": max(peaks),
+        "peak_hbm_bytes_per_device": peaks,
+        "wall_s": round(time.perf_counter() - t_start, 2),
+    }), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -73,11 +73,12 @@ def _hist_kernel_nibble(bins_ref, stats_ref, out_ref, *, h: int, l: int,
     instead of O(B), which is what bounds the kernel (the one-hot build
     is VPU-compare work; the matmuls are almost free on the MXU).
 
-    Quantized stats (int8/int16) keep the one-hots in the SAME narrow
-    dtype and ask the MXU for an int32 accumulator via
-    ``preferred_element_type`` — the i8->i32 lowering the quantized
-    inference kernels use (core/quantize.py), giving exact integer
-    histogram sums.
+    Quantized stats keep the one-hots in the SAME narrow dtype and ask
+    the MXU for an int32 accumulator via ``preferred_element_type`` —
+    the i8->i32 lowering the quantized inference kernels use
+    (core/quantize.py), giving exact integer histogram sums. Only int8
+    compiles for TPU (the MXU has no int16 path; booster's
+    _validate_hist_params refuses hist_bits=16 here).
 
     Output layout is (3h, fc*l) — feature j's (3h, l) block at columns
     [j*l, (j+1)*l) — because collapsing (h, l) into the lane axis is
@@ -94,12 +95,17 @@ def _hist_kernel_nibble(bins_ref, stats_ref, out_ref, *, h: int, l: int,
     lo_ids = lax.broadcasted_iota(jnp.int32, (l, c), 0)
 
     oh_dtype = stats_blk.dtype
+    # Mosaic has an int8 matmul but no int8 vector multiply: quantized
+    # stats weight the hi one-hot in int32 and narrow for the MXU
+    mul_dtype = jnp.int32 if jnp.issubdtype(oh_dtype, jnp.integer) \
+        else oh_dtype
+    stats_mul = stats_blk.astype(mul_dtype)
     parts = []
     for j in range(fc):                            # static unroll
-        hoh = (hi[j][None, :] == hi_ids).astype(oh_dtype)       # (h, C)
+        hoh = (hi[j][None, :] == hi_ids).astype(mul_dtype)      # (h, C)
         loh = (lo[j][None, :] == lo_ids).astype(oh_dtype)       # (l, C)
-        lhs = (stats_blk[:, None, :] * hoh[None, :, :]) \
-            .reshape(3 * h, c)                     # (3h, C)
+        lhs = (stats_mul[:, None, :] * hoh[None, :, :]) \
+            .reshape(3 * h, c).astype(oh_dtype)    # (3h, C)
         parts.append(lax.dot_general(
             lhs, loh, (((1,), (1,)), ((), ())),
             preferred_element_type=acc_dtype))     # (3h, l)

@@ -596,7 +596,10 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                 (_time.perf_counter() - t_dispatch) * 1e3)
 
         def dispatch(inputs):
-            outputs = self._compiled()(weights, inputs)
+            # traced under the mesh: kernels that XLA cannot partition
+            # (ring_attention.flash_per_shard) read it
+            with jax.set_mesh(mesh):
+                outputs = self._compiled()(weights, inputs)
             for model_out in fetches.values():
                 if model_out not in outputs:
                     raise KeyError(

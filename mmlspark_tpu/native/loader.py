@@ -23,15 +23,26 @@ log = get_logger("native")
 
 _NATIVE_DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_NATIVE_DIR, "lib", "libmml_native.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "src", "mml_native.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _stale() -> bool:
+    """The library is missing, or older than the source it was built
+    from: the bindings below assume the symbols of the source as
+    committed."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    return (os.path.exists(_SRC_PATH)
+            and os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH))
+
+
 def _build() -> bool:
-    """One-time cmake build (the packaging-time step; done lazily here
-    so source checkouts self-provision)."""
+    """cmake build (the packaging-time step; done lazily here so source
+    checkouts self-provision)."""
     build_dir = os.path.join(_NATIVE_DIR, "build")
     os.makedirs(build_dir, exist_ok=True)
     try:
@@ -71,21 +82,19 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_long),
         ctypes.POINTER(ctypes.c_int32)]
     lib.mml_apply_bins.restype = ctypes.c_int
-    if hasattr(lib, "mml_apply_bins_t_u8"):   # pre-upgrade .so lacks it
-        lib.mml_apply_bins_t_u8.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, ctypes.c_long, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_long),
-            ctypes.POINTER(ctypes.c_uint8)]
-        lib.mml_apply_bins_t_u8.restype = ctypes.c_int
-    if hasattr(lib, "mml_apply_bins_t_u8_range"):
-        lib.mml_apply_bins_t_u8_range.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, ctypes.c_long, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_long),
-            ctypes.POINTER(ctypes.c_uint8)]
-        lib.mml_apply_bins_t_u8_range.restype = ctypes.c_int
+    lib.mml_apply_bins_t_u8.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_long, ctypes.c_int,
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_long),
+        ctypes.POINTER(ctypes.c_uint8)]
+    lib.mml_apply_bins_t_u8.restype = ctypes.c_int
+    lib.mml_apply_bins_t_u8_range.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_long, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int,
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_long),
+        ctypes.POINTER(ctypes.c_uint8)]
+    lib.mml_apply_bins_t_u8_range.restype = ctypes.c_int
     return lib
 
 
@@ -101,9 +110,8 @@ def get_lib(allow_build: bool = True) -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("MMLSPARK_TPU_NO_NATIVE") == "1":
             return None  # kill-switch: force pure-numpy paths
-        if not os.path.exists(_LIB_PATH):
-            if not (allow_build and _build()):
-                return None
+        if _stale() and not (allow_build and _build()):
+            return None
         try:
             _lib = _bind(ctypes.CDLL(_LIB_PATH))
             log.info("native library loaded from %s", _LIB_PATH)
@@ -206,13 +214,9 @@ def apply_bins_t_u8(X: np.ndarray, upper_bounds: list,
     engine's ship layout). ``feature_range=(j0, j1)`` bins only that
     column slice into a (j1-j0, n) block without copying X — the unit
     of the pipelined host-bin/device-ship overlap. Requires every
-    feature's bin count <= 256 and the library built after the kernel
-    landed (probed via hasattr)."""
+    feature's bin count <= 256."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "mml_apply_bins_t_u8"):
-        return None
-    if feature_range is not None and not hasattr(
-            lib, "mml_apply_bins_t_u8_range"):
+    if lib is None:
         return None
     if any(len(u) + 1 > 256 for u in upper_bounds):
         return None

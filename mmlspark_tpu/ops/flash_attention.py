@@ -42,38 +42,37 @@ NEG_INF = -1e30
 # measured on v5e (H=8-16, D=64-128, causal fwd+bwd): 1024x1024 blocks
 # run ~2x faster than the 256x256 default at every L from 1k to 32k —
 # fewer grid steps and fewer online-softmax rescales per KV element.
-# The backward's (bq, bk) f32 intermediates need the larger VMEM of
-# v5e+ parts; older generations clamp back to 256 (see _block_caps)
+# The backward's (bq, bk) f32 intermediates need the VMEM of v5e+ parts.
 BLOCK_Q = 1024
 BLOCK_K = 1024
 
-
-_BLOCK_CAP_MEMO: dict = {}
+# block ceiling at D <= 128 by jax ``device_kind``, exact match. A TPU
+# that is not listed is an error, not a guess: add it here once the
+# kernel has been compiled on it (chip_smoke.py does).
+_BLOCK_CAP_BY_KIND = {
+    "TPU v5 lite": 1024,     # v5e
+}
 
 
 def _block_caps(d: int):
     """Per-generation, per-head-dim block ceiling: the tuned 1024 blocks
     are VMEM-safe on v5e+ up to D=128 (measured); D=160 overflows the
     16 MB scoped-vmem limit in the backward (observed: 16.78M request),
-    so wider heads halve the blocks. Unknown/older parts keep the
-    conservative 256.
-
-    Memoized manually (not lru_cache): if the first call lands before the
-    jax backend is usable, the conservative fallback must NOT be pinned
-    for the process lifetime — the next call re-probes the device."""
-    if d in _BLOCK_CAP_MEMO:
-        return _BLOCK_CAP_MEMO[d]
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # backend not initialized yet — don't memoize
+    so wider heads halve the blocks. Off-TPU the kernel only runs
+    interpreted (tests), where 256 keeps the interpreter quick."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         return 256, 256
-    if any(t in kind for t in ("v5", "v6", "v7")):
-        caps = (BLOCK_Q, BLOCK_K) if d <= 128 else \
-            (min(BLOCK_Q, 512), min(BLOCK_K, 512))
-    else:
-        caps = (min(BLOCK_Q, 256), min(BLOCK_K, 256))
-    _BLOCK_CAP_MEMO[d] = caps
-    return caps
+    cap = _BLOCK_CAP_BY_KIND.get(dev.device_kind)
+    if cap is None:
+        raise ValueError(
+            f"flash_attention has no block sizes for device_kind "
+            f"{dev.device_kind!r}; add it to _BLOCK_CAP_BY_KIND in "
+            f"ops/flash_attention.py (known: "
+            f"{sorted(_BLOCK_CAP_BY_KIND)})")
+    if d > 128:
+        cap = min(cap, 512)
+    return min(BLOCK_Q, cap), min(BLOCK_K, cap)
 
 
 def _fully_masked(qi, ki, bq, bk, q_offset, k_offset):

@@ -225,14 +225,6 @@ def initialize(coordinator_address: Optional[str] = None,
                 process_id=pid,
                 initialization_timeout=int(max(1, timeout_s)),
             )
-        except TypeError:
-            # older jax without initialization_timeout: unbounded —
-            # still correct, just without the fast-fail envelope
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=nproc,
-                process_id=pid,
-            )
         except Exception as e:  # noqa: BLE001 — surface actionably
             raise ProcessGroupError(
                 f"jax.distributed rendezvous failed for process {pid}/"

@@ -173,16 +173,23 @@ def _rebuild_mesh(axes: Dict[str, int]):
 def _artifact_cache(art_dir: str):
     """Point jax's persistent compilation cache into the artifact for
     the duration (export seeds it; load hits it), restoring the
-    caller's cache config after. Best-effort: a jax without the knobs
-    — or an artifact on a read-only mount (cache READS still work) —
-    still exports/loads, just without (re)seeding the disk cache.
+    caller's cache config after. When the operator placed the cache
+    (``JAX_COMPILATION_CACHE_DIR``) it stays where they put it and
+    nothing is redirected. An artifact on a read-only mount (cache
+    READS still work) still loads, just without (re)seeding the disk
+    cache.
 
     NOTE: the cache redirection is process-global for the duration, so
     a compile racing on another thread during this window caches into
-    the artifact instead of the operator's configured dir (harmless but
+    the artifact instead of the configured dir (harmless but
     surprising). Load artifacts BEFORE initiating a swap on a live
     engine rather than from inside a serving callback."""
+    from mmlspark_tpu.utils.compile_cache import cache_dir_from_env
+    if cache_dir_from_env():
+        yield
+        return
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
     cache_dir = os.path.join(art_dir, _XLA_CACHE)
     try:
         os.makedirs(cache_dir, exist_ok=True)
@@ -192,36 +199,21 @@ def _artifact_cache(art_dir: str):
         if not os.path.isdir(cache_dir):
             yield
             return
-    old = {}
     knobs = {"jax_compilation_cache_dir": cache_dir,
              "jax_persistent_cache_min_entry_size_bytes": -1,
              "jax_persistent_cache_min_compile_time_secs": 0.0}
+    old = {k: getattr(jax.config, k) for k in knobs}
     try:
         for k, v in knobs.items():
-            try:
-                old[k] = getattr(jax.config, k)
-                jax.config.update(k, v)
-            except Exception:  # noqa: BLE001 — knob missing on old jax
-                pass
-        _reset_cc()
+            jax.config.update(k, v)
+        # drop the lazily-initialized cache singleton so the dir change
+        # takes effect mid-process
+        compilation_cache.reset_cache()
         yield
     finally:
         for k, v in old.items():
-            try:
-                jax.config.update(k, v)
-            except Exception:  # noqa: BLE001
-                pass
-        _reset_cc()
-
-
-def _reset_cc() -> None:
-    """Drop jax's lazily-initialized compilation-cache singleton so a
-    cache-dir change mid-process actually takes effect."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — private API drift: cache just
-        pass           # stays bound to the first dir it saw
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 def _single_device_mesh():

@@ -245,7 +245,7 @@ class _SeqShardedApply:
     def _build(self):
         if self._fn is not None:
             return self._fn
-        from mmlspark_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         module, axis = self.module, self.axis
         out_spec = (P(None, axis) if module.num_classes == 0 else P())
 

@@ -1,65 +1,18 @@
-"""jax version-compatibility shims.
+"""Virtual CPU devices for tests and dry runs.
 
-The codebase targets current jax (``jax.shard_map`` with ``check_vma``),
-but some images pin jax 0.4.x where the API is
-``jax.experimental.shard_map.shard_map`` with ``check_rep``. Import
-``shard_map`` from here instead of from jax so both work; the wrapper
-translates the replication-check kwarg to whatever the installed jax
-spells it.
+The distributed-without-a-cluster pattern: a process that wants an
+n-device mesh without n chips asks the CPU backend for n virtual
+devices. One implementation for conftest, the distributed test workers,
+and the driver entry points.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
-import os
-
-try:
-    from jax import shard_map as _jax_shard_map   # jax >= 0.6
-except ImportError:                               # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-_HAS_VMA = "check_vma" in inspect.signature(_jax_shard_map).parameters
-
-# jax 0.4.x's SPMD lowering of a pallas_call inlined directly inside a
-# fori_loop + ppermute shard_map body emits an unpartitionable
-# PartitionId instruction; routing the call through real control flow
-# (lax.switch with >1 branch) sidesteps it. Consumers gate the
-# workaround on this flag so current jax keeps the straight-line path.
-LEGACY_SHARD_MAP = not _HAS_VMA
-
 
 def set_cpu_device_count(n: int, platform: str = "cpu") -> None:
-    """Give this process ``n`` virtual CPU devices; call before first
-    backend use. jax >= 0.5 spells it as the ``jax_num_cpu_devices``
-    config option; older jax only has the XLA flag, which is set ONLY on
-    that fallback path (newer jax rejects flag + option combined) and
-    never appended twice. One implementation for conftest, the
-    distributed test workers, and the driver entry points."""
+    """Give this process ``n`` virtual CPU devices. Must run before the
+    first backend use — jax raises RuntimeError afterwards."""
     import jax
     if platform:
         jax.config.update("jax_platforms", platform)
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        import re
-        flag = f"--xla_force_host_platform_device_count={n}"
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" in flags:
-            # replace a pre-existing count (possibly different) rather
-            # than silently keeping it, matching the config-option path
-            flags = re.sub(
-                r"--xla_force_host_platform_device_count=\d+", flag, flags)
-        else:
-            flags = (flags + " " + flag).strip()
-        os.environ["XLA_FLAGS"] = flags
-
-
-def shard_map(f=None, **kwargs):
-    """``jax.shard_map`` across jax versions. Supports both direct call
-    and ``functools.partial(shard_map, ...)`` decorator usage."""
-    if "check_vma" in kwargs and not _HAS_VMA:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    return _jax_shard_map(f, **kwargs)
+    jax.config.update("jax_num_cpu_devices", n)

@@ -1,0 +1,60 @@
+"""chip_smoke.py's legs at tiny sizes on the CPU (kernels interpreted
+where the code has a route to them), and its refusal to run without a
+chip. The real check is ``python chip_smoke.py`` on a TPU."""
+
+import pytest
+
+import chip_smoke
+
+TINY = {
+    **chip_smoke.FULL,
+    "on_chip": False,
+    "lm_spec": {"type": "transformer", "vocab_size": 32, "dim": 16,
+                "depth": 1, "heads": 2, "max_len": 8,
+                "head_dtype": "bfloat16"},
+    "lm_steps_per_epoch": 1,
+    "transform_rows": 1,
+    "serve_classes": 4,
+    "serve_requests": 2,
+    "gbdt_rows": 1024,
+    "gbdt_valid_rows": 512,
+    "gbdt_features": 4,
+    "gbdt_leaves": 4,
+    "gbdt_iterations": 2,
+    "gbdt_max_bins": (255,),
+    "gbdt_hist_method": "pallas",     # interpreted off the chip
+    "gbdt_min_auc": 0.6,
+    "pipeline_rows": 200,
+}
+
+
+def test_train_then_transform():
+    facts, model, toks = chip_smoke.leg_train(TINY, 1)
+    assert facts["steps"] == 2 and not facts["flash_in_step"]
+    facts = chip_smoke.leg_transform(TINY, model, toks, 1)
+    assert facts["rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
+
+
+def test_serve():
+    facts = chip_smoke.leg_serve(TINY)
+    assert facts["requests"] == facts["predictions_equal_reference"] == 2
+
+
+def test_gbdt_and_fused_pipeline():
+    facts = chip_smoke.leg_gbdt(TINY, 1)
+    assert facts["pipeline"]["roundtrips"] == 1
+    assert "TPUBoost" in facts["pipeline"]["plan"]
+
+
+def test_unbalanced_placement_is_caught():
+    chip_smoke.assert_balanced([100, 60, 80, 100], "even")
+    with pytest.raises(AssertionError, match="peaks"):
+        chip_smoke.assert_balanced([100, 0, 0, 0], "device 0 only")
+
+
+def test_main_refuses_a_cpu_backend(capsys):
+    """No CPU mode: leg 1 exits non-zero and no result line appears."""
+    with pytest.raises(SystemExit) as exit_info:
+        chip_smoke.main()
+    assert "needs a TPU" in str(exit_info.value.code)   # exit status 1
+    assert '"ok"' not in capsys.readouterr().out

@@ -68,7 +68,7 @@ def _run_in_venv(venv, code=None, argv=None, cwd=None, timeout=300):
     NON-repo cwd so imports cannot leak from the checkout."""
     env = dict(os.environ)
     env.pop("PYTHONPATH", None)
-    env["MMLSPARK_TPU_PLATFORM"] = "cpu"   # keep CLI tests off the chip
+    env["JAX_PLATFORMS"] = "cpu"   # keep CLI tests off the chip
     if code is not None:
         cmd = [str(venv / "bin" / "python"), "-c", code]
     else:
@@ -233,7 +233,7 @@ def test_cli_serve_scores_over_http(installed_venv, tmp_path):
     port = 18931
     env = dict(os.environ)
     env.pop("PYTHONPATH", None)
-    env["MMLSPARK_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [str(venv / "bin" / "mmlspark-tpu"), "serve",
          "--model", str(model_dir), "--port", str(port)],
