@@ -1,0 +1,14 @@
+"""The model step's share of the bf16 peak while it runs: the forward
+operations of the rows the window answered (padded rows are not work,
+flops.py) over the device's busy time in the trace."""
+
+
+def read(ctx):
+    from flops import forward_flops_per_row
+    t, peak, c = ctx.get("trace"), ctx.get("peak"), ctx["counters"]
+    if not t or not peak or not c.get("rows_ok") or t["busy_s"] <= 0:
+        return None
+    spec = ctx["cell"]["config_file"]["networkSpec"]
+    need = forward_flops_per_row(spec, c["seq"]) * c["rows_ok"]
+    return 100.0 * need / (t["busy_s"] * peak["bf16_flops"]
+                           * ctx["cell"]["chips"])
