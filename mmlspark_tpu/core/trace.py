@@ -50,6 +50,13 @@ Logging correlation: ``use_span``/``current_span`` hold the active span
 in a ``contextvars`` context so the JSON log formatter
 (``core.logging_utils``) can stamp ``trace_id`` on every record emitted
 inside a span.
+
+The stage clock: ``phase`` is the one way a hot path times a host
+stage. One pair of ``perf_counter`` stamps feeds the span, the
+histogram and — through ``jax.profiler.TraceAnnotation`` — the
+profiler's own file, where the phase lies beside the device rows;
+``record`` does the span and the histogram for an interval that no
+thread executes. ``STAGES`` names every stage.
 """
 
 from __future__ import annotations
@@ -60,6 +67,7 @@ import itertools
 import os
 import random
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -279,11 +287,15 @@ class Trace:
     to sibling traces). Thread-safe add — batcher, worker, and handler
     threads all contribute spans."""
 
-    __slots__ = ("trace_id", "root", "_spans", "_lock", "_finished")
+    __slots__ = ("trace_id", "root", "tracer", "_spans", "_lock",
+                 "_finished")
 
-    def __init__(self, trace_id: str, root: Span):
+    def __init__(self, trace_id: str, root: Span, tracer=None):
         self.trace_id = trace_id
         self.root = root
+        # the Tracer that minted it: ``phase``/``record`` draw span ids
+        # from it, so a caller hands over the trace alone
+        self.tracer = tracer
         self._spans: List[Span] = []
         self._lock = threading.Lock()
         self._finished = False
@@ -576,7 +588,7 @@ class Tracer:
                     parent_id=(str(parent_id)[:64] if parent_id
                                else None),
                     start=start)
-        return Trace(trace_id, root)
+        return Trace(trace_id, root, self)
 
     def continue_trace(self, name: str, ctx: Optional[TraceContext],
                        start: Optional[float] = None) -> Trace:
@@ -616,6 +628,19 @@ class Tracer:
                     parent_id=parent.span_id if parent else None,
                     start=start)
         trace.add(span)
+        return span
+
+    def join_span(self, name: str, traces: Sequence[Trace],
+                  start: Optional[float] = None) -> Span:
+        """The batch-join span: ONE span that belongs to every trace of
+        ``traces`` (child of the first one's root) and links each
+        request's root, so one decode or device span explains all N
+        rows it served."""
+        span = self.start_span(name, traces[0], start=start)
+        for tr in traces:
+            span.link(tr.root.trace_id, tr.root.span_id)
+        for tr in traces[1:]:
+            tr.add(span)
         return span
 
     def finish(self, trace: Trace, end: Optional[float] = None) -> None:
@@ -692,6 +717,138 @@ def use_span(span: Optional[Span]) -> Iterator[Optional[Span]]:
         yield span
     finally:
         _current_span.reset(token)
+
+
+# ---------------------------------------------------------------------------
+# the stage clock
+# ---------------------------------------------------------------------------
+
+# A served request's stages, in order. Spans of these names tile
+# [enqueued_at, answered_at] with no gap and no overlap
+# (serving/server.py), and each has an engine histogram that measures
+# the same interval (docs/observability.md has the table).
+REQUEST_STAGES = ("queue_wait", "collect_wait", "token_wait", "decode",
+                  "dispatch_wait", "device", "respond")
+# What a thread of the hot paths executes, by the name it has on the
+# profiler's clock (the ``/host:CPU`` plane of an xplane profile).
+HOST_PHASES = (
+    "serve.token_wait", "serve.decode", "serve.execute", "serve.respond",
+    "tpu_model.pad", "tpu_model.dispatch", "tpu_model.readback",
+    "learner.chunk", "learner.step", "learner.flush_logs",
+    "learner.checkpoint", "learner.feed_wait", "learner.final_wait")
+# Every name ``phase`` and ``record`` are called with: the tests,
+# docs/observability.md and PERF.md enumerate this tuple.
+STAGES = REQUEST_STAGES + HOST_PHASES
+
+_annotation_cls = None
+_current_phase: "contextvars.ContextVar[Optional[phase]]" = \
+    contextvars.ContextVar("mmlspark_tpu_current_phase", default=None)
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """``jax.profiler.TraceAnnotation(name, **attrs)``, or None in a
+    process that has not imported jax: no profiler session can be open
+    there, and this module must not be what pays jax's import."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        try:
+            _annotation_cls = jax.profiler.TraceAnnotation
+        except AttributeError:      # jax absent, or still importing
+            return None
+    return _annotation_cls(name, **attrs)
+
+
+def _open_span(trace, name: str, start: float,
+               attrs: Dict[str, Any]) -> Span:
+    """A started span in ``trace``: one Trace, or a sequence of them
+    for a batch-join span."""
+    if isinstance(trace, Trace):
+        span = trace.tracer.start_span(name, trace, start=start)
+    else:
+        span = trace[0].tracer.join_span(name, trace, start=start)
+    span.attrs.update(attrs)
+    return span
+
+
+class phase:
+    """Time one host stage: the only way the hot paths do.
+
+        with phase("serve.execute", span="device", trace=traces,
+                   batch=seq, rows=n) as ph:
+            ...
+
+    One entry does three things from one pair of ``perf_counter``
+    stamps (``ph.start``, ``ph.end``): it lies in the profiler's file
+    as a ``TraceAnnotation(name, **attrs)`` — recorded only while a
+    profiler session is open, about a microsecond otherwise; it emits
+    a span named ``span`` (default: ``name``) into ``trace`` — a Trace,
+    or a sequence of Traces for a batch-join span; None where the
+    tracer is off; and it observes its milliseconds into ``hist``. A
+    phase that raises marks its span as an error and observes nothing.
+    ``start`` hands over the stamp at which the stage before ended, so
+    consecutive stages share their boundary. A phase inside another
+    inherits its ``batch`` attribute."""
+
+    __slots__ = ("name", "attrs", "start", "end", "span", "_trace",
+                 "_span_name", "_hist", "_ann", "_token")
+
+    def __init__(self, name: str, *, span: Optional[str] = None,
+                 trace=None, hist=None, start: Optional[float] = None,
+                 **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.start = start
+        self.end: Optional[float] = None
+        self.span: Optional[Span] = None
+        self._trace = trace
+        self._span_name = span or name
+        self._hist = hist
+
+    def __enter__(self) -> "phase":
+        outer = _current_phase.get()
+        if outer is not None and "batch" in outer.attrs:
+            self.attrs.setdefault("batch", outer.attrs["batch"])
+        self._token = _current_phase.set(self)
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self.start is None:
+            self.start = _now()
+        if self._trace:
+            self.span = _open_span(self._trace, self._span_name,
+                                   self.start, self.attrs)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end = _now()
+        if self.span is not None:
+            if exc is not None:
+                self.span.error(exc)
+            self.span.finish(self.end)
+        if self._hist is not None and exc is None:
+            self._hist.observe((self.end - self.start) * 1e3)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _current_phase.reset(self._token)
+        return False
+
+    @property
+    def ms(self) -> float:
+        return (self.end - self.start) * 1e3
+
+
+def record(name: str, start: float, end: float, *, trace=None,
+           hist=None, **attrs) -> Optional[Span]:
+    """``phase`` for an interval that no thread executes (a batch lying
+    in the dispatch queue, a request waiting for its batch): the span
+    and the histogram from explicit stamps, and nothing on the
+    profiler's clock."""
+    if hist is not None:
+        hist.observe((end - start) * 1e3)
+    if trace:
+        return _open_span(trace, name, start, attrs).finish(end)
+    return None
 
 
 # ---------------------------------------------------------------------------

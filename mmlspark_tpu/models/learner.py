@@ -208,12 +208,6 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
     profileDir = StringParam(
         "emit a jax.profiler xplane trace of the training loop here "
         "('' = off; SURVEY §5 profiler upgrade)", default="")
-    traceAnnotations = BoolParam(
-        "wrap each train-step/chunk dispatch in a named "
-        "jax.profiler.TraceAnnotation so an on-chip (xplane) profile's "
-        "rows correlate 1:1 with the framework's learner.step/chunk "
-        "spans (opt-in: annotations cost a TraceMe record per dispatch)",
-        default=False)
     memoryStatsEvery = IntParam(
         "steps between device-memory-stats samples (bytes_in_use/peak) "
         "recorded into learner.memory_samples and the fit trace "
@@ -437,7 +431,12 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                 "step": st["step"] + 1,
             }, loss
 
-        jit_step = jax.jit(train_step,
+        def learner_step(st, batch):
+            """``train_step`` under the name its program has in a
+            profile (jit_learner_step)."""
+            return train_step(st, batch)
+
+        jit_step = jax.jit(learner_step,
                            in_shardings=(state_sharding, data_sharding),
                            out_shardings=(state_sharding, None),
                            donate_argnums=(0,))
@@ -519,22 +518,29 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
         # what the MFU FLOPs are read from, kept so a caller can check
         # which kernels the step lowered to (chip_smoke.py does)
         self.step_lowered = None
-        # fit-scoped trace: per-step/chunk dispatch spans + optional
-        # device-memory samples, in the same buffer the serving spans
-        # land in (span count capped so a long fit can't balloon it)
-        from mmlspark_tpu.core.trace import get_tracer
+        # fit-scoped trace: a span a host stage (dispatch, log flush,
+        # checkpoint, feed wait) + optional device-memory samples, in
+        # the same buffer the serving spans land in (span count capped
+        # so a long fit can't balloon it)
+        from mmlspark_tpu.core.trace import get_tracer, phase
         _tracer = get_tracer()
         fit_trace = _tracer.new_trace("learner.fit") \
             if _tracer.enabled else None
         _SPAN_CAP = 2048
-        ann_on = bool(self.get("traceAnnotations"))
         mem_every = int(self.get("memoryStatsEvery") or 0)
         self.memory_samples: List[Dict[str, Any]] = []
 
-        def _emit_span(name, t0, **attrs):
+        def _capped_trace():
             if fit_trace is not None and \
                     len(fit_trace._spans) < _SPAN_CAP:
-                _tracer.emit(name, t0, trace=fit_trace, attrs=attrs)
+                return fit_trace
+            return None
+
+        def _stage(name, **attrs):
+            """A host stage of the fit on the one stage clock
+            (core.trace.phase): in the profiler's file always, in the
+            fit trace while its cap lasts."""
+            return phase(name, trace=_capped_trace(), **attrs)
 
         def _sample_memory(step_, force=False):
             if not mem_every or (not force and step_ % mem_every):
@@ -549,7 +555,10 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                 if key in stats:
                     sample[key] = stats[key]
             self.memory_samples.append(sample)
-            _emit_span("memory", _time.perf_counter(), **sample)
+            trace = _capped_trace()
+            if trace is not None:
+                _tracer.emit("memory", _time.perf_counter(),
+                             attrs=sample, trace=trace)
 
         np_rng = np.random.default_rng(self.get("seed"))
         log_every = self.get("logEvery")
@@ -631,19 +640,24 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
             # flush entries whose device value is (almost surely) ready:
             # everything but the newest, or everything when final
             keep = 0 if final else 1
-            while len(pending) > keep:
-                step_, epoch_, dev_loss, t = pending.pop(0)
-                if isinstance(dev_loss, tuple):
-                    # device-feed chunks log (loss_vector, index); resolve
-                    # via a plain transfer — indexing with jnp would
-                    # compile an eager gather mid-loop
-                    arr, j = dev_loss
-                    lv = float(np.asarray(arr)[j])
-                else:
-                    lv = float(dev_loss)
-                self.history.append({"step": step_, "loss": lv,
-                                     "epoch": epoch_, "time": t})
-                logger.info("step %d/%d loss %.4f", step_, total_steps, lv)
+            if len(pending) <= keep:
+                return
+            # the read of a loss blocks until its step has run
+            with _stage("learner.flush_logs", entries=len(pending) - keep):
+                while len(pending) > keep:
+                    step_, epoch_, dev_loss, t = pending.pop(0)
+                    if isinstance(dev_loss, tuple):
+                        # device-feed chunks log (loss_vector, index);
+                        # resolve via a plain transfer — indexing with
+                        # jnp would compile an eager gather mid-loop
+                        arr, j = dev_loss
+                        lv = float(np.asarray(arr)[j])
+                    else:
+                        lv = float(dev_loss)
+                    self.history.append({"step": step_, "loss": lv,
+                                         "epoch": epoch_, "time": t})
+                    logger.info("step %d/%d loss %.4f", step_,
+                                total_steps, lv)
 
         from mmlspark_tpu.utils.profiling import maybe_trace
 
@@ -676,6 +690,10 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                 pending.append((global_step, epoch, loss, _time.time()))
                 flush_logs()
             if ckpt_dir and global_step % ckpt_every == 0:
+                save_checkpoint()
+
+        def save_checkpoint():
+            with _stage("learner.checkpoint", step=global_step):
                 _save_checkpoint(ckpt_dir, global_step, state)
 
         if device_feed:
@@ -765,10 +783,12 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
 
             def get_chunk_fn(length):
                 if length not in chunk_fns:
-                    def f(st, xf, yf, wf, e, s0, _len=length):
+                    # named for its program in a profile
+                    # (jit_learner_chunk)
+                    def learner_chunk(st, xf, yf, wf, e, s0, _len=length):
                         return run_chunk(st, xf, yf, wf, e, s0, _len)
                     chunk_fns[length] = jax.jit(
-                        f,
+                        learner_chunk,
                         in_shardings=(state_sharding,) + row_shardings
                         + (None, None),
                         out_shardings=(state_sharding, None, None),
@@ -799,7 +819,7 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                             (gs, epoch, (losses, j), _time.time()))
                 flush_logs()
                 if ckpt_dir and global_step % ckpt_every == 0:
-                    _save_checkpoint(ckpt_dir, global_step, state)
+                    save_checkpoint()
 
             # steps trace under the mesh: kernels that XLA cannot
             # partition (ring_attention.flash_per_shard) read it
@@ -845,49 +865,45 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                                 state, batch_sds)
                             flops_per_step = _step_flops(
                                 self.step_lowered.compile())
-                        from mmlspark_tpu.utils.profiling import annotate
-                        t_chunk = _time.perf_counter()
-                        if ann_on:
-                            with annotate("learner_chunk"):
-                                state, losses, cnt = fn(
-                                    state, x_dev, y_dev, w_dev,
-                                    np.int32(epoch), np.int32(i))
-                        else:
+                        # the dispatch alone (chunks run async): the
+                        # span shows host-side stalls, the profile's
+                        # device rows the on-chip time
+                        with _stage("learner.chunk", step=base + seg_end,
+                                    epoch=epoch, length=length):
                             state, losses, cnt = fn(
                                 state, x_dev, y_dev, w_dev,
                                 np.int32(epoch), np.int32(i))
                         global_step = base + seg_end
                         chunk_bookkeeping(losses, cnt, length, epoch)
-                        _emit_span("learner.chunk", t_chunk,
-                                   step=global_step, epoch=epoch,
-                                   length=length)
                         _sample_memory(global_step, force=bool(mem_every))
                         i = seg_end
         else:
-            from mmlspark_tpu.utils.profiling import annotate
             feed = make_prefetcher(index_stream(), make_batch, depth=2)
+            batches = iter(feed)
             try:
                 with maybe_trace(self.get("profileDir")), \
                         jax.set_mesh(mesh):
-                    for epoch, global_step, true_len, batch in feed:
-                        t_step = _time.perf_counter()
-                        if ann_on:
-                            with annotate("learner_step"):
-                                state, loss = jit_step(state, batch)
-                        else:
-                            state, loss = jit_step(state, batch)
+                    while True:
+                        # blocked on the prefetcher
+                        with _stage("learner.feed_wait"):
+                            item = next(batches, None)
+                        if item is None:
+                            break
+                        epoch, global_step, true_len, batch = item
                         # dispatch-enqueue wall (steps run async): the
-                        # span shows host-side stalls, the xplane
-                        # annotation shows the on-chip time
-                        _emit_span("learner.step", t_step,
-                                   step=global_step, epoch=epoch)
+                        # span shows host-side stalls, the profile's
+                        # device rows the on-chip time
+                        with _stage("learner.step", step=global_step,
+                                    epoch=epoch):
+                            state, loss = jit_step(state, batch)
                         step_bookkeeping(loss, true_len, epoch)
                         _sample_memory(global_step)
             finally:
                 # abnormal exit must not leave the worker blocked in put()
                 # pinning prefetched batches in HBM
                 feed.close()
-        state = jax.block_until_ready(state)
+        with _stage("learner.final_wait"):
+            state = jax.block_until_ready(state)
         t_end = _time.time()
         if device_feed:
             # resolve the deferred per-chunk row counts (transfers only,
@@ -938,7 +954,7 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                     self.timing["mfu"] = tflops * 1e12 / \
                         peak_flops_per_chip(dev.device_kind)
         if ckpt_dir:
-            _save_checkpoint(ckpt_dir, global_step, state)
+            save_checkpoint()
         if fit_trace is not None:
             fit_trace.root.set("steps", int(global_step))
             fit_trace.root.set("feed",

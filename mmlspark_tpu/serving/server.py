@@ -20,7 +20,6 @@ replicated by jax, no cross-host reply routing is ever needed.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import math
@@ -38,6 +37,7 @@ import numpy as np
 from mmlspark_tpu.core.logging_utils import get_logger
 from mmlspark_tpu.core.stage import Transformer
 from mmlspark_tpu.core.table import DataTable
+from mmlspark_tpu.core.trace import phase, record, use_span
 from mmlspark_tpu.io.http import HTTPSchema, _jsonable as _to_jsonable
 
 log = get_logger("serving")
@@ -97,7 +97,8 @@ class _ParkedRequest:
         self.response: Optional[Dict[str, Any]] = None
         # stamped at enqueue / at leaving the queue; their difference
         # is the queue-wait histogram sample (dequeue stamps are set by
-        # drain_parked/top_up, the two exits from the source queue)
+        # drain_parked/top_up, the two exits from the source queue).
+        # The stamps after these are the batch's (_BatchCtx).
         self.enqueued_at: float = 0.0
         self.dequeued_at: float = 0.0
         # the request's Trace (core.trace) when the engine traces; the
@@ -705,52 +706,63 @@ class PipelineHandle:
             return self._outstanding
 
 
-class _BatchTraceCtx:
-    """Per-micro-batch tracing context, riding the dispatch item from
-    the batcher to the worker (and through retries/rescues) so every
-    stage lands spans on the right request traces.
+class _BatchCtx:
+    """One micro-batch's stamps and request traces, riding the dispatch
+    item from the batcher to the worker (and through retries/rescues),
+    so that every stage of ``core.trace.REQUEST_STAGES`` is cut from
+    shared stamps and lands its span on the right request traces.
 
-    Batch-join semantics: ``batch_span`` creates ONE span that is
-    shared by every member trace and ``links`` each request's root
-    span — one decode/device span explains all N rows it served."""
+    ``sealed_at``: the batcher stopped collecting; ``granted_at``: it
+    holds the in-flight token; ``dispatched_at``: the item is in the
+    dispatch queue; ``taken_at``: a worker has it. ``traces`` (empty
+    when tracing is off) is what a batch-join span is emitted into:
+    one span shared by every member trace, linking each request's
+    root — one decode/device span explains all N rows it served."""
 
-    __slots__ = ("tracer", "traces", "by_rid", "primary", "roots",
-                 "dispatched_at")
+    __slots__ = ("seq", "rows", "sealed_at", "granted_at",
+                 "dispatched_at", "taken_at", "stamps", "by_rid")
 
-    def __init__(self, tracer, parked: List[_ParkedRequest]):
-        self.tracer = tracer
-        self.traces = []
-        self.by_rid: Dict[str, Any] = {}
-        self.roots = []
-        # stamped when the batcher hands the item to the dispatch
-        # queue; the FIRST device span starts here so the worker-wake
-        # handoff is attributed instead of falling between spans
+    def __init__(self, seq: int, parked: List[_ParkedRequest],
+                 sealed_at: float, granted_at: float):
+        self.seq = seq              # engine-wide batch number
+        self.rows = len(parked)
+        self.sealed_at = sealed_at
+        self.granted_at = granted_at
         self.dispatched_at: Optional[float] = None
-        for p in parked:
-            if p.trace is not None:
-                self.traces.append(p.trace)
-                self.by_rid[p.id] = p.trace
-                self.roots.append(p.trace.root)
-        self.primary = self.traces[0] if self.traces else None
+        # consumed once: a rescue/retry re-run starts its device stage
+        # at now and has no dispatch wait
+        self.taken_at: Optional[float] = None
+        # a request's (enqueued, dequeued, token wait begun): one that
+        # a top-up took in after the seal has collected for no time and
+        # waits for the token from its own dequeue on
+        self.stamps = [(p.enqueued_at, p.dequeued_at,
+                        max(sealed_at, p.dequeued_at)) for p in parked]
+        self.by_rid: Dict[str, Any] = {
+            p.id: p.trace for p in parked if p.trace is not None}
 
-    def batch_span(self, name: str, start: Optional[float] = None):
-        if self.primary is None:
-            return None
-        span = self.tracer.start_span(name, self.primary,
-                                      parent=self.primary.root,
-                                      start=start)
-        for root in self.roots:
-            span.link(root.trace_id, root.span_id)
-        for tr in self.traces[1:]:
-            tr.add(span)
-        return span
+    @property
+    def traces(self) -> List[Any]:
+        return list(self.by_rid.values())
 
-    def request_span(self, rid: str, name: str,
-                     start: Optional[float] = None):
-        tr = self.by_rid.get(rid)
-        if tr is None:
-            return None
-        return self.tracer.start_span(name, tr, start=start)
+    def keep(self, rows: List[int], ids: List[str]) -> None:
+        """Cut the batch down to the requests that survived decode."""
+        self.rows = len(rows)
+        self.stamps = [self.stamps[i] for i in rows]
+        self.by_rid = {rid: self.by_rid[rid] for rid in ids
+                       if rid in self.by_rid}
+
+    def wait_sums_us(self, taken_at: float) -> Dict[str, float]:
+        """Each wait before the device stage summed over the rows, in
+        microseconds: what ``serve.execute`` carries into the
+        profiler's file, so that a reader of it gets per-request means
+        and not per-batch ones."""
+        enq, deq, begun = (sum(col) for col in zip(*self.stamps))
+        sums = (deq - enq, begun - deq,
+                self.rows * self.granted_at - begun,
+                self.rows * (taken_at - self.dispatched_at))
+        return {f"{name}_us": round(v * 1e6, 3) for name, v in zip(
+            ("queue_wait", "collect_wait", "token_wait",
+             "dispatch_wait"), sums)}
 
 
 class _PendingGroup:
@@ -954,9 +966,14 @@ class ServingEngine:
         self.batches_processed = 0
         self.workers_restarted = 0
         self._stats_lock = threading.Lock()
-        self.hists = histogram_set("queue_wait_ms", "decode_ms",
-                                   "pipeline_ms", "respond_ms",
-                                   "batch_rows")
+        # one histogram a stage of core.trace.REQUEST_STAGES (the
+        # device stage's is pipeline_ms); the four waits are observed
+        # once a request, the others once a batch
+        self.hists = histogram_set("queue_wait_ms", "collect_wait_ms",
+                                   "token_wait_ms", "decode_ms",
+                                   "dispatch_wait_ms", "pipeline_ms",
+                                   "respond_ms", "batch_rows")
+        self._batch_seq = itertools.count(1)
 
     # -- versioned pipeline access ------------------------------------------
 
@@ -1017,35 +1034,34 @@ class ServingEngine:
             200, "OK", body if isinstance(body, bytes)
             else body.encode("utf-8"), headers))
 
-    def _finish_request_trace(self, tctx: Optional[_BatchTraceCtx],
+    def _finish_request_trace(self, tctx: Optional[_BatchCtx],
                               rid: str, t_answer: float,
                               error: bool = False) -> None:
         """Trace bookkeeping for one reply, BEFORE the respond() event
-        fires: a ``respond`` span covering wait-for-my-turn in the
-        answer loop + this row's flush, then the root closes at
-        reply-enqueue. All trace writes happen before the handler
-        thread (which buffers the finished trace) can wake."""
-        if tctx is None:
+        fires: a ``respond`` span from the end of the device stage
+        (wait-for-my-turn in the answer loop + this row's flush) to
+        now, where the root closes too. All trace writes happen before
+        the handler thread (which buffers the finished trace) can
+        wake."""
+        tr = tctx.by_rid.get(rid) if tctx is not None else None
+        if tr is None:
             return
-        span = tctx.request_span(rid, "respond", start=t_answer)
-        if span is None:
-            return
+        now = time.perf_counter()
+        span = record("respond", t_answer, now, trace=tr)
         if error:
             span.error()
-        span.finish()
-        root = tctx.by_rid[rid].root
-        if error:
-            root.error()
-        root.finish()
+            tr.root.error()
+        tr.root.finish(now)
 
     def _answer_output(self, out: DataTable, ids: List[str],
-                       tctx: Optional[_BatchTraceCtx] = None,
-                       handle: Optional[PipelineHandle] = None) -> None:
+                       tctx: Optional[_BatchCtx], handle: PipelineHandle,
+                       t_answer: float) -> None:
         """Answer one transformed batch, splitting per-row errors: a
         non-null ``error_col`` value means that row failed and gets a
         500 while its batchmates still get their 200s
-        (ref: SimpleHTTPTransformer.scala:104-150 error-split pipeline)."""
-        t_answer = time.perf_counter()
+        (ref: SimpleHTTPTransformer.scala:104-150 error-split pipeline).
+        ``t_answer`` is where the device stage ended: every row's
+        ``respond`` stage starts there."""
         replies = out[self.reply_col]
         out_ids = out[self.id_col]
         errors = (out[self.error_col]
@@ -1084,21 +1100,13 @@ class ServingEngine:
         self._execute_batch(table, ids, None, self._active)
         return len(ids)
 
-    def _device_span(self, tctx: Optional[_BatchTraceCtx],
-                     handle: PipelineHandle, rows: int):
-        """The batch-join device span: ONE span shared by every request
-        trace in the micro-batch, linking their root spans and carrying
-        the version/routing annotations the swap protocol needs to be
-        debuggable. Returns (span, jit_miss_probe, misses_before)."""
-        if tctx is None or tctx.primary is None:
-            return None, None, None
-        start = tctx.dispatched_at     # consumed once: a rescue/retry
-        tctx.dispatched_at = None      # re-run starts its span at now
-        ds = tctx.batch_span("device", start=start)
+    def _annotate_device(self, ds, handle: PipelineHandle, rows: int):
+        """The version/routing annotations the swap protocol needs to
+        be debuggable, on the batch-join device span. Returns
+        (jit_miss_probe, misses_before)."""
         ds.set("model_version", handle.version)
         if handle.model_key is not None:
             ds.set("model", handle.model_key)
-        ds.set("rows", rows)
         if handle.is_canary:
             ds.set("canary", True)
         state = self.swap_state
@@ -1117,37 +1125,54 @@ class ServingEngine:
                 miss0 = int(miss_fn())
             except Exception:  # noqa: BLE001 — annotation only
                 miss_fn = None
-        return ds, miss_fn, miss0
+        return miss_fn, miss0
 
     def _execute_batch(self, table: DataTable, ids: List[str],
                        prepped: Any,
                        handle: Optional[PipelineHandle] = None,
-                       tctx: Optional[_BatchTraceCtx] = None) -> None:
+                       tctx: Optional[_BatchCtx] = None) -> None:
         """Stage 2 of the pipeline: device execution + reply flush for
         one micro-batch (``prepped`` carries stage 1's decode output
         when the pipeline supports the split). The whole batch runs on
         ``handle``'s pipeline version — retries included — so no reply
-        batch ever mixes model versions."""
-        from mmlspark_tpu.core.trace import use_span
+        batch ever mixes model versions. Stages: ``dispatch_wait`` (the
+        item lay in the dispatch queue), ``device`` (from the worker
+        taking it to the output on the host; one batch-join span shared
+        by every request trace of the batch), ``respond``."""
         if handle is None:
             handle = self._active
         # canary handles carry their controller; stable batches report
         # to whatever swap is in flight (the latency-delta baseline)
         ctl = handle.controller if handle.controller is not None \
             else self.__dict__.get("_swap_ctl")
-        ds, miss_fn, miss0 = self._device_span(tctx, handle, len(ids))
-        span_ctx = use_span(ds) if ds is not None \
-            else contextlib.nullcontext()
-        t0 = time.perf_counter()
+        rows = len(ids)
+        attrs = {"rows": rows}
+        traces = taken_at = None
+        if tctx is not None:
+            attrs["batch"] = tctx.seq
+            traces = tctx.traces
+            taken_at, tctx.taken_at = tctx.taken_at, None
+        if taken_at is not None:
+            attrs.update(tctx.wait_sums_us(taken_at))
+            hist = self.hists["dispatch_wait_ms"]
+            wait_ms = (taken_at - tctx.dispatched_at) * 1e3
+            for _ in range(tctx.rows):      # the same for every row
+                hist.observe(wait_ms)
+            record("dispatch_wait", tctx.dispatched_at, taken_at,
+                   trace=traces)
+        miss_fn = None
+        ex = phase("serve.execute", span="device", trace=traces,
+                   start=taken_at, **attrs)
         try:
-            with span_ctx:
+            with ex, use_span(ex.span):
+                if ex.span is not None:
+                    miss_fn, miss0 = self._annotate_device(
+                        ex.span, handle, rows)
                 if prepped is not None and handle.execute is not None:
                     out = handle.execute(table, prepped)
                 else:
                     out = handle.pipeline.transform(table)
         except Exception as e:  # noqa: BLE001 — isolate the poison row(s)
-            if ds is not None:
-                ds.error(e).finish()
             if handle.is_canary and handle.rescue_to is not None:
                 # a canary batch's faults are the SWAP's problem, not
                 # the clients': record the strike and re-execute the
@@ -1156,8 +1181,8 @@ class ServingEngine:
                 log.warning("canary batch failed (%s); rescuing on %s",
                             e, handle.rescue_to.version)
                 if ctl is not None:
-                    ctl.observe(handle, ok=False, latency_ms=(
-                        time.perf_counter() - t0) * 1e3, error=e)
+                    ctl.observe(handle, ok=False, latency_ms=ex.ms,
+                                error=e)
                 self._run_rescued(table, ids, handle.rescue_to, tctx)
                 return
             log.warning("serving batch failed (%s); retrying per-row", e)
@@ -1165,22 +1190,18 @@ class ServingEngine:
                 # per-model SLO stream (batch granularity): the failed
                 # batch is this model's bad event even though per-row
                 # retries may still answer some rows
-                self.slo.record(False,
-                                (time.perf_counter() - t0) * 1e3,
-                                model=handle.model_key,
+                self.slo.record(False, ex.ms, model=handle.model_key,
                                 include_engine=False)
             self._process_rows_individually(table, ids, handle, tctx)
             with self._stats_lock:
                 self.batches_processed += 1
             return
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        if ds is not None:
-            if miss_fn is not None:
-                try:
-                    ds.set("jit_cache_miss", bool(miss_fn() - miss0))
-                except Exception:  # noqa: BLE001 — annotation only
-                    pass
-            ds.finish()
+        dt_ms = ex.ms
+        if miss_fn is not None:
+            try:
+                ex.span.set("jit_cache_miss", bool(miss_fn() - miss0))
+            except Exception:  # noqa: BLE001 — annotation only
+                pass
         if ctl is not None:
             # the controller discards row_errors for stable handles, so
             # only canary batches pay the error-column scan
@@ -1198,35 +1219,38 @@ class ServingEngine:
                 return
             ctl.observe(handle, ok=True, latency_ms=dt_ms,
                         row_errors=row_errors)
-        self.hists["pipeline_ms"].observe(dt_ms)
-        if self.zoo is not None and handle.model_name is not None:
-            # per-model latency (cardinality-capped — serving/zoo.py)
-            self.zoo.observe_latency(handle.model_name, dt_ms)
-        if self.slo is not None and handle.model_key is not None:
-            # per-model SLO stream (engine-level totals come from the
-            # HTTP handler; include_engine=False avoids double count)
-            self.slo.record(True, dt_ms, model=handle.model_key,
-                            include_engine=False)
-        if self.variants is not None and handle.model_key is not None:
-            # the selector's windowed latency/cost profile feed (O(1)
-            # counter writes; decisions happen on the batcher tick)
-            self.variants.observe(handle.model_key, dt_ms, len(ids))
-        t1 = time.perf_counter()
-        try:
-            self._answer_output(out, ids, tctx, handle)
-        except Exception as e:  # noqa: BLE001 — e.g. missing reply column
-            log.warning("answering batch failed (%s); sending 500s", e)
-            for rid in ids:
-                self.source.respond(rid, HTTPSchema.response(
-                    500, f"reply error: {e}", None))
-        self.hists["respond_ms"].observe(
-            (time.perf_counter() - t1) * 1e3)
+        with phase("serve.respond", hist=self.hists["respond_ms"],
+                   start=ex.end, **attrs):
+            self.hists["pipeline_ms"].observe(dt_ms)
+            if self.zoo is not None and handle.model_name is not None:
+                # per-model latency (cardinality-capped — serving/zoo.py)
+                self.zoo.observe_latency(handle.model_name, dt_ms)
+            if self.slo is not None and handle.model_key is not None:
+                # per-model SLO stream (engine-level totals come from
+                # the HTTP handler; include_engine=False avoids double
+                # count)
+                self.slo.record(True, dt_ms, model=handle.model_key,
+                                include_engine=False)
+            if self.variants is not None and handle.model_key is not None:
+                # the selector's windowed latency/cost profile feed
+                # (O(1) counter writes; decisions happen on the batcher
+                # tick)
+                self.variants.observe(handle.model_key, dt_ms, rows)
+            try:
+                self._answer_output(out, ids, tctx, handle, ex.end)
+            except Exception as e:  # noqa: BLE001 — e.g. missing reply
+                # column
+                log.warning("answering batch failed (%s); sending 500s",
+                            e)
+                for rid in ids:
+                    self.source.respond(rid, HTTPSchema.response(
+                        500, f"reply error: {e}", None))
         with self._stats_lock:
             self.batches_processed += 1
 
     def _run_rescued(self, table: DataTable, ids: List[str],
                      rescue: PipelineHandle,
-                     tctx: Optional[_BatchTraceCtx] = None) -> None:
+                     tctx: Optional[_BatchCtx] = None) -> None:
         """Re-execute a failed canary batch on the stable handle,
         COUNTED as in-flight on it: the swap's drain phase polls the
         old handle's outstanding count, so an untracked rescue could
@@ -1251,7 +1275,7 @@ class ServingEngine:
     def _process_rows_individually(self, table: DataTable,
                                    ids: List[str],
                                    handle: Optional[PipelineHandle] = None,
-                                   tctx: Optional[_BatchTraceCtx] = None,
+                                   tctx: Optional[_BatchCtx] = None,
                                    ) -> None:
         """Batch-failure fallback: run each row alone so one poison
         request cannot 500 its batchmates (the per-row half of the
@@ -1262,70 +1286,46 @@ class ServingEngine:
         if handle is None:
             handle = self._active
         requests = table["request"]
+        attrs = {"rows": 1, "retry": True}
+        if tctx is not None:
+            attrs["batch"] = tctx.seq
         for rid, req in zip(ids, requests):
             row = DataTable({"id": [rid], "request": [req]})
-            span = tctx.request_span(rid, "device") if tctx is not None \
-                else None
-            if span is not None:
-                span.set("model_version", handle.version)
-                span.set("rows", 1)
-                span.set("retry", True)
             try:
-                out = handle.pipeline.transform(row)
-                if span is not None:
-                    span.finish()
-                self._answer_output(out, [rid], tctx, handle)
+                with phase("serve.execute", span="device",
+                           trace=tctx and tctx.by_rid.get(rid),
+                           **attrs) as ex:
+                    if ex.span is not None:
+                        ex.span.set("model_version", handle.version)
+                    out = handle.pipeline.transform(row)
+                self._answer_output(out, [rid], tctx, handle, ex.end)
             except Exception as e:  # noqa: BLE001
-                if span is not None:
-                    span.error(e).finish()
                 self.source.respond(rid, HTTPSchema.response(
                     500, f"pipeline error: {e}", None))
 
     def _build_item(self, parked: List[_ParkedRequest],
-                    handle: PipelineHandle) -> Tuple:
+                    handle: PipelineHandle, tctx: _BatchCtx,
+                    dspan=None) -> Optional[Tuple]:
         """Assemble + (optionally) decode one collected batch: the host
-        half of the two-stage pipeline, run on the batcher thread.
-        Tracing: each member request gets a ``queue_wait`` span
-        (ingress enqueue → batch assembly, covering both the source
-        queue AND the adaptive collect window) and the batch gets a
-        shared ``decode`` span; both ride the returned item so the
-        worker's device/respond spans land on the same traces."""
+        half of the two-stage pipeline, run on the batcher thread
+        inside the ``decode`` stage, whose shared span is ``dspan``.
+        ``tctx`` rides the returned item so the worker's stages are cut
+        from the same stamps and land on the same traces."""
         table = DataTable({"id": [p.id for p in parked],
                            "request": [p.request for p in parked]})
         ids = [p.id for p in parked]
-        tctx: Optional[_BatchTraceCtx] = None
-        if self.tracer is not None:
-            ctx = _BatchTraceCtx(self.tracer, parked)
-            if ctx.primary is not None:
-                tctx = ctx
-                t_build = time.perf_counter()
-                for p in parked:
-                    if p.trace is not None:
-                        self.tracer.start_span(
-                            "queue_wait", p.trace,
-                            start=p.enqueued_at).finish(t_build)
         prepped = None
         if handle.prepare is not None and handle.execute is not None:
-            t0 = time.perf_counter()
-            dspan = tctx.batch_span("decode", start=t0) \
-                if tctx is not None else None
-            if dspan is not None:
-                dspan.set("rows", len(ids))
             try:
                 prepped = handle.prepare(table)
-                if dspan is not None:
-                    codecs = getattr(prepped, "codecs", None)
-                    if codecs:
-                        dspan.set("codec",
-                                  ",".join(sorted(codecs)))
-                    dspan.finish()
-                self.hists["decode_ms"].observe(
-                    (time.perf_counter() - t0) * 1e3)
+                codecs = getattr(prepped, "codecs", None)
+                if dspan is not None and codecs:
+                    dspan.set("codec", ",".join(sorted(codecs)))
             except Exception as e:  # noqa: BLE001 — poison rows can die
                 # in decode too: hand the batch over un-prepared so the
                 # worker's per-row retry isolates the offender
                 if dspan is not None:
-                    dspan.error(e).finish()
+                    dspan.error(e)
                 prepped = None
         # per-request codec rejects (columnar ingress, io/columnar.py):
         # a malformed or schema-mismatched body 400s exactly ITS
@@ -1337,21 +1337,17 @@ class ServingEngine:
                 parked, table, ids, rejects, tctx)
             if not ids:
                 return None   # nothing survived decode — no dispatch
-        if tctx is not None:
-            tctx.dispatched_at = time.perf_counter()
         return table, ids, prepped, handle, tctx
 
     def _apply_rejects(self, parked: List[_ParkedRequest],
                        table: DataTable, ids: List[str],
-                       rejects: Dict[str, str], tctx):
+                       rejects: Dict[str, str], tctx: _BatchCtx):
         """Answer 400 for every codec-rejected request (finalizing its
         trace with error=true) and return the filtered (table, ids,
-        trace-context) the surviving batch dispatches with."""
-        kept: List[_ParkedRequest] = []
+        batch context) the surviving batch dispatches with."""
         for p in parked:
             msg = rejects.get(p.id)
             if msg is None:
-                kept.append(p)
                 continue
             if p.trace is not None:
                 p.trace.root.set("codec_error", msg)
@@ -1363,12 +1359,8 @@ class ServingEngine:
         keep_idx = [i for i, rid in enumerate(ids) if rid not in rejects]
         ids = [ids[i] for i in keep_idx]
         table = table._take_indices(np.asarray(keep_idx, dtype=np.int64))
-        new_tctx = None
-        if self.tracer is not None and kept:
-            ctx = _BatchTraceCtx(self.tracer, kept)
-            if ctx.primary is not None:
-                new_tctx = ctx
-        return table, ids, new_tctx
+        tctx.keep(keep_idx, ids)
+        return table, ids, tctx
 
     def _batcher_loop(self):
         """Stage 1 of the pipeline: adaptive collect + (optional) host
@@ -1641,13 +1633,33 @@ class ServingEngine:
                 self._drop_pending(pick_key)
 
     def _dispatch_now(self, parked: List[_ParkedRequest],
-                      handle: Optional[PipelineHandle]) -> None:
+                      handle: Optional[PipelineHandle],
+                      seq: Optional[int] = None,
+                      sealed_at: Optional[float] = None) -> None:
         """Assemble + dispatch ONE micro-batch whose in-flight token is
         ALREADY held (the pump acquired it non-blocking). ``handle`` is
         None for the default (single-model) path — version routing and
         acquisition happen here — or a zoo handle that arrives ALREADY
         acquired (zoo.acquire bumps outstanding under the registry
-        lock, atomically with the eviction scan)."""
+        lock, atomically with the eviction scan). ``seq`` and
+        ``sealed_at`` come from ``_dispatch_parked``, which sealed the
+        batch and then waited for the token; the pump seals a group as
+        it is granted the token, so its requests wait for no token and
+        their time in the group is ``collect_wait``. The ``decode``
+        stage runs from here to the put."""
+        granted_at = time.perf_counter()
+        if seq is None:
+            seq, sealed_at = next(self._batch_seq), granted_at
+        tctx = _BatchCtx(seq, parked, sealed_at, granted_at)
+        # the waits that ended with the grant, a sample a request;
+        # decode_ms and the stages after it are the batch's
+        for p, (enqueued, dequeued, begun) in zip(parked, tctx.stamps):
+            record("queue_wait", enqueued, dequeued, trace=p.trace,
+                   hist=self.hists["queue_wait_ms"])
+            record("collect_wait", dequeued, begun, trace=p.trace,
+                   hist=self.hists["collect_wait_ms"])
+            record("token_wait", begun, granted_at, trace=p.trace,
+                   hist=self.hists["token_wait_ms"])
         # token ownership transfers to the worker ONLY on a
         # successful put; any other exit (assembly failure, a
         # respond() error, a BaseException killing this thread)
@@ -1655,34 +1667,40 @@ class ServingEngine:
         # shrink the engine's dispatch budget
         handed_off = False
         try:
-            if handle is None:
-                # version routing happens HERE, once per batch: the
-                # handle rides with the item so decode, execution,
-                # retries, and replies all use one model version.
-                # acquire() BEFORE any other work, then re-check the
-                # active handle: a cutover landing between route and
-                # acquire would otherwise let the swap's drain poll
-                # read outstanding==0 while this batch is still headed
-                # for the old version.
-                handle = self._route()
-                handle.acquire()
-                if not handle.is_canary and handle is not self._active:
-                    handle.release()
-                    handle = self._active   # stale route: follow cutover
+            with phase("serve.decode", span="decode", trace=tctx.traces,
+                       hist=self.hists["decode_ms"], start=granted_at,
+                       batch=seq, rows=len(parked)) as dec:
+                if handle is None:
+                    # version routing happens HERE, once per batch: the
+                    # handle rides with the item so decode, execution,
+                    # retries, and replies all use one model version.
+                    # acquire() BEFORE any other work, then re-check
+                    # the active handle: a cutover landing between
+                    # route and acquire would otherwise let the swap's
+                    # drain poll read outstanding==0 while this batch
+                    # is still headed for the old version.
+                    handle = self._route()
                     handle.acquire()
-            try:
-                item = self._build_item(parked, handle)
-            except Exception as e:  # noqa: BLE001
-                log.error("batch assembly failed (%s); "
-                          "dropping to 500s", e)
-                for p in parked:
-                    self.source.respond(p.id, HTTPSchema.response(
-                        500, f"batch assembly error: {e}", None))
-                return
-            if item is None:
-                # every request in the batch was codec-rejected
-                # (each already answered 400); nothing to dispatch
-                return
+                    if not handle.is_canary and \
+                            handle is not self._active:
+                        handle.release()
+                        handle = self._active   # stale route: follow
+                        handle.acquire()        # the cutover
+                try:
+                    item = self._build_item(parked, handle, tctx,
+                                            dec.span)
+                except Exception as e:  # noqa: BLE001
+                    log.error("batch assembly failed (%s); "
+                              "dropping to 500s", e)
+                    for p in parked:
+                        self.source.respond(p.id, HTTPSchema.response(
+                            500, f"batch assembly error: {e}", None))
+                    return
+                if item is None:
+                    # every request in the batch was codec-rejected
+                    # (each already answered 400); nothing to dispatch
+                    return
+            item[4].dispatched_at = dec.end
             self._dispatch_q.put(item)   # unbounded: tokens bound it
             handed_off = True
         finally:
@@ -1693,37 +1711,35 @@ class ServingEngine:
                     handle.release()
                 self._inflight.release()
         self._drained_rows.inc(len(parked))
-        for p in parked:
-            # dequeue stamp, not dispatch time: queue_wait must not
-            # absorb the token wait or the decode stage (decode_ms
-            # measures that) — the breakdown stays additive
-            self.hists["queue_wait_ms"].observe(
-                max(0.0, p.dequeued_at - p.enqueued_at) * 1e3)
         self.hists["batch_rows"].observe(float(len(parked)))
 
     def _dispatch_parked(self, parked: List[_ParkedRequest],
                          handle: Optional[PipelineHandle] = None) -> None:
         """Token-gate + assemble + dispatch ONE micro-batch (the
         single-model path; zoo engines go through the continuous
-        ``_pump``). Waits for an in-flight token, topping the pending
+        ``_pump``). The batch is sealed on entry; the ``token_wait``
+        stage is the wait for an in-flight token, topping the pending
         batch up from the queue meanwhile: back-pressure converts
         directly into batch occupancy instead of tiny trailing
         batches."""
+        seq = next(self._batch_seq)
         granted = False
-        while not self._stop.is_set():
-            if self._inflight.acquire(timeout=0.005):
-                granted = True
-                break
-            if self.zoo is None and len(parked) < self.batch_size:
-                try:
-                    self.source.top_up(parked, self.batch_size)
-                except Exception:  # noqa: BLE001 — source closing
-                    pass
+        with phase("serve.token_wait", batch=seq,
+                   rows=len(parked)) as waited:   # rows when sealed
+            while not self._stop.is_set():
+                if self._inflight.acquire(timeout=0.005):
+                    granted = True
+                    break
+                if self.zoo is None and len(parked) < self.batch_size:
+                    try:
+                        self.source.top_up(parked, self.batch_size)
+                    except Exception:  # noqa: BLE001 — source closing
+                        pass
         if not granted:              # stopping — parked requests will
             if handle is not None:   # run out their reply timeout, but
                 handle.release()     # the zoo handle must drain
             return
-        self._dispatch_now(parked, handle)
+        self._dispatch_now(parked, handle, seq, waited.start)
 
     # -- model routing + admission (zoo engines; batcher thread only) -------
 
@@ -1878,6 +1894,7 @@ class ServingEngine:
                 item = self._dispatch_q.get(timeout=0.05)
             except queue.Empty:
                 continue
+            item[4].taken_at = time.perf_counter()
             try:
                 self._execute_batch(*item)
             except Exception as e:  # noqa: BLE001 — keep serving

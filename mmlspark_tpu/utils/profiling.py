@@ -5,7 +5,8 @@ The reference's only tracing is the Timer stage's wall-clock logging
 jax-profiler/xplane integration as the intended TPU upgrade. Any stage
 (Timer's ``traceDir``, TPULearner's ``profileDir``) can wrap its hot
 section in ``maybe_trace`` to emit a TensorBoard-loadable xplane trace of
-the real device timeline.
+the real device timeline. Host stages reach the same file through
+``core.trace.phase``.
 """
 
 from __future__ import annotations
@@ -33,27 +34,6 @@ def trace_files(trace_dir: str) -> List[str]:
     """The xplane protobufs a trace run produced (for tests/tools)."""
     return sorted(glob.glob(
         os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True))
-
-
-@contextlib.contextmanager
-def annotate(name: str, enabled: bool = True) -> Iterator[None]:
-    """Named ``jax.profiler.TraceAnnotation`` around a code block when
-    ``enabled`` (else a no-op): framework spans (core.trace) and the
-    on-chip xplane timeline then share the same phase names, so a
-    device profile row correlates 1:1 with a framework span. Opt-in —
-    annotations cost a TraceMe record per entry even outside an active
-    profiler session."""
-    if not enabled:
-        yield
-        return
-    try:
-        import jax
-        ann = jax.profiler.TraceAnnotation(str(name))
-    except Exception:  # noqa: BLE001 — profiler API absent: still run
-        yield
-        return
-    with ann:
-        yield
 
 
 def device_memory_stats(device=None) -> Optional[dict]:
@@ -108,47 +88,3 @@ def mesh_memory_stats() -> Optional[dict]:
     total["devices"] = len(per_device)
     total["per_device"] = per_device
     return total
-
-
-class MemorySampler:
-    """Background device-memory-stats sampler: a daemon thread snapshots
-    ``memory_stats()`` every ``interval_s`` into a bounded ring, so a
-    training run's framework spans can be read against the on-chip
-    memory curve (``TPULearner(memoryStatsEvery=...)`` uses the inline
-    per-step variant; this is the wall-clock variant for serving)."""
-
-    def __init__(self, interval_s: float = 1.0, capacity: int = 512,
-                 device=None):
-        import collections
-        import threading
-        self.interval_s = float(interval_s)
-        self.device = device
-        self.samples: "collections.deque" = collections.deque(
-            maxlen=int(capacity))
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-
-    def start(self) -> "MemorySampler":
-        import threading
-        import time
-        if self._thread is not None:
-            return self
-
-        def run():
-            while not self._stop.wait(self.interval_s):
-                stats = device_memory_stats(self.device)
-                if stats is not None:
-                    stats["t"] = time.time()
-                    self.samples.append(stats)
-
-        self._thread = threading.Thread(target=run, daemon=True,
-                                        name="mem-sampler")
-        self._thread.start()
-        return self
-
-    def stop(self) -> List[dict]:
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=2 * self.interval_s + 1)
-            self._thread = None
-        return list(self.samples)

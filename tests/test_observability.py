@@ -916,8 +916,7 @@ class TestTrainingTraces:
                 networkSpec={"type": "mlp", "features": [8],
                              "num_classes": 2},
                 epochs=1, batchSize=32, logEvery=1000,
-                computeDtype="float32", memoryStatsEvery=1,
-                traceAnnotations=True)
+                computeDtype="float32", memoryStatsEvery=1)
             learner.fit(DataTable({"features": x, "label": y}))
         finally:
             trace_mod.set_tracer(None)

@@ -106,13 +106,16 @@ CAPPED_FAMILIES = {
 # with "{}" placeholders, as extracted from the JoinedStr.
 DYNAMIC_OK: Dict[str, Tuple[str, ...]] = {
     # engine/fleet per-stage histograms + the warmup family
-    "serving_{}": ("serving_queue_wait_ms", "serving_decode_ms",
-                   "serving_pipeline_ms", "serving_respond_ms",
-                   "serving_batch_rows", "serving_model_warmup_ms"),
+    "serving_{}": ("serving_queue_wait_ms", "serving_collect_wait_ms",
+                   "serving_token_wait_ms", "serving_decode_ms",
+                   "serving_dispatch_wait_ms", "serving_pipeline_ms",
+                   "serving_respond_ms", "serving_batch_rows",
+                   "serving_model_warmup_ms"),
     # pipeline_families: the model's own histogram hooks (TPUModel
-    # pad/device split)
+    # pad/device/readback split)
     "serving_model_{}": ("serving_model_pad_ms",
-                         "serving_model_device_ms"),
+                         "serving_model_device_ms",
+                         "serving_model_readback_ms"),
     # device memory gauges (utils/profiling.device_memory_stats keys)
     "device_memory_{}": ("device_memory_bytes_in_use",
                          "device_memory_bytes_limit",
