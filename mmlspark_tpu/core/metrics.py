@@ -191,6 +191,24 @@ def histogram_set(*names: str) -> Dict[str, LatencyHistogram]:
     return {n: LatencyHistogram() for n in names}
 
 
+class CounterSet:
+    """Monotone event counts under names fixed at construction, so an
+    exporter shows every series from the first scrape on, zeros
+    included. Thread-safe."""
+
+    def __init__(self, *names: str):
+        self._counts = dict.fromkeys(names, 0)
+        self._lock = threading.Lock()
+
+    def inc(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self._counts[name] += n
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._counts)
+
+
 class LabelledHistograms:
     """Per-label ``LatencyHistogram`` family with a HARD cardinality
     cap: the first ``cap`` distinct labels get their own histogram,

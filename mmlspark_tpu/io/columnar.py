@@ -587,9 +587,11 @@ class StagingPool:
 
     The ring depth bounds aliasing: a buffer is not rewritten until
     ``depth`` younger batches have staged, and the engine's in-flight
-    gate (workers + pipeline_depth - 1 batches past the batcher) keeps
-    the number of batches that could still be reading a staging buffer
-    below ``depth``. A fleet shares one scorer across its engines, so
+    gate (workers + pipeline_depth - 1 batches past the batcher;
+    workers + 1 with the default depth, where the engine decides when
+    the batch that runs ahead is let through) keeps the number of
+    batches that could still be reading a staging buffer below
+    ``depth``. A fleet shares one scorer across its engines, so
     the bound is the SUM over engines — the default of 8 covers the
     stock 2-engine x (2 workers + depth 2) deployment; raise ``depth``
     if you raise those knobs.

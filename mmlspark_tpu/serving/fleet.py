@@ -775,7 +775,7 @@ class ServingFleet:
                  hedge_min_s: float = 0.02,
                  max_parked: Optional[int] = None,
                  max_wait_ms: float = 5.0,
-                 pipeline_depth: int = 2,
+                 pipeline_depth: Optional[int] = None,
                  version: str = "v0", tracer=None,
                  tracing: Optional[bool] = None,
                  zoo=None, admission=None,

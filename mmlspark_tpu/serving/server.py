@@ -26,6 +26,7 @@ import math
 import os
 import queue
 import secrets
+import statistics
 import threading
 import time
 import urllib.parse
@@ -646,6 +647,158 @@ class _NoDefaultPipeline:
                            "/models/<name@version> path)")
 
 
+# What a run-ahead grant leaves between the end of the next batch's
+# decode and the expected end of the running batch's device stage: a
+# thread that wakes from a timed wait may stand one switch interval
+# (5 ms) behind the interpreter lock. Every millisecond of it is a
+# millisecond in which an arrival misses the batch it could have joined
+# (on the chip 5 ms against 10: the median request 9-17 ms faster in 4
+# pairs of 4, and no batch of 440 put its decode on the critical path).
+_RUN_AHEAD_MARGIN_S = 0.005
+
+
+class _StageEstimate:
+    """The last few readings of one stage of a handle's batches, in
+    seconds; ``low`` is the least of them and ``middle`` their median,
+    None before the first. A stall of the host only ever adds to a
+    reading, so neither moves with one. The gate takes the device
+    stage ``low``: where batches differ (rows, buckets) the error then
+    seals a batch earlier than it had to, which an engine with a fixed
+    depth does for every batch, and not later, which would put the
+    decode on the critical path. The readings are replaced as a whole,
+    so a reader needs no lock (two writers at once may lose one)."""
+
+    __slots__ = ("_last",)
+
+    def __init__(self):
+        self._last: Tuple[float, ...] = ()
+
+    def observe(self, seconds: float) -> None:
+        self._last = (self._last + (seconds,))[-8:]
+
+    @property
+    def low(self) -> Optional[float]:
+        return min(self._last, default=None)
+
+    @property
+    def middle(self) -> Optional[float]:
+        return statistics.median(self._last) if self._last else None
+
+
+class _InflightGate:
+    """The in-flight gate: how many batches may be past the batcher at
+    once, and WHEN the batch that runs ahead of the workers is let
+    through. ``_dispatch_parked`` (blocking, in slices, topping the
+    pending batch up between them) and ``_pump`` (non-blocking) both
+    acquire here, so the two paths share one rule.
+
+    With an explicit ``depth`` it is a counting semaphore of
+    ``workers + depth - 1`` tokens, each granted as soon as it is free.
+    With ``depth=None`` there are ``workers + 1`` and the engine
+    decides when the last is granted:
+
+    1. A token that a free worker can use (fewer held than workers) is
+       granted at once.
+    2. The run-ahead token (every worker busy) is granted no earlier
+       than the planned time of the batches past the put, the least of
+       ``began + device_est - decode_est - margin`` (``plan``): the
+       pending batch stays open and absorbs arrivals until a worker is
+       about to free, and its decode still overlaps the device.
+    3. It is granted at once where holding buys nothing: the pending
+       batch is ``full``, a batch past the put has ``no_estimate``, or
+       the planned time was already past when the batch first asked
+       (``prep_bound``: what is left of the running step is no longer
+       than the decode, as for every batch of a small model).
+    4. A worker that frees before the planned time wakes the waiter,
+       and rule 1 grants (``early_free``): the decode is then on the
+       critical path once, and the device never waits out a timer.
+
+    ``counters``: batches whose run-ahead grant was deferred (``held``;
+    those that rule 4 cut short are also under ``early_free``) and, by
+    reason, those whose run-ahead grant came at once."""
+
+    def __init__(self, workers: int, depth: Optional[int]):
+        from mmlspark_tpu.core.metrics import CounterSet
+        self.workers = workers
+        self.adaptive = depth is None
+        self.tokens = workers + (1 if depth is None else depth - 1)
+        self.counters = CounterSet("held", "early_free", "full",
+                                   "no_estimate", "prep_bound")
+        self._cond = threading.Condition()
+        self._held = 0
+        # batch seq -> the time before which no run-ahead is granted on
+        # its account (None: its handle has no estimate), from the put
+        # to the release
+        self._plans: Dict[int, Optional[float]] = {}
+        self._holding = False    # the pending batch's grant is deferred
+
+    @property
+    def held(self) -> int:
+        with self._cond:
+            return self._held
+
+    def _grant_in(self, full: bool, now: float) -> float:
+        """Seconds until the pending batch may have a token (0: now;
+        inf: when one comes back). Counts the batch once, when it
+        first asks for the run-ahead token."""
+        if self._held >= self.tokens:
+            return math.inf
+        if not self.adaptive:
+            return 0.0
+        wait, reason = 0.0, None
+        if self._held < self.workers:
+            if self._holding:
+                reason = "early_free"
+        else:
+            plans = self._plans.values()
+            if full:
+                reason = "full"
+            elif not plans or None in plans:
+                reason = "no_estimate"
+            else:
+                wait = max(0.0, min(plans) - now)
+                reason = "held" if wait else "prep_bound"
+            if self._holding:
+                reason = None       # counted when the hold began
+        if reason is not None:
+            self.counters.inc(reason)
+        self._holding = wait > 0
+        return wait
+
+    def acquire(self, full: bool, timeout: float = 0.0) -> bool:
+        """Take a token for the pending batch (``full``: it has
+        ``batch_size`` rows or cannot grow), waiting up to ``timeout``
+        seconds for the grant."""
+        deadline = time.perf_counter() + timeout
+        with self._cond:
+            while True:
+                now = time.perf_counter()
+                wait = self._grant_in(full, now)
+                if wait <= 0:
+                    self._held += 1
+                    return True
+                wait = min(wait, deadline - now)
+                if wait <= 0:
+                    return False
+                self._cond.wait(wait)
+
+    def plan(self, seq: int, not_before: Optional[float]) -> None:
+        """Batch ``seq`` is past the put (and again: a worker took it):
+        the run-ahead waits for ``not_before`` on its account."""
+        if self.adaptive:
+            with self._cond:
+                self._plans[seq] = not_before
+                self._cond.notify_all()
+
+    def release(self, seq: Optional[int] = None) -> None:
+        """Give a token back; ``seq`` names the batch if it was past
+        the put."""
+        with self._cond:
+            self._held -= 1
+            self._plans.pop(seq, None)
+            self._cond.notify_all()
+
+
 class PipelineHandle:
     """One immutable (pipeline, version) binding plus its in-flight
     batch count — the unit of the zero-downtime swap protocol. Every
@@ -663,11 +816,16 @@ class PipelineHandle:
     ``model_name``/``model_key`` are set only on zoo handles
     (serving/zoo.py): a model-routed batch carries the model identity
     through decode/execute/reply, so device spans and reply headers
-    can audit exactly which ``name@version`` served each row."""
+    can audit exactly which ``name@version`` served each row.
+
+    ``decode_est``/``device_est`` are the running estimates of those two
+    stages of this handle's batches: what the in-flight gate plans the
+    run-ahead grant from (``_InflightGate``)."""
 
     __slots__ = ("pipeline", "version", "precision", "aot", "prepare",
                  "execute", "is_canary", "controller", "rescue_to",
-                 "model_name", "model_key", "_outstanding", "_lock")
+                 "model_name", "model_key", "decode_est", "device_est",
+                 "_outstanding", "_lock")
 
     def __init__(self, pipeline: Transformer, version: str,
                  is_canary: bool = False):
@@ -689,6 +847,8 @@ class PipelineHandle:
         self.rescue_to: Optional["PipelineHandle"] = None
         self.model_name: Optional[str] = None
         self.model_key: Optional[str] = None
+        self.decode_est = _StageEstimate()
+        self.device_est = _StageEstimate()
         self._outstanding = 0
         self._lock = threading.Lock()
 
@@ -815,7 +975,8 @@ class ServingEngine:
                  batch_size: int = 64,
                  content_type: str = "application/json",
                  error_col: str = "error", workers: int = 1,
-                 max_wait_ms: float = 5.0, pipeline_depth: int = 2,
+                 max_wait_ms: float = 5.0,
+                 pipeline_depth: Optional[int] = None,
                  version: str = "v0", tracer=None,
                  tracing: Optional[bool] = None,
                  zoo=None, admission=None,
@@ -945,17 +1106,21 @@ class ServingEngine:
         # batching policy: flush on batch_size rows OR max_wait_ms
         # elapsed since the batch's first request, whichever first
         self.max_wait_ms = float(max_wait_ms)
-        # in-flight gating: at most workers + (pipeline_depth - 1)
-        # batches past the batcher at once — every worker busy plus a
-        # bounded run-ahead of prepared batches. While no token is
-        # free (device saturated) the batcher keeps ABSORBING queued
+        # in-flight gating (_InflightGate): every worker busy plus a
+        # bounded run-ahead of prepared batches. While the gate grants
+        # nothing (device saturated) the batcher keeps ABSORBING queued
         # requests into the pending batch, so occupancy rises exactly
         # when the device is the bottleneck; without the gate, a burst
         # dispatches as many tiny batches as there are slots and pays
         # the fixed per-batch cost once per row instead of per batch.
-        self.pipeline_depth = max(1, int(pipeline_depth))
-        self._inflight = threading.Semaphore(
-            self.workers + self.pipeline_depth - 1)
+        # pipeline_depth=None: one batch runs ahead, and the gate lets
+        # it through when a worker is about to take it, by the handle's
+        # own stage estimates, so that it is not sealed a model step
+        # early to lie in the dispatch queue. An integer: workers +
+        # depth - 1 tokens, each granted as soon as it is free.
+        self.pipeline_depth = None if pipeline_depth is None \
+            else max(1, int(pipeline_depth))
+        self._inflight = _InflightGate(self.workers, self.pipeline_depth)
         self._dispatch_q: "queue.Queue[Tuple]" = queue.Queue()
         self._stop = threading.Event()
         self._killed = threading.Event()   # chaos kill: no restart
@@ -1222,6 +1387,7 @@ class ServingEngine:
         with phase("serve.respond", hist=self.hists["respond_ms"],
                    start=ex.end, **attrs):
             self.hists["pipeline_ms"].observe(dt_ms)
+            handle.device_est.observe(dt_ms / 1e3)
             if self.zoo is not None and handle.model_name is not None:
                 # per-model latency (cardinality-capped — serving/zoo.py)
                 self.zoo.observe_latency(handle.model_name, dt_ms)
@@ -1366,11 +1532,15 @@ class ServingEngine:
         """Stage 1 of the pipeline: adaptive collect + (optional) host
         decode/pad, feeding the bounded dispatch queue. While a worker
         drives the device for batch N, this thread is already
-        collecting and decoding batch N+1 — host work overlaps device
-        work instead of serializing with it. While the dispatch queue
-        is full (workers saturated), the pending batch keeps absorbing
-        newly-queued requests up to batch_size, so batches grow toward
-        full occupancy exactly when the device is the bottleneck.
+        collecting batch N+1, and decodes it as the gate lets it
+        through — host work overlaps device work instead of
+        serializing with it. While the gate grants nothing (workers
+        saturated), the pending batch keeps absorbing newly-queued
+        requests up to batch_size, so batches grow toward full
+        occupancy exactly when the device is the bottleneck. With the
+        default depth the gate lets batch N+1 through as batch N is
+        about to end (``_InflightGate``): it is sealed and decoded
+        just as the worker frees, not a model step before.
 
         With a model zoo attached the plane is CONTINUOUS and
         MODEL-ROUTED (Orca-style iteration-level scheduling, OSDI'22,
@@ -1378,13 +1548,13 @@ class ServingEngine:
         whatever is queued RIGHT NOW into per-model pending groups
         (admission + variant routing at ingest), then ``_pump``
         dispatches every group that is ready (full or aged past
-        ``max_wait_ms``) for which an in-flight token is free —
+        ``max_wait_ms``) for which the gate grants a token —
         non-blocking, oldest-first within priority. A slow model's
         group waiting on a token no longer blocks another model's
         admission or dispatch (the old loop dispatched groups
         sequentially, BLOCKING on the token inside each one), and
         newly parked requests join their model's next dispatch slot
-        the moment a pipeline-depth token frees. Batches still never
+        the moment the gate grants one. Batches still never
         mix models, and cold models still activate on the zoo's
         loader thread while their requests park in ``_awaiting``."""
         while not self._stop.is_set():
@@ -1542,12 +1712,14 @@ class ServingEngine:
             self.zoo.remove_waiter(key)
 
     def _pump(self) -> None:
-        """Dispatch every READY unit an in-flight token can cover,
+        """Dispatch every READY unit the in-flight gate lets through,
         oldest-first within priority (batcher thread only). Units are
         ready-lane chunks (always dispatchable: handle in hand) and
         pending groups that are full or older than ``max_wait_ms``.
         The token acquire is NON-blocking: when the device is
-        saturated the pump returns and groups keep absorbing arrivals
+        saturated, or the gate holds the run-ahead token for the
+        running batch's planned end, the pump returns (the loop asks
+        again within 2 ms) and groups keep absorbing arrivals
         — back-pressure becomes batch occupancy, exactly like the old
         top-up loop, but per model. Oldest-first ordering is the
         fairness bound: a continuously-fed hot model re-forms its
@@ -1573,7 +1745,10 @@ class ServingEngine:
                     best, pick_ready, pick_key = rank, -1, key
             if best is None:
                 return              # nothing ready
-            if not self._inflight.acquire(blocking=False):
+            # a ready-lane chunk cannot grow, so holding it buys nothing
+            if not self._inflight.acquire(
+                    full=pick_ready >= 0 or len(
+                        self._pending[pick_key].reqs) >= self.batch_size):
                 return              # saturated: groups keep absorbing
             if pick_ready >= 0:
                 entry = self._ready.pop(pick_ready)
@@ -1701,6 +1876,10 @@ class ServingEngine:
                     # (each already answered 400); nothing to dispatch
                     return
             item[4].dispatched_at = dec.end
+            handle.decode_est.observe(dec.end - granted_at)
+            # a free worker takes it at once; if none is free the
+            # worker's own plan replaces this one when it does
+            self._plan_run_ahead(item, dec.end)
             self._dispatch_q.put(item)   # unbounded: tokens bound it
             handed_off = True
         finally:
@@ -1709,25 +1888,38 @@ class ServingEngine:
                 # must come back on any non-dispatch exit
                 if handle is not None:
                     handle.release()
-                self._inflight.release()
+                self._inflight.release(seq)
         self._drained_rows.inc(len(parked))
         self.hists["batch_rows"].observe(float(len(parked)))
+
+    def _plan_run_ahead(self, item: Tuple, began: float) -> None:
+        """Tell the gate when the batch of ``item``, whose device stage
+        began (or is about to begin) at ``began``, is expected to free
+        its worker, less the time the next batch's decode takes."""
+        handle, tctx = item[3], item[4]
+        device_s, decode_s = handle.device_est.low, handle.decode_est.middle
+        self._inflight.plan(
+            tctx.seq, None if device_s is None or decode_s is None else
+            began + device_s - decode_s - _RUN_AHEAD_MARGIN_S)
 
     def _dispatch_parked(self, parked: List[_ParkedRequest],
                          handle: Optional[PipelineHandle] = None) -> None:
         """Token-gate + assemble + dispatch ONE micro-batch (the
         single-model path; zoo engines go through the continuous
         ``_pump``). The batch is sealed on entry; the ``token_wait``
-        stage is the wait for an in-flight token, topping the pending
-        batch up from the queue meanwhile: back-pressure converts
-        directly into batch occupancy instead of tiny trailing
+        stage is the wait for the gate's grant, in slices of 5 ms,
+        topping the pending batch up from the queue between them:
+        back-pressure, and the gate's hold of the run-ahead token,
+        convert directly into batch occupancy instead of tiny trailing
         batches."""
         seq = next(self._batch_seq)
         granted = False
         with phase("serve.token_wait", batch=seq,
                    rows=len(parked)) as waited:   # rows when sealed
             while not self._stop.is_set():
-                if self._inflight.acquire(timeout=0.005):
+                if self._inflight.acquire(
+                        full=len(parked) >= self.batch_size,
+                        timeout=0.005):
                     granted = True
                     break
                 if self.zoo is None and len(parked) < self.batch_size:
@@ -1748,9 +1940,11 @@ class ServingEngine:
         queued behind busy workers PLUS requests backed up in the
         source queue PLUS the continuous batcher's admitted-but-
         undispatched backlog (pending groups + the ready lane). The
-        dispatch queue alone is bounded by the in-flight token count
-        (workers + pipeline_depth - 1, typically 2-3), which would
-        leave the default tier limits unreachable; and the continuous
+        dispatch queue alone is bounded by the gate's token count
+        (``_inflight.tokens``: workers + 1 by default, typically 2-3),
+        and with the default depth a batch is let into it only as a
+        worker is about to free, which would leave the default tier
+        limits unreachable; and the continuous
         batcher drains the source queue eagerly, so WITHOUT the
         pending/ready terms overload would hide in groups the old
         queue-depth signal never saw."""
@@ -1896,6 +2090,7 @@ class ServingEngine:
                 continue
             item[4].taken_at = time.perf_counter()
             try:
+                self._plan_run_ahead(item, item[4].taken_at)
                 self._execute_batch(*item)
             except Exception as e:  # noqa: BLE001 — keep serving
                 log.error("serving loop error (continuing): %s", e)
@@ -1906,7 +2101,7 @@ class ServingEngine:
                 # handle must drain even on a crashed batch, or a swap
                 # would wait on its outstanding count forever
                 item[3].release()
-                self._inflight.release()
+                self._inflight.release(item[4].seq)
 
     def _spawn_worker(self) -> threading.Thread:
         t = threading.Thread(target=self._worker_loop, daemon=True)
@@ -1973,6 +2168,16 @@ class ServingEngine:
                 "swaps_rolled_back": self.swaps_rolled_back,
             }
 
+    def _run_ahead_counts(self) -> Dict[str, Any]:
+        """The gate's counters under their exported names: batches
+        whose run-ahead grant was deferred, those granted at once by
+        reason, and those that a worker freeing early cut short. Held
+        over all three is how often the late seal engages."""
+        counts = self._inflight.counters.snapshot()
+        return {"run_ahead_held_total": counts.pop("held"),
+                "run_ahead_early_free_total": counts.pop("early_free"),
+                "run_ahead_immediate_total": counts}
+
     def metrics(self) -> Dict[str, Any]:
         """Hot-path latency breakdown: engine histograms (queue wait,
         decode, pipeline, respond, batch occupancy) plus whatever the
@@ -1981,6 +2186,7 @@ class ServingEngine:
         counter). Exported on /healthz."""
         active, out = self._lifecycle_snapshot()
         out.update({k: h.summary() for k, h in self.hists.items()})
+        out.update(self._run_ahead_counts())
         with self._stats_lock:
             if self.rejections:
                 out["rejections"] = dict(self.rejections)
@@ -2073,6 +2279,20 @@ class ServingEngine:
         for name, hist in self.hists.items():
             r.histogram(f"serving_{name}",
                         "engine hot-path stage distribution", hist)
+        run_ahead = self._run_ahead_counts()
+        r.counter("serving_run_ahead_held_total",
+                  "batches whose run-ahead token was granted late, as a "
+                  "worker was about to free",
+                  run_ahead["run_ahead_held_total"])
+        r.counter("serving_run_ahead_early_free_total",
+                  "held batches granted early because a worker freed "
+                  "before the planned time",
+                  run_ahead["run_ahead_early_free_total"])
+        for reason, n in run_ahead["run_ahead_immediate_total"].items():
+            r.counter("serving_run_ahead_immediate_total",
+                      "batches whose run-ahead token was granted at once, "
+                      "by reason (full, no_estimate, prep_bound)",
+                      n, {"reason": reason})
         ctl = self.__dict__.get("_swap_ctl")
         if ctl is not None:
             try:
@@ -2260,7 +2480,7 @@ def serve_model(pipeline: Optional[Transformer] = None,
                 port: int = 8899, batch_size: int = 64,
                 reply_col: str = "reply",
                 workers: int = 1, max_wait_ms: float = 5.0,
-                pipeline_depth: int = 2,
+                pipeline_depth: Optional[int] = None,
                 version: str = "v0", tracer=None,
                 tracing: Optional[bool] = None,
                 zoo=None, admission=None,
