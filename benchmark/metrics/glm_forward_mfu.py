@@ -1,0 +1,17 @@
+"""The ``latent_moe_lm`` step's share of the bf16 peak while it runs:
+the operations that the rows the window answered need
+(``flops_glm_dsa``: selected pairs only, the experts held only, the
+pairs routed here as the program counted them, padded rows not
+counted) over the device's busy time in the trace."""
+
+
+def read(ctx):
+    from flops_glm_dsa import forward_flops_per_row
+    t, peak, c = ctx.get("trace"), ctx.get("peak"), ctx["counters"]
+    if not t or not peak or not c.get("rows_ok") or t["busy_s"] <= 0:
+        return None
+    spec = ctx["cell"]["config_file"]["networkSpec"]
+    need = forward_flops_per_row(spec, c["seq"], c.get("moe_tokens_held")) \
+        * c["rows_ok"]
+    return 100.0 * need / (t["busy_s"] * peak["bf16_flops"]
+                           * ctx["cell"]["chips"])

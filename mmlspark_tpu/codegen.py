@@ -44,9 +44,14 @@ STAGE_MODULES = [
 
 
 def load_all_stages() -> Dict[str, Type[PipelineStage]]:
+    """Every registered stage that can be imported by name. A class
+    built inside a function (serving/aot.py's model class, made on
+    first load) is registered or not depending on what ran before, and
+    is no API."""
     for m in STAGE_MODULES:
         importlib.import_module(m)
-    return dict(STAGE_REGISTRY)
+    return {name: cls for name, cls in STAGE_REGISTRY.items()
+            if "<locals>" not in cls.__qualname__}
 
 
 def stage_kind(cls: Type[PipelineStage]) -> str:

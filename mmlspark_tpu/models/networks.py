@@ -340,12 +340,21 @@ class Transformer(nn.Module):
 # registry + spec construction (BrainScriptBuilder analog)
 # ---------------------------------------------------------------------------
 
+def _latent_moe_lm(**spec) -> nn.Module:
+    # a file of its own: latent attention, the sparse selector and the
+    # expert layers share nothing with the families above
+    from mmlspark_tpu.models.latent_moe_lm import (
+        LatentMoEConfig, LatentMoELM)
+    return LatentMoELM(LatentMoEConfig(**spec))
+
+
 NETWORK_REGISTRY: Dict[str, Callable[..., nn.Module]] = {
     "mlp": MLP,
     "convnet": ConvNet,
     "resnet": ResNet,
     "bilstm": BiLSTMTagger,
     "transformer": Transformer,
+    "latent_moe_lm": _latent_moe_lm,
 }
 
 

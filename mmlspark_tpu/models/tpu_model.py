@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mmlspark_tpu.core.metrics import histogram_set
+from mmlspark_tpu.core.metrics import LatencyHistogram, histogram_set
 from mmlspark_tpu.core.params import (
     DictParam, EnumParam, HasInputCol, HasOutputCol, IntParam, PyTreeParam,
     StringParam, UDFParam,
@@ -41,6 +41,9 @@ from mmlspark_tpu.parallel import mesh as mesh_lib
 # power of two from here, so the compiled-executable set stays
 # log2(batchSize)-sized (see TPUModel.bucket_sizes)
 MIN_BUCKET = 8
+# model outputs under this prefix are per-row numbers for the model's
+# histograms, not columns (see TPUModel.transform's flush)
+STAT_PREFIX = "stat."
 
 
 def _column_to_array(col, field: Field, dtype) -> np.ndarray:
@@ -138,7 +141,12 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         fn = _FlaxApply(module, method)
         if not (isinstance(variables, dict) and "params" in variables):
             variables = {"params": variables}
-        return TPUModel(modelFn=fn, weights=dict(variables), **kw)
+        model = TPUModel(modelFn=fn, weights=dict(variables), **kw)
+        # a module's per-row counters (``row_stats``) are histograms of
+        # the model from the start, so that an exporter sees them all
+        for name in getattr(module, "row_stats", ()):
+            model._hists[name] = LatencyHistogram(unit="count")
+        return model
 
     # -- mesh / jit management ----------------------------------------------
 
@@ -599,8 +607,14 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                        hist=self._hists["readback_ms"],
                        rows=true_len) as read:
                 host = [np.asarray(d) for d in device]
+                stats = {k[len(STAT_PREFIX):]: np.asarray(v)[:true_len]
+                         for k, v in outputs.items()
+                         if k.startswith(STAT_PREFIX)}
             for out_col, val in zip(fetches, host):
                 out_cols[out_col].append(val[:true_len])
+            for name, per_row in stats.items():
+                for value in per_row:
+                    self._hists[name].observe(value)
             # dispatch -> readback-complete: the device round trip as
             # the serving path experiences it (async dispatch means the
             # compiled call alone measures nothing)
@@ -677,4 +691,12 @@ class _FlaxApply:
                                 and "params" in weights) else {"params": weights}
         if self.method is not None:
             return self.module.apply(variables, *args, method=self.method)
-        return self.module.apply(variables, *args)
+        names = getattr(self.module, "row_stats", ())
+        if not names:
+            return self.module.apply(variables, *args)
+        # a module that sows per-row numbers into "stats" hands them
+        # out beside its output, one (rows,) array a name
+        variables = {k: v for k, v in variables.items() if k != "stats"}
+        out, sown = self.module.apply(variables, *args, mutable=["stats"])
+        return {"output": out,
+                **{STAT_PREFIX + n: sown["stats"][n][-1] for n in names}}

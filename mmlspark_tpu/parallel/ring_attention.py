@@ -113,7 +113,39 @@ def attention(q, k, v, causal: bool = False,
     return dense_attention(q, k, v, causal, q_offset, k_offset)
 
 
-def flash_per_shard(flash, mesh, q, k, v):
+def dense_selected_attention(q, k, v, keep) -> jnp.ndarray:
+    """The einsum path of ``selected_attention``: q, k, v (B, H, L, D),
+    ``keep`` (B, Lq, Lk) shared by the heads; float32 scores."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(keep[:, None], s, NEG_INF)
+    p = jnp.where(keep[:, None], jax.nn.softmax(s, axis=-1), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def selected_attention(q, k, v, keep, causal: bool = True) -> jnp.ndarray:
+    """Attention over a set of keys chosen per query (a learned sparse
+    selector's output), which neither ``attention``'s masks nor the
+    flash kernel's express: ``keep`` (B, Lq, Lk) is true where query t
+    may attend to key s, the same for every head. Heads-major q, k, v
+    (B, H, L, D), q already scaled. Long sequences on TPU run the
+    Pallas kernel of ops/selected_attention.py, which never holds an
+    (Lq, Lk) score per head; short ones the masked einsum."""
+    if (jax.default_backend() == "tpu"
+            and q.shape[2] >= FLASH_MIN_LEN
+            and k.shape[2] >= FLASH_MIN_LEN):
+        from mmlspark_tpu.ops.selected_attention import (
+            selected_attention as kernel)
+        kernel = functools.partial(kernel, causal=causal)
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.size > 1 and not mesh.manual_axes:
+            return flash_per_shard(kernel, mesh, q, k, v, keep)
+        return kernel(q, k, v, keep)
+    return dense_selected_attention(q, k, v, keep)
+
+
+def flash_per_shard(flash, mesh, *operands):
     """XLA cannot partition a Mosaic kernel: inside a jit over several
     devices the call must sit in a shard_map or it does not lower. The
     caller says which devices by tracing under ``jax.set_mesh`` (the
@@ -121,9 +153,9 @@ def flash_per_shard(flash, mesh, q, k, v):
     it divides and everything else is replicated — the layout both of
     them feed."""
     n = mesh.shape.get(DATA_AXIS, 1)
-    spec = P(DATA_AXIS) if n > 1 and q.shape[0] % n == 0 else P()
-    return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    spec = P(DATA_AXIS) if n > 1 and operands[0].shape[0] % n == 0 else P()
+    return shard_map(flash, mesh=mesh, in_specs=(spec,) * len(operands),
+                     out_specs=spec, check_vma=False)(*operands)
 
 
 # ring shards at least this long run each hop through the Pallas flash
