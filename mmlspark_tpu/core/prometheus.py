@@ -200,9 +200,9 @@ def process_families(r: PromRenderer, tracer: Any = None) -> None:
 def pipeline_families(r: PromRenderer, pipeline: Any,
                       labels: Optional[Dict[str, Any]] = None) -> None:
     """The duck-typed pipeline surface (model histograms, jit-cache
-    misses, drift monitor) rendered once — shared by the engine's and
-    the fleet's expositions so a new pipeline hook is wired in ONE
-    place."""
+    misses, weight-placement counters, drift monitor) rendered once —
+    shared by the engine's and the fleet's expositions so a new
+    pipeline hook is wired in ONE place."""
     model_hists = getattr(pipeline, "histograms", None)
     if callable(model_hists):
         try:
@@ -218,6 +218,20 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
             r.counter("serving_jit_cache_misses_total",
                       "XLA compiles triggered by the serving forward "
                       "(steady state should be flat)", miss_fn(), labels)
+        except Exception:  # noqa: BLE001 — stats stay partial
+            pass
+    stage_metrics = getattr(pipeline, "metrics", None)
+    if callable(stage_metrics):
+        try:
+            m = stage_metrics()
+            if "weights_cast_leaves" in m:
+                r.gauge("serving_model_weights_cast_leaves",
+                        "weight leaves placed on the device in a "
+                        "narrower dtype than held: the one the model "
+                        "fn reads", m["weights_cast_leaves"], labels)
+                r.gauge("serving_model_weights_cast_bytes",
+                        "weight bytes a model call no longer reads "
+                        "for it", m["weights_cast_bytes"], labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

@@ -13,17 +13,22 @@ multi-input/output maps follow CNTKModel.scala:206-225; input coercion
 
 The weights are device-resident and replicated across the mesh — the
 analog of the reference's broadcast + ``ParameterCloningMethod.Share``
-(:83) without any copy per partition.
+(:83) without any copy per partition. The ``weights`` Param keeps what
+the caller gave; the device copy holds each leaf in the dtype the model
+function reads it in (``TPUModel._weights_on_device``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Tuple,
+)
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jax_core
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mmlspark_tpu.core.metrics import LatencyHistogram, histogram_set
@@ -46,6 +51,66 @@ MIN_BUCKET = 8
 STAT_PREFIX = "stat."
 
 
+class _Reading(NamedTuple):
+    """One abstract trace of a model function at one bucket."""
+    signature: Dict[str, Tuple]     # the inputs it was traced at
+    jaxpr: Any                      # ClosedJaxpr over (weights, inputs)
+    out_tree: Any                   # structure of the function's output
+    dtypes: List[Any]               # the dtype it reads each leaf in
+
+
+def _read_model_fn(model_fn: Callable, weights: Any,
+                   inputs: Dict[str, Any]) -> _Reading:
+    """Trace ``model_fn`` once at ``inputs`` and read from its jaxpr the
+    dtype it reads each leaf of ``weights`` in, in flattening order.
+
+    A floating leaf whose EVERY use is a ``convert_element_type`` to one
+    narrower floating dtype is read in that dtype: the cast is a pure
+    function of the leaf, and left in the program XLA fuses it into the
+    product that reads it, where the product's tile loop repeats it (a
+    float32 kernel of GPT-2-XL was streamed from HBM 32 times a step).
+    Any other use — raw, sliced first, passed whole into a sub-jaxpr
+    (``pjit``, ``scan``, a Pallas call), returned, raised to a wider
+    dtype — and every leaf that is not floating (int8 kernels) reads
+    the leaf as held."""
+    n = len(jax.tree_util.tree_leaves(weights))
+    closed, out_shape = jax.make_jaxpr(model_fn, return_shape=True)(
+        weights, inputs)
+    jaxpr = closed.jaxpr
+    held = jaxpr.invars[:n]
+    # per leaf, the target dtype of each convert that reads it; None
+    # stands for any other use
+    uses: Dict[Any, set] = {v: set() for v in held}
+    for eqn in jaxpr.eqns:
+        to = None
+        if eqn.primitive.name == "convert_element_type" \
+                and not eqn.params.get("weak_type") \
+                and eqn.params.get("sharding") is None:
+            to = eqn.params["new_dtype"]
+        for v in eqn.invars:
+            if isinstance(v, jax_core.Var) and v in uses:
+                uses[v].add(to)
+    for v in jaxpr.outvars:
+        if isinstance(v, jax_core.Var) and v in uses:
+            uses[v].add(None)
+    dtypes = []
+    for v in held:
+        dt = v.aval.dtype
+        if len(uses[v]) == 1 and jnp.issubdtype(dt, jnp.floating):
+            to, = uses[v]
+            if to is not None and jnp.issubdtype(to, jnp.floating) \
+                    and jnp.dtype(to).itemsize < dt.itemsize:
+                dt = jnp.dtype(to)
+        dtypes.append(dt)
+    return _Reading(_signature(inputs), closed,
+                    jax.tree_util.tree_structure(out_shape), dtypes)
+
+
+def _signature(inputs: Dict[str, Any]) -> Dict[str, Tuple]:
+    return {k: (tuple(v.shape), jnp.dtype(v.dtype))
+            for k, v in inputs.items()}
+
+
 def _column_to_array(col, field: Field, dtype) -> np.ndarray:
     """Coerce a table column into a dense batch array
     (ref: CNTKModel.scala:419-462 coerceDFAndFeedDict)."""
@@ -65,6 +130,17 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
 
     The model is ``model_fn(weights, inputs: dict[str, Array]) ->
     dict[str, Array] | Array``. Use ``from_flax`` / ``from_fn`` to build.
+
+    What is held where: the ``weights`` Param is the tree as given
+    (``save``, ``quantize`` and ``device_op`` read it). The
+    device copy the forward runs on holds each leaf in the dtype
+    ``model_fn`` reads it in: a floating leaf the function only ever
+    converts to one narrower floating dtype (a float32 kernel under
+    ``nn.Dense(dtype=bfloat16)``) is converted once as it is placed,
+    every other leaf is placed as held (``_read_model_fn``). The outputs
+    are the same numbers; the step stops reading and converting the
+    wider copy on every call. ``metrics()`` counts the converted leaves
+    (``weights_cast_leaves``, ``weights_cast_bytes``).
     """
 
     modelFn = UDFParam("callable (weights, inputs dict) -> outputs", default=None)
@@ -102,6 +178,12 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         self.aot = False
         self._jitted: Dict[Tuple, Callable] = {}
         self._device_weights = None
+        # the trace of modelFn the weights were placed by: the dtype
+        # it reads each leaf in, decided once per (modelFn, weights) at
+        # the first bucket and held to by every later one
+        self._placement: Optional[_Reading] = None
+        # leaves placed narrower than held, and the bytes that saves
+        self._cast = (0, 0)
         # lazy init is shared mutable state; concurrent first calls
         # (multi-worker serving engines) must not race it — a race would
         # device_put N transient copies of the full weight tree
@@ -121,10 +203,17 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         self._hists = histogram_set("pad_ms", "device_ms", "readback_ms")
 
     def _on_param_change(self, name: str) -> None:
-        if name == "weights":
-            self._device_weights = None
-        elif name == "modelFn":
+        if name in ("weights", "modelFn"):
+            self._unplace()
+        if name == "modelFn":
             self._jitted = {}
+
+    def _unplace(self) -> None:
+        """Forget the device copy of the weights and the decision it
+        was placed by; the next call places them again."""
+        self._device_weights = None
+        self._placement = None
+        self._cast = (0, 0)
 
     # -- constructors -------------------------------------------------------
 
@@ -153,7 +242,7 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
     def set_mesh(self, mesh: Optional[Mesh]) -> "TPUModel":
         self._mesh = mesh
         self._jitted = {}
-        self._device_weights = None
+        self._unplace()
         return self
 
     def set_sharding(self, mesh: Mesh, weight_specs: Any = None,
@@ -225,7 +314,7 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         }
         self._mesh = mesh
         self._jitted = {}
-        self._device_weights = None
+        self._unplace()
         return self
 
     @property
@@ -239,33 +328,91 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                     self._mesh = mesh_lib.make_mesh()
         return self._mesh
 
-    def _weights_on_device(self):
-        """Replicate weights across the mesh once (broadcast analog,
-        ref: CNTKModel.scala:413 rebroadcastCNTKModel). Double-checked
-        locking: thread-safe under multi-worker serving."""
-        if self._device_weights is None:
-            m = self._get_mesh()
+    def _weights_on_device(self, inputs: Optional[Dict[str, Any]] = None):
+        """The weights on the mesh, placed once (broadcast analog, ref:
+        CNTKModel.scala:413 rebroadcastCNTKModel): replicated, or split
+        per the declared specs of ``set_sharding``. With the first
+        bucket's ``inputs`` each leaf lands in the dtype ``modelFn``
+        reads it in (``_read_model_fn``), converted on the device from
+        the held value; a caller with no inputs to show (a byte count
+        before the first batch) gets the leaves as held, and the first
+        batch converts what it must from those. Double-checked locking:
+        thread-safe under multi-worker serving."""
+        if self._device_weights is None or (
+                self._placement is None and inputs is not None):
+            self._get_mesh()
             with self._init_lock:
-                if self._device_weights is None:
-                    if self._sharding is not None:
-                        # per-leaf declared placement: sharded leaves
-                        # land split across the mesh (per-device
-                        # resident bytes < the total weight bytes)
-                        self._device_weights = jax.tree_util.tree_map(
-                            lambda a, s: jax.device_put(
-                                jnp.asarray(a), s),
-                            self.get("weights"),
-                            self._sharding["weight_shardings"])
-                    else:
-                        repl = NamedSharding(m, P())
-                        self._device_weights = jax.tree_util.tree_map(
-                            lambda a: jax.device_put(jnp.asarray(a),
-                                                     repl),
-                            self.get("weights"))
+                reading = None
+                if self._placement is None and inputs is not None:
+                    reading = self._placement = self._read(inputs)
+                if self._device_weights is None or reading is not None:
+                    self._place(reading.dtypes if reading else None)
         return self._device_weights
 
+    def _read(self, inputs: Dict[str, Any]) -> _Reading:
+        """One abstract trace of ``modelFn`` at the bucket of ``inputs``,
+        under the mesh its caller (``transform``'s dispatch) has set. A
+        reading whose ``dtypes`` is None (a model with no function to
+        trace, ``serving/aot.py``) places every leaf as held."""
+        return _read_model_fn(self.get("modelFn"), self.get("weights"),
+                              inputs)
+
+    def _place(self, dtypes: Optional[List[Any]]) -> None:
+        """Put the weights on the mesh, leaf by leaf so that at most one
+        leaf is on the device twice, from the device copy where there
+        is one (placed as held, before the function had been read)."""
+        src = self._device_weights if self._device_weights is not None \
+            else self.get("weights")
+        leaves, treedef = jax.tree_util.tree_flatten(src)
+        if self._sharding is not None:
+            # per-leaf declared placement: sharded leaves land split
+            # across the mesh (per-device resident bytes < the total)
+            shardings = jax.tree_util.tree_leaves(
+                self._sharding["weight_shardings"])
+        else:
+            shardings = [NamedSharding(self._mesh, P())] * len(leaves)
+        placed, n_cast, saved = [], 0, 0
+        for a, sharding, dt in zip(leaves, shardings,
+                                   dtypes or [None] * len(leaves)):
+            a = jax.device_put(jnp.asarray(a), sharding)
+            if dt is not None and a.dtype != dt:
+                n_cast += 1
+                saved += a.size * (a.dtype.itemsize - dt.itemsize)
+                # a cast leaf keeps its declared placement
+                a = jax.device_put(a.astype(dt), sharding)
+            placed.append(a)
+        self._cast = (n_cast, saved)
+        self._device_weights = jax.tree_util.tree_unflatten(treedef, placed)
+
+    def _apply_read(self, placed, inputs):
+        """``modelFn(placed, inputs)`` inside the jit trace of a bucket,
+        by evaluating the jaxpr the bucket was read by, so that a bucket
+        costs ONE Python trace of ``modelFn``: the first bucket's is the
+        one its weights were placed by, a later bucket is read here. The
+        leaves stand in for the held ones the jaxpr was traced over; a
+        leaf placed narrower meets only its own converts there, which
+        no longer convert anything. A bucket at which ``modelFn`` reads
+        a leaf in another dtype than it was placed in would compute with
+        other numbers than the held weights give: an error, not a
+        second device copy."""
+        reading = self._placement
+        if reading is None or reading.signature != _signature(inputs):
+            reading = self._read(inputs)
+        flat = jax.tree_util.tree_leaves(placed)
+        got = [a.dtype for a in flat]
+        if reading.dtypes != got:
+            raise ValueError(
+                f"modelFn reads its weights differently at inputs "
+                f"{reading.signature} than at the bucket they were "
+                f"placed for: "
+                f"{sum(w != g for w, g in zip(reading.dtypes, got))} "
+                f"leaves differ in dtype")
+        out = jax_core.jaxpr_as_fun(reading.jaxpr)(
+            *flat, *jax.tree_util.tree_leaves(inputs))
+        return jax.tree_util.tree_unflatten(reading.out_tree, out)
+
     def resident_bytes(self) -> int:
-        """Device bytes the shipped weights occupy, summed across PER-
+        """Device bytes the placed weights occupy, summed across PER-
         DEVICE shards over the whole mesh (a replicated tree counts
         once per device; a sharded tree counts its true split
         footprint) — the zoo's per-model eviction-cost signal. Falls
@@ -306,7 +453,6 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             with self._init_lock:
                 fn = self._jitted.get("run")
                 if fn is None:
-                    model_fn = self.get("modelFn")
                     model = self
 
                     def tpu_model_forward(
@@ -315,7 +461,7 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                         # input signature, i.e. once per XLA compile
                         with model._miss_lock:
                             model.jit_cache_misses += 1
-                        out = model_fn(weights, inputs)
+                        out = model._apply_read(weights, inputs)
                         if not isinstance(out, dict):
                             out = {"output": out}
                         return out
@@ -402,6 +548,9 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         out: Dict[str, Any] = {k: h.summary()
                                for k, h in self._hists.items()}
         out["jit_cache_misses"] = self.jit_cache_misses
+        # leaves placed narrower than held (the dtype modelFn reads
+        # them in), and the bytes a call no longer reads for it
+        out["weights_cast_leaves"], out["weights_cast_bytes"] = self._cast
         out["precision"] = self.get("precision")
         out["aot"] = bool(self.aot)
         if self._sharding is not None:
@@ -536,7 +685,6 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             if self.get("computeDtype") != "bfloat16" else jnp.bfloat16
         batch_size = self.get("batchSize")
         mesh = self._get_mesh()
-        weights = self._weights_on_device()
 
         n = len(table)
         out_cols: Dict[str, List[np.ndarray]] = {c: [] for c in fetches}
@@ -626,7 +774,8 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             # (ring_attention.flash_per_shard) read it
             with phase("tpu_model.dispatch", rows=rows) as sent, \
                     jax.set_mesh(mesh):
-                outputs = self._compiled()(weights, inputs)
+                outputs = self._compiled()(
+                    self._weights_on_device(inputs), inputs)
             for model_out in fetches.values():
                 if model_out not in outputs:
                     raise KeyError(

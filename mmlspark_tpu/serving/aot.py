@@ -18,7 +18,8 @@ Artifact layout (``<dir>/``)::
     manifest.json        # kind, version, precision, buckets, backend,
                          # jax version, serve hints — human-readable
     programs.pkl         # [(key, input avals, serialized executable)]
-    weights.pkl          # np weights pytree (incl. int8 scales)
+    weights.pkl          # np weights pytree as the programs consume
+                         # it (incl. int8 scales)
     model_fn.pkl         # lazy fallback for shapes the artifact
                          # never saw (tpu_model kind only)
     pipeline.pkl         # the fitted stage list (pipeline kind only)
@@ -368,8 +369,12 @@ def _export_tpu_model(model, example, out_dir: str,
 
     with open(os.path.join(out_dir, _PROGRAMS), "wb") as f:
         pickle.dump(records, f)
+    # the weights as the exported programs consume them: the clone's
+    # placed tree, each leaf in the dtype the model fn reads it in
+    # (TPUModel._weights_on_device) — a replica loads them and goes,
+    # with no model fn to read a placement from
     host_weights = jax.tree_util.tree_map(np.asarray,
-                                          model.get("weights"))
+                                          clone._weights_on_device())
     with open(os.path.join(out_dir, _WEIGHTS), "wb") as f:
         pickle.dump(host_weights, f)
     with open(os.path.join(out_dir, _MODEL_FN), "wb") as f:
@@ -635,7 +640,9 @@ def _aot_model_class():
     global _AOT_MODEL_CLS
     if _AOT_MODEL_CLS is not None:
         return _AOT_MODEL_CLS
-    from mmlspark_tpu.models.tpu_model import TPUModel
+    from mmlspark_tpu.models.tpu_model import (
+        TPUModel, _Reading, _signature,
+    )
 
     class AOTTPUModel(TPUModel):
         """TPUModel whose compiled-call dispatch goes straight to the
@@ -648,6 +655,13 @@ def _aot_model_class():
             self.aot = True
             self._aot_programs: Dict[Tuple, Callable] = {}
             self._artifact_dir: Optional[str] = None
+
+        def _read(self, inputs):
+            # the artifact's weights are stored as its programs consume
+            # them: placed as held, with no model fn to trace
+            if isinstance(self.get("modelFn"), _LazyModelFn):
+                return _Reading(_signature(inputs), None, None, None)
+            return super()._read(inputs)
 
         def _fallback(self) -> Callable:
             # check-then-set under the model's init lock: two workers

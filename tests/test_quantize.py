@@ -433,6 +433,57 @@ class TestAOTExportLoad:
         assert np.array_equal(a, r)
         assert loaded.jit_cache_misses == 0
 
+    def test_bfloat16_module_roundtrip_stores_what_its_programs_read(
+            self, tmp_path):
+        """A module that computes in bfloat16 over float32 parameters:
+        the exported programs consume the placed tree (kernels in
+        bfloat16), so the artifact stores that tree, the replica places
+        it as held with no model fn to read, and scores what the
+        in-process model scores — on an exported bucket and through
+        the lazy fallback on one that was not."""
+        import jax
+        import jax.numpy as jnp
+        from mmlspark_tpu.models.networks import build_network
+        from mmlspark_tpu.models.tpu_model import TPUModel
+        from mmlspark_tpu.parallel import mesh as mesh_lib
+        from mmlspark_tpu.serving import aot
+        module = build_network({
+            "type": "transformer", "vocab_size": 64, "dim": 32, "depth": 1,
+            "heads": 4, "max_len": 8, "num_classes": 3,
+            "dtype": "bfloat16"})
+        variables = jax.jit(module.init)(jax.random.PRNGKey(0),
+                                         jnp.zeros((1, 8), jnp.int32))
+        m = TPUModel.from_flax(module, variables, inputCol="features",
+                               outputCol="scores", batchSize=16)
+        m.set_mesh(mesh_lib.make_mesh({"data": 1},
+                                      devices=[jax.devices()[0]]))
+        rows = np.random.default_rng(2).integers(
+            0, 64, size=(13, 8)).astype(np.float32)
+        art = str(tmp_path / "lm_v1")
+        aot.export_model(m, {"features": rows[:1]}, art, version="v1")
+        loaded = aot.load_model(art)
+        stored = loaded.get("weights")["params"]
+        assert str(stored["block_0"]["qkv"]["kernel"].dtype) == "bfloat16"
+        assert str(stored["head"]["kernel"].dtype) == "float32"
+        t = DataTable({"features": rows})
+        want = np.asarray(m.transform(t)["scores"])
+        assert np.array_equal(np.asarray(loaded.transform(t)["scores"]),
+                              want)
+        assert loaded.jit_cache_misses == 0
+        assert loaded.metrics()["weights_cast_leaves"] == 0
+        assert m.metrics()["weights_cast_leaves"] == 9
+        assert loaded.resident_bytes() == m.resident_bytes()
+        # a bucket the artifact never saw: the fallback traces the real
+        # fn over the stored tree and finds nothing left to convert
+        loaded.set("batchSize", 32)
+        m.set("batchSize", 32)
+        wide = DataTable({"features": np.tile(rows, (2, 1))})
+        assert np.array_equal(
+            np.asarray(loaded.transform(wide)["scores"]),
+            np.asarray(m.transform(wide)["scores"]))
+        assert loaded.jit_cache_misses == 1
+        assert loaded.metrics()["weights_cast_leaves"] == 0
+
     def test_pipeline_artifact_serves_end_to_end(self, tmp_path):
         """Pipeline-kind artifact: the fused serving programs load
         pre-compiled, the scorer warms with zero compiles, and replies
