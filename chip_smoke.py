@@ -1,13 +1,13 @@
 """chip_smoke.py — does the system still start on the chip?
 
 One process drives the normal path once, through the entry points a user
-calls, at the full width of the one model this repo has run at width
-(the dim-2048 transformer LM of bench.py): train a few steps, score with
-the model ``fit`` returned, serve a classifier of the same trunk over
-HTTP, boost a HIGGS-shaped forest at 63 and 255 bins, and run a fused
-featurize -> booster pipeline. Weights are random from a seed, data is
-synthetic, nothing touches the network. Every leg is fatal: a failure
-propagates, the exit code is non-zero and no result line is printed.
+calls, at the width of a dim-2048 transformer LM: train a few steps,
+score with the model ``fit`` returned, serve a classifier of the same
+trunk over HTTP, boost a HIGGS-shaped forest at 63 and 255 bins, and
+run a fused featurize -> booster pipeline. Weights are random from a
+seed, data is synthetic, nothing touches the network. Every leg is
+fatal: a failure propagates, the exit code is non-zero and no result
+line is printed.
 
     python chip_smoke.py
 
@@ -33,9 +33,8 @@ import urllib.request
 
 import numpy as np
 
-# bench.py's LM_SPEC / HIGGS shape, restated so this file stands alone
+# a dim-2048 LM and a HIGGS-shaped table
 FULL = {
-    "on_chip": True,
     "lm_spec": {"type": "transformer", "vocab_size": 32000, "dim": 2048,
                 "depth": 8, "heads": 16, "max_len": 1024,
                 "head_dtype": "bfloat16"},
@@ -159,10 +158,6 @@ def leg_train(cfg: dict, n_dev: int):
     # random weights on random tokens: the first loss is ln(vocab) give
     # or take the logits' variance
     assert abs(losses[0] - math.log(vocab)) < 1.0, losses
-    hlo = learner.step_lowered.as_text()
-    if cfg["on_chip"] and seq >= 512:
-        assert "tpu_custom_call" in hlo, \
-            "the train step lowered without the flash kernel"
     timing = learner.timing
     assert timing.get("steps_timed", 0) > 0 and \
         timing.get("examples_per_sec", 0) > 0, timing
@@ -171,16 +166,11 @@ def leg_train(cfg: dict, n_dev: int):
         "steps": steps,
         "loss_first": round(losses[0], 4),
         "loss_last": round(losses[-1], 4),
-        "flash_in_step": "tpu_custom_call" in hlo,
         "tokens_per_sec_per_chip":
             round(timing["examples_per_sec"] * seq / n_dev, 1),
-        "mfu_xla_cost_analysis":
-            round(timing["mfu"], 4) if "mfu" in timing else None,
     }
     _log(f"train: information only, no threshold: "
-         f"{facts['tokens_per_sec_per_chip']} tokens/s/chip, MFU "
-         f"{facts['mfu_xla_cost_analysis']} (XLA cost analysis; counts "
-         f"the flash call as zero)")
+         f"{facts['tokens_per_sec_per_chip']} tokens/s/chip")
     return facts, model, toks
 
 

@@ -16,7 +16,7 @@ token hashes once, counts scatter in one key sort), string
 index/one-hot map through a unique-value LUT instead of a per-row dict
 probe, and fit's level scan uses np.unique. The pre-vectorization
 per-row loops survive as ``_build_parts_rowloop`` — the bit-parity
-oracle the tests and ``bench.py``'s automl scenario measure against.
+oracle the tests compare against.
 """
 
 from __future__ import annotations
@@ -302,9 +302,8 @@ def _build_part(spec: Dict[str, Any], table: DataTable):
 
 def _build_parts_rowloop(specs, table: DataTable) -> List[Any]:
     """The pre-vectorization per-row loops, verbatim — the bit-parity
-    ORACLE for the columnar kernels (pinned by tests) and the baseline
-    ``bench.py``'s automl scenario measures the speedup against. Not on
-    any hot path."""
+    ORACLE for the columnar kernels (pinned by tests). Not on any hot
+    path."""
     parts: List[Any] = []
     n = len(table)
     for spec in specs or []:

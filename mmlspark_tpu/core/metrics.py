@@ -468,9 +468,9 @@ def gbdt_train_histograms() -> Dict[str, LatencyHistogram]:
 # Distributed-GBDT histogram-build phases and collective payload bytes
 # ---------------------------------------------------------------------------
 
-# per-phase wall milliseconds of the histogram hot loop, micro-timed by
-# the distributed bench (bench.py gbdt_dist): build (local histogram
-# kernel), reduce (the cross-device collective), split (best-gain scan)
+# per-phase wall milliseconds of the histogram hot loop: build (local
+# histogram kernel), reduce (the cross-device collective), split
+# (best-gain scan)
 GBDT_HIST_PHASES = ("build", "reduce", "split")
 _GBDT_HIST_HISTS: Dict[str, LatencyHistogram] = histogram_set(
     *GBDT_HIST_PHASES)
@@ -486,7 +486,7 @@ def gbdt_hist_histograms() -> Dict[str, LatencyHistogram]:
 # ring-payload model at the end of every distributed train() (the
 # collectives run inside jit, so bytes cannot be counted on the wire;
 # the model is exact for ring implementations and labeled as such in
-# docs/distributed_gbdt.md) — the instrument behind the BENCH_r19
+# docs/distributed_gbdt.md) — the instrument behind the
 # comm-reduction floor.
 GBDT_COMM_COLLECTIVES = ("psum", "psum_scatter", "all_gather")
 _GBDT_COMM_LOCK = threading.Lock()

@@ -1,10 +1,11 @@
 """Zero-copy columnar ingress codecs for the serving hot path.
 
-BENCH_r07's phase breakdown showed JSON decode + row batching + pad
-together rivaling the device phase: text parsing had become the serving
-bottleneck the way HTTP transport was before the PR 2 keep-alive
-overhaul. This module retires the host side of that path the way Arrow
-/ Plasma retire serialization in analytics stacks (Moritz et al.):
+On a CPU container, before the chip, the serving phase breakdown
+showed JSON decode + row batching + pad together rivaling the device
+phase (a CPU wall): text parsing had become the serving bottleneck the
+way HTTP transport was before the PR 2 keep-alive overhaul. This module
+retires the host side of that path the way Arrow / Plasma retire
+serialization in analytics stacks (Moritz et al.):
 requests carry **typed column buffers** instead of JSON rows, and
 decode becomes an ``np.frombuffer`` view over the request body — no
 text parse, no per-row Python objects, no per-element boxing between

@@ -88,37 +88,6 @@ def make_optimizer(name: str, lr: float, *, momentum: float = 0.9,
 # ---------------------------------------------------------------------------
 
 
-# bf16 peak FLOP/s per chip by jax ``device_kind`` — used only to report
-# MFU alongside measured throughput (public figures)
-_PEAK_BF16_FLOPS = {
-    "TPU v2": 46e12,
-    "TPU v3": 123e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-}
-
-
-def peak_flops_per_chip(device_kind: str) -> float:
-    """bf16 peak of a TPU ``device_kind`` for MFU reporting. A kind that
-    is not in the table is an error: MFU is never dropped silently."""
-    try:
-        return _PEAK_BF16_FLOPS[device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no bf16 peak for device_kind {device_kind!r}; add it to "
-            f"_PEAK_BF16_FLOPS in models/learner.py (known: "
-            f"{sorted(_PEAK_BF16_FLOPS)})") from None
-
-
-def _step_flops(compiled) -> float:
-    """Per-step FLOPs from XLA's cost analysis of a compiled step."""
-    return float(compiled.cost_analysis()["flops"])
-
-
 def fsdp_sharding_rule(mesh: Mesh, axis: str = mesh_lib.FSDP_AXIS
                        ) -> Callable[[jnp.ndarray], NamedSharding]:
     """Shard each leaf's largest dim divisible by the axis size; replicate
@@ -514,10 +483,6 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
 
         self.history = []
         self.timing: Dict[str, float] = {}
-        # jax.stages.Lowered of one bare train step (device feed only):
-        # what the MFU FLOPs are read from, kept so a caller can check
-        # which kernels the step lowered to (chip_smoke.py does)
-        self.step_lowered = None
         # fit-scoped trace: a span a host stage (dispatch, log flush,
         # checkpoint, feed wait) + optional device-memory samples, in
         # the same buffer the serving spans land in (span count capped
@@ -666,7 +631,6 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
         t_loop_start = _time.time()
         first_timed_step = start_step
         examples_timed = 0   # true (unpadded) rows after the warmup step
-        flops_per_step: Optional[float] = None
         # CPU backend: async dispatch racing ahead starves XLA's
         # in-process collective rendezvous on small hosts (7/8 devices
         # join, the 8th's thunk never gets a pool thread -> fatal
@@ -840,31 +804,6 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
                             seg_end = min(seg_end, nxt - base)
                         length = seg_end - i
                         fn = get_chunk_fn(length)
-                        if flops_per_step is None:
-                            # cost-analyze ONE bare train_step (XLA's
-                            # analysis counts a scan body once, so
-                            # analyzing the chunk would under-report by
-                            # the scan length); lowered from avals, one
-                            # extra compile before timing starts
-                            batch_sds = {
-                                "x": jax.ShapeDtypeStruct(
-                                    (global_batch,) + x_p.shape[1:],
-                                    x_p.dtype),
-                                "y": jax.ShapeDtypeStruct(
-                                    (global_batch,) + y_p.shape[1:],
-                                    y_p.dtype),
-                                "w": jax.ShapeDtypeStruct(
-                                    (global_batch,), jnp.float32),
-                            }
-                            probe = jax.jit(
-                                train_step,
-                                in_shardings=(state_sharding,
-                                              data_sharding),
-                                out_shardings=(state_sharding, None))
-                            self.step_lowered = probe.lower(
-                                state, batch_sds)
-                            flops_per_step = _step_flops(
-                                self.step_lowered.compile())
                         # the dispatch alone (chunks run async): the
                         # span shows host-side stalls, the profile's
                         # device rows the on-chip time
@@ -939,20 +878,6 @@ class TPULearner(Estimator, HasFeaturesCol, HasLabelCol):
             }
             if self_timing_includes_compile:
                 self.timing["includes_compile"] = True
-            if flops_per_step:
-                # XLA cost analysis reports the PER-DEVICE cost of the
-                # SPMD-partitioned module (verified empirically on a
-                # data-sharded matmul), so per-chip rates need no further
-                # division by chip count
-                tflops = flops_per_step * steps_timed / max(wall, 1e-9) / 1e12
-                self.timing["flops_per_step_per_chip"] = flops_per_step
-                self.timing["model_flops_per_step"] = (
-                    flops_per_step * int(mesh.devices.size))
-                self.timing["tflops_per_sec_per_chip"] = tflops
-                dev = jax.devices()[0]
-                if dev.platform == "tpu":
-                    self.timing["mfu"] = tflops * 1e12 / \
-                        peak_flops_per_chip(dev.device_kind)
         if ckpt_dir:
             save_checkpoint()
         if fit_trace is not None:

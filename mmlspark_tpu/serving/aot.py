@@ -9,9 +9,7 @@ before it could serve its first request. This module moves that work to
 directory, and seeds a persistent XLA compilation cache next to them —
 so ``load_model`` on a fresh replica is *deserialize and go*: no Python
 tracing of the model, and the XLA compile of each deserialized program
-is a disk hit. Measured as ``cold_start_to_first_200_ms`` in the
-serving bench (``bench.py --scenarios coldstart``) and floor-pinned in
-``tests/test_perf_floors.py``.
+is a disk hit. Floor-pinned in ``tests/test_perf_floors.py``.
 
 Artifact layout (``<dir>/``)::
 

@@ -1,10 +1,9 @@
 """Where jax's persistent compilation cache lives.
 
-One rule for every entry point (``cli.main``, ``bench.py``,
-``chip_smoke.py``): the cache is placed from outside through
-``JAX_COMPILATION_CACHE_DIR``, and only when that is unset does the
-program pick a directory — a fixed one, because a cache that moves is
-never hit.
+One rule for every entry point (``cli.main``, ``chip_smoke.py``): the
+cache is placed from outside through ``JAX_COMPILATION_CACHE_DIR``, and
+only when that is unset does the program pick a directory — a fixed
+one, because a cache that moves is never hit.
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Launch helper for the multi-host fabric drills: one member of a
 2-process ``jax.distributed`` group on this box.
 
-Spawned N times by tests/test_multihost_fabric.py (and the bench.py
-``fabric`` scenario). Each member rendezvouses through
-``parallel.distributed.initialize`` — the REAL coordinator/worker path
+Spawned N times by tests/test_multihost_fabric.py. Each member
+rendezvouses through ``parallel.distributed.initialize`` — the REAL
+coordinator/worker path
 with the bounded timeout and gloo CPU collectives — then runs the two
 fabric drills end-to-end:
 
@@ -24,15 +24,6 @@ Usage::
 
     python multihost_worker.py <coordinator_port> <process_id> <nproc>
         [--timeout-s T] [--die-before-rendezvous]
-        [--bench-rows N --bench-feats F --bench-iters T
-         --hist-bits B --hist-comm C]
-
-With ``--bench-rows`` the fabric drills are replaced by ONE
-HIGGS-shaped training run at the given scale (bench.py's
-``gbdt_dist`` scenario): each host writes its row shard to an Arrow
-IPC file, streams it back as ChunkedTable chunks through sketch
-binning, trains data-parallel over the group, and prints ``BENCH``
-lines (per-phase walls, modeled comm bytes, peak RSS).
 
 ``--die-before-rendezvous`` makes a non-coordinator member exit before
 ever calling initialize() — the member-death drill: the SURVIVING member
@@ -58,78 +49,6 @@ from mmlspark_tpu.utils.jax_compat import set_cpu_device_count  # noqa: E402
 set_cpu_device_count(1)
 
 
-def _run_bench(pid: int, args) -> None:
-    """bench.py ``gbdt_dist`` payload: a HIGGS-shaped quantized
-    distributed training run at the requested scale. The local row
-    shard is staged to an Arrow IPC file and streamed back as
-    memory-mapped ChunkedTable chunks through sketch binning — the
-    raw f64 matrix never materializes — then trained data-parallel
-    over the REAL process group. Prints machine-parsable lines:
-
-        BENCH_PHASE <pid> <phase> <seconds>
-        BENCH_COMM <pid> <collective> <modeled_bytes>
-        BENCH_STAT <pid> <auc4> <raw_mb> <peak_chunk_mb> <maxrss_mb>
-    """
-    import resource
-    import tempfile
-
-    import numpy as np
-
-    from mmlspark_tpu.core.table import DataTable
-    from mmlspark_tpu.gbdt.booster import train as gbdt_train
-    from mmlspark_tpu.io.ooc import ChunkedTable, write_arrow_ipc
-
-    n, f = args.bench_rows, args.bench_feats
-    rng = np.random.default_rng(100 + pid)    # disjoint per-host rows
-    X = rng.normal(size=(n, f)).astype(np.float32)
-    logit = (X[:, 0] + 0.6 * X[:, 1] * X[:, 2]
-             + 0.4 * np.sin(2 * X[:, 3]) + 0.3)
-    y = (logit + rng.normal(scale=0.5, size=n) > 0
-         ).astype(np.float32)
-    raw_mb = X.nbytes / 2 ** 20
-    with tempfile.NamedTemporaryFile(suffix=".arrow",
-                                     delete=False) as tf:
-        path = tf.name
-    try:
-        write_arrow_ipc(DataTable({"features": X, "label": y}), path,
-                        chunk_rows=max(1, n // 64))
-        del X
-        ct = ChunkedTable.from_arrow_ipc(path,
-                                         chunk_rows=max(1, n // 64))
-        booster = gbdt_train(
-            {"objective": "binary",
-             "num_iterations": args.bench_iters, "num_leaves": 31,
-             "max_bin": 63, "parallelism": "data",
-             "hist_method": "scatter", "bin_fit": "sketch",
-             "hist_bits": args.hist_bits, "hist_comm": args.hist_comm},
-            ct)
-        for phase, secs in booster.train_timing.items():
-            print(f"BENCH_PHASE {pid} {phase} {secs}", flush=True)
-        for coll, nb in booster.train_info.get(
-                "comm_bytes", {}).items():
-            print(f"BENCH_COMM {pid} {coll} {nb}", flush=True)
-        # holdout AUC on fresh rows from the same generator family
-        ho = np.random.default_rng(999)
-        Xh = ho.normal(size=(4096, f)).astype(np.float32)
-        lh = (Xh[:, 0] + 0.6 * Xh[:, 1] * Xh[:, 2]
-              + 0.4 * np.sin(2 * Xh[:, 3]) + 0.3)
-        yh = (lh + ho.normal(scale=0.5, size=4096) > 0)
-        p = booster.predict(Xh)
-        order = np.argsort(p, kind="stable")
-        ranks = np.empty(len(p))
-        ranks[order] = np.arange(1, len(p) + 1)
-        npos = int(yh.sum())
-        auc = (ranks[yh].sum() - npos * (npos + 1) / 2) / max(
-            npos * (len(yh) - npos), 1)
-        peak_mb = ct.stats.snapshot()["tracked_peak_bytes"] / 2 ** 20
-        rss_mb = resource.getrusage(
-            resource.RUSAGE_SELF).ru_maxrss / 1024
-        print(f"BENCH_STAT {pid} {auc:.4f} {raw_mb:.1f} "
-              f"{peak_mb:.1f} {rss_mb:.1f}", flush=True)
-    finally:
-        os.unlink(path)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("port", type=int)
@@ -137,12 +56,6 @@ def main() -> None:
     ap.add_argument("nproc", type=int)
     ap.add_argument("--timeout-s", type=float, default=60.0)
     ap.add_argument("--die-before-rendezvous", action="store_true")
-    ap.add_argument("--bench-rows", type=int, default=0,
-                    help="rows per host; >0 switches to bench mode")
-    ap.add_argument("--bench-feats", type=int, default=28)
-    ap.add_argument("--bench-iters", type=int, default=10)
-    ap.add_argument("--hist-bits", type=int, default=16)
-    ap.add_argument("--hist-comm", default="auto")
     args = ap.parse_args()
     pid, nproc = args.process_id, args.nproc
 
@@ -175,11 +88,6 @@ def main() -> None:
     from mmlspark_tpu.core.table import DataTable
     from mmlspark_tpu.gbdt.booster import train as gbdt_train
     from mmlspark_tpu.io.ooc import ChunkedTable
-
-    if args.bench_rows > 0:
-        _run_bench(pid, args)
-        print(f"OK {pid}", flush=True)
-        return
 
     def _comm_line(tag, booster):
         cb = booster.train_info.get("comm_bytes", {})

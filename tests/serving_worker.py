@@ -1,12 +1,11 @@
-"""One serving-host process for the cross-process serving tests/bench.
+"""One serving-host process for the cross-process serving tests.
 
 The reference's serving is genuinely per-executor — one JVMSharedServer
 in every executor process with reply-by-uuid routing
 (ref: src/io/http/src/main/scala/DistributedHTTPSource.scala:96-266).
 This worker is the TPU-native equivalent of one executor: its own OS
 process, its own ServingEngine + port, its own counters. The parent
-(tests/test_distributed.py, tests/test_sharded.py, bench.py
-``fleet_procs``) sprays requests across all workers and checks the
+(tests/test_distributed.py, tests/test_sharded.py) sprays requests across all workers and checks the
 reply-routing invariant and the fleet-wide counter aggregate.
 
 Two scorers:

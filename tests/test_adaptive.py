@@ -9,8 +9,7 @@ bounded scale rates and drain-before-retire discipline, and the
 
 The full chaos acceptance drill (SLO ramp over real HTTP -> step_down
 -> availability/correctness/recompile floors -> recovery step_up) and
-the real-OS-process autoscaler round trip are slow-marked;
-``bench.py adaptive`` runs the measured cost/occupancy comparison.
+the real-OS-process autoscaler round trip are slow-marked.
 """
 
 import importlib.util

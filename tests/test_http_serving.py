@@ -425,9 +425,9 @@ class TestMiniBatch:
 
 
 class TestServingThroughput:
-    """Serving performance floor (bench.py bench_serving measures the
-    real-chip number; this guards the machinery from regressing into
-    per-request recompiles or serialized batching on any backend)."""
+    """Serving performance floor: guards the machinery from regressing
+    into per-request recompiles or serialized batching on any backend
+    (a CPU wall; the chip's numbers are the benchmark's serve cells)."""
 
     @pytest.mark.slow   # wall-clock floor: meaningless on a contended host
     def test_fleet_qps_floor(self):

@@ -708,8 +708,8 @@ class TestColumnarIngressFloor:
     def test_columnar_at_least_2x_json_rows_per_s(self):
         """The acceptance floor: single-replica rows/sec >= 2x the JSON
         oracle on the same engine, host ingress phases < 20% of request
-        p50, zero steady-state recompiles (BENCH_r11 measures ~60x on
-        this container; 2x is the pinned floor)."""
+        p50, zero steady-state recompiles (~60x on the CPU container,
+        before the chip: a CPU wall; 2x is the pinned floor)."""
         import concurrent.futures
         from mmlspark_tpu.core.metrics import (
             ingress_decode_histograms, ingress_histograms,

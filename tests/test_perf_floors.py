@@ -1,11 +1,12 @@
 """Throughput/wall-clock regression floors for the training hot paths.
 
-bench.py measures the real-chip numbers; these floors guard the
-MACHINERY on the CI backend (8 virtual CPU devices, shared 1-core
-host) — a regression that serializes the input feed, loses the jit
-cache, or re-traces per step shows up as a many-fold slowdown on any
-backend. Floors sit ~3x below the idle-host measurement so shared-host
-noise passes but a 2x-per-step machinery regression fails
+The chip's numbers are the benchmark's (``benchmark/run.py``); these
+floors are CPU walls that guard the MACHINERY on the CI backend (8
+virtual CPU devices, shared 1-core host) — a regression that serializes
+the input feed, loses the jit cache, or re-traces per step shows up as
+a many-fold slowdown on any backend. Floors sit ~3x below the
+idle-host measurement so shared-host noise passes but a 2x-per-step
+machinery regression fails
 (ref: src/core/test/benchmarks/.../Benchmarks.scala:15-60 — the
 reference pins its benchmark numbers in-repo too; VERDICT r4 weak #2:
 no LM or GBDT floor existed at all).
@@ -67,7 +68,7 @@ class TestGBDTWallFloor:
              "num_leaves": 31, "max_bin": 63}, X, y)
         wall = time.perf_counter() - t0
         phases = booster.train_timing
-        # phase attribution must be present (the bench JSON contract)
+        # phase attribution must be present
         for key in ("bin", "ship", "first_iter", "boost", "fetch"):
             assert key in phases, phases
         # idle-host: wall ~5.4s, boost ~2.5s, bin ~0.06s. first_iter
@@ -89,8 +90,7 @@ class TestGBDTWallFloor:
         ingest path and fused boosting chunks, within a wall budget —
         and a second train() at the SAME shapes must add ZERO program
         traces (the chunk-fn cache guard, the GBDT analog of serving's
-        steady_state_recompiles == 0; wired into the bench JSON as
-        bin_path / boost_chunk)."""
+        steady_state_recompiles == 0)."""
         from mmlspark_tpu.gbdt import booster as booster_mod
         from mmlspark_tpu.gbdt.booster import train as gbdt_train
         rng = np.random.default_rng(2)
@@ -149,8 +149,8 @@ class TestServingQPSFloor:
         compile cache + pipelined dispatch): guards against regressions
         that re-serialize the request->device path — per-request
         recompiles, lost keep-alive, a batcher that stops aggregating —
-        while riding out shared-host noise. bench.py's serving scenario
-        measures the real-chip number; this is the machinery guard."""
+        while riding out shared-host noise. A CPU wall: the machinery
+        guard, not a chip number."""
         import concurrent.futures
         import json
 
@@ -220,8 +220,7 @@ class TestTracingOverheadFloor:
         path). Same serving-scenario shape as the QPS floor; tracing
         OFF and ON runs interleave and each mode keeps its best rep, so
         shared-host noise hits both sides of the ratio. The 3% pin gets
-        a small absolute-qps guard band on top purely for CI noise —
-        the bench observability scenario reports the unpadded number."""
+        a small absolute-qps guard band on top purely for CI noise."""
         import concurrent.futures
         import json
 
@@ -409,8 +408,7 @@ class TestAutoMLFloor:
         host-noise-robust (both sides measured back to back on the same
         data), so a regression that reintroduces per-row Python — a
         dict probe per row, a per-token hash call — fails by an order
-        of magnitude. bench.py's automl scenario measures the full
-        1M-row number (acceptance: >= 10x there)."""
+        of magnitude."""
         from mmlspark_tpu.automl.featurize import Featurize
 
         rng = np.random.default_rng(0)
@@ -496,9 +494,9 @@ class TestQuantThroughputFloor:
     can show it: integer matmul doubles effective MXU batch throughput
     on TPU-class chips, but this CI container's CPU backend has no
     int8 systolic path (XLA's CPU int8 dot measures ~0.2x of its
-    oneDNN f32 gemm — BENCH_r10.json records that honestly, backend
-    labeled). Skipped off-TPU rather than asserted into fiction; the
-    backend-independent accuracy floors live in tests/test_quantize.py."""
+    oneDNN f32 gemm: a CPU wall). Skipped off-TPU rather than asserted
+    into fiction; the backend-independent accuracy floors live in
+    tests/test_quantize.py."""
 
     def test_int8_batch_throughput_on_mxu_backends(self):
         import jax
@@ -547,8 +545,7 @@ class TestColdStartFloor:
     actually hurts on; a 2-layer MLP's compile is noise next to the
     interpreter+jax import both modes pay). Idle-host calibration:
     trace ~6.5 s, aot ~1.5 s => 4.4x; best-of-2 per mode rides out
-    shared-host noise above the 3x pin (BENCH_r10.json records the
-    measured numbers)."""
+    shared-host noise above the 3x pin (CPU walls)."""
 
     def test_aot_cold_start_3x_and_zero_request_traces(self, tmp_path):
         import json
@@ -615,9 +612,7 @@ class TestPipelineFusionFloor:
         """Whole-pipeline fusion (core/fusion.py) vs the legacy
         stage-at-a-time path on a 200k-row raw-rows pipeline
         (Featurize w/ 128-level one-hot + hashed tokens ->
-        StandardScaler -> logistic -> drop(features)) — the scaled-down
-        twin of bench.py's ``pipeline`` scenario (acceptance: >= 3x
-        COLD there at 1M rows; measured 6x on this container).
+        StandardScaler -> logistic -> drop(features)).
 
         Ratios are measured back to back on the same data, so shared-
         host noise hits both sides: idle-host calibration is ~3.4x cold
@@ -710,70 +705,12 @@ class TestPipelineFusionFloor:
             f"(host {host_s:.2f}s vs fused {warm_s:.2f}s)")
 
 
-class TestFleetProcsFloor:
-    """Multi-process fleet throughput scaling (bench.py fleet_procs):
-    >= 2.5x with 4 engine processes vs 1 behind ServingFleet.connect
-    under the columnar load generator. Process scaling is bounded by
-    usable cores, so the floor is GATED on >= 4 of them — this CI
-    container exposes 1 (4 CPU-bound processes timeshare it; measured
-    ~1.7x there purely from escaping the single engine's GIL convoy,
-    recorded honestly in BENCH_r14.json). The availability floor for
-    the SIGKILL chaos drill is backend-independent and pinned in
-    tests/test_sharded.py."""
-
-    def test_four_process_scaling_on_multicore(self):
-        import os as _os
-        import sys as _sys
-        cores = len(_os.sched_getaffinity(0))
-        if cores < 4:
-            pytest.skip(f"process-scaling floor needs >= 4 usable "
-                        f"cores; this host exposes {cores}")
-        _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))))
-        import bench
-        result = bench.bench_fleet_procs()
-        assert result["chaos_kill_one"]["availability"] >= 0.99, result
-        assert result["value"] >= 2.5, (
-            f"fleet process-scaling floor: {result['value']:.2f}x "
-            f"({result['one_proc']} -> {result['n_procs']})")
-
-
 class TestFabricFloors:
-    """Multi-host fabric floors (bench.py fabric, PR 17). Both are
-    GATED, not faked: the shm uplift is a serialization-savings claim
-    that needs client and engines on separate cores (this CI container
-    exposes 1 — BENCH_r17.json records the honest 1-core number,
-    ~0.93x, where everything timeshares one core and the staged copy
-    buys nothing); the multi-machine floor only means anything inside
-    a real ``jax.distributed`` group, so it gates on
-    ``in_process_group()`` the way PR 14's scaling floors gated on
-    cores — tier-1 proves the gate itself via the 2-process drill in
+    """Multi-host fabric floors (PR 17), GATED, not faked: a
+    multi-machine floor only means anything inside a real
+    ``jax.distributed`` group, so it gates on ``in_process_group()``;
+    tier-1 proves the gate itself via the 2-process drill in
     tests/test_multihost_fabric.py."""
-
-    def test_shm_transport_uplift_on_multicore(self):
-        import os as _os
-        import sys as _sys
-        cores = len(_os.sched_getaffinity(0))
-        if cores < 2:
-            pytest.skip(f"shm-uplift floor needs >= 2 usable cores "
-                        f"(client + engine on separate cores); this "
-                        f"host exposes {cores}")
-        _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))))
-        import bench
-        result = bench.bench_fabric()
-        shm = result["transports"]["shm"]
-        http = result["transports"]["http_msgpack"]
-        # equal availability first — a fast transport that drops
-        # requests is not an uplift
-        assert shm["availability"] >= 0.99, result
-        assert http["availability"] >= 0.99, result
-        assert shm["negotiated"] and shm["fallbacks"] == 0, result
-        assert shm["gen_mismatch"] == 0, result
-        assert result["value"] >= 1.3, (
-            f"shm transport uplift floor: {result['value']:.2f}x "
-            f"(shm {shm['rows_per_s']} rows/s vs http "
-            f"{http['rows_per_s']} rows/s on {cores} cores)")
 
     def test_multimachine_gbdt_fit_floor_in_process_group(self):
         from mmlspark_tpu.parallel import distributed as dist
@@ -817,14 +754,15 @@ class TestFabricFloors:
             assert digest == "f5a78c0b12b87015", digest
         assert wall <= 60.0, (
             f"multi-host sketch-GBDT fit wall floor: {wall:.1f}s on "
-            f"{info.process_count} processes (bench.py fabric measured "
-            f"~10s spawn-to-OK for the whole 2-process drill)")
+            f"{info.process_count} processes (~10s spawn-to-OK for "
+            f"the whole 2-process drill on the CPU container)")
 
     def test_quantized_gbdt_comm_bytes_floor_in_process_group(self):
         """PR 19 wire floor: hist_bits=16 + reduce_scatter must model
         >=2x fewer collective bytes than the f32 psum engine on the
-        SAME distributed fit (BENCH_r19.json measures ~3.7x; the int16
-        wire alone is 2x and the feature partition pays the rest)."""
+        SAME distributed fit (the model reads ~3.7x at 100k x 28 on
+        two processes: a count; the int16 wire alone is 2x and the
+        feature partition pays the rest)."""
         from mmlspark_tpu.parallel import distributed as dist
         if not dist.in_process_group():
             pytest.skip("comm-bytes floor needs process_count >= 2 "

@@ -213,18 +213,27 @@ def test_record_takes_explicit_stamps():
     assert hist.snapshot()["count"] == 2
 
 
-def test_phase_with_nobody_listening_costs_next_to_nothing():
-    """No tracer, no histogram, no profiler session: under 5 us."""
-    def spin(n):
-        t0 = time.perf_counter()
-        for i in range(n):
-            with phase("serve.execute", batch=i, rows=8) as ph:
+def test_phase_with_nobody_listening_costs_next_to_nothing(monkeypatch):
+    """No tracer, no histogram, no profiler session: a phase opens no
+    span, observes no histogram and its annotation is one the profiler
+    does not record. (What that costs in time is a chip-host reading:
+    PERF.md section 6.)"""
+    import jax
+
+    from mmlspark_tpu.core import trace as trace_mod
+    opened, observed = [], []
+    monkeypatch.setattr(trace_mod, "_open_span",
+                        lambda *a, **kw: opened.append(a))
+    monkeypatch.setattr(LatencyHistogram, "observe",
+                        lambda self, ms: observed.append(ms))
+    for i in range(100):
+        with phase("serve.execute", batch=i, rows=8) as ph:
+            with phase("tpu_model.pad") as inner:
                 pass
-        return (time.perf_counter() - t0) / n, ph
-    spin(200)
-    best, ph = min((spin(2000) for _ in range(7)), key=lambda r: r[0])
-    assert ph.span is None
-    assert best < 5e-6, f"{best * 1e6:.2f} us a phase"
+        assert ph.span is None and inner.span is None
+        assert inner.attrs == {"batch": i} and ph.end >= inner.end
+    assert opened == [] and observed == []
+    assert not jax.profiler.TraceAnnotation.is_enabled()
 
 
 def test_trace_module_needs_no_jax():

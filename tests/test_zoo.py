@@ -5,8 +5,7 @@ churn, tenant quotas + priority shedding, the mixed-tenant model-churn
 chaos drill, and the warmup-example validation satellite.
 
 The 256-model floor (bounded p99 under churn, zero steady-state
-recompiles on resident models) is slow-marked; ``bench.py zoo`` runs
-the full-scale measurement.
+recompiles on resident models) is slow-marked.
 """
 
 import json
@@ -968,7 +967,7 @@ class TestWarmupExampleValidation:
 
 
 # ---------------------------------------------------------------------------
-# the CI-feasible scale floor (full scale lives in bench.py zoo)
+# the CI-feasible scale floor
 # ---------------------------------------------------------------------------
 
 
@@ -1063,8 +1062,7 @@ class TestZooFloor:
                     assert body["served_by"] == model_key
             lat = sorted(r[3] for r in ok)
             p99 = lat[int(0.99 * len(lat))]
-            # CI-feasible bound on this throttled 2-core container;
-            # bench.py zoo measures the real number
+            # CI-feasible bound on this throttled 2-core container
             assert p99 < 30.0, f"p99 {p99:.2f}s"
             assert zoo.evictions > 0
             assert zoo.evictions_with_outstanding == 0
