@@ -2,33 +2,48 @@
 
 The O(L^2) score matrix of ``ring_attention.attention`` never leaves
 VMEM here: the kernel streams K/V blocks past each Q block, maintaining
-online-softmax statistics (m, l, acc) in scratch across the KV grid
-axis — O(L) HBM traffic per head instead of materializing (L, L) scores
-(the standard TPU flash-attention scheme; same m/l/o algebra the ring
-layer uses across devices, applied within one device).
+online-softmax statistics (m, l, acc) across the KV grid axis — O(L)
+HBM traffic per head instead of materializing (L, L) scores (the
+standard TPU flash-attention scheme; same m/l/o algebra the ring layer
+uses across devices, applied within one device).
 
 Same contract as ring_attention.attention: q (B, Lq, H, D),
 k/v (B, Lk, H, D), optional causal masking with global position offsets
 (shards of a longer sequence). Rows whose keys are all masked return 0,
 matching the ring layer's _finalize.
 
-Grid: (B*H, Lq blocks, Lk blocks) with the KV axis innermost — TPU grid
-steps run sequentially, so VMEM scratch carries the running statistics
-and the output block is written once, on the last KV step. In causal
-mode, KV blocks entirely above the diagonal skip their matmuls
-(roughly 2x fewer FLOPs at long L).
+What is fetched and what is computed are two sizes. The grid is
+(B*H, Lq blocks, Lk blocks) with the KV axis innermost, and a grid step
+FETCHES one block of up to 1024 rows of each operand: one DMA an
+operand and head, few grid steps (a step costs more than a small
+product). Inside a step the body COMPUTES in tiles (128 queries x 256
+keys at the tuned blocks), and ``tile_plan`` says which: a tile wholly
+above the diagonal, or wholly of padded keys, is not visited; a tile
+wholly below it runs without the mask (no iotas, compares or selects);
+only a tile the diagonal or the end of the keys crosses builds
+``_valid_mask``. At L <= 1024 one fetch block is the whole sequence, so
+all the skipping there is between tiles (20 of 32 run, 8 of them
+masked), not between grid blocks; at longer L a fetch block wholly above
+the diagonal has no tile to run. Everything but a block's indices is
+known at trace time, so the tiles are unrolled, straight-line code the
+compiler schedules as one block (a loop over tiles with traced bounds
+ran 1.4 x slower than no tiles at all). TPU grid steps run sequentially,
+so VMEM scratch carries the running statistics between KV blocks and
+the output block is written once, on the last KV step.
 
 The BACKWARD is also Pallas (O(L) memory): the forward additionally
 writes the per-row log-sum-exp, and two kernels recompute the
-probabilities blockwise — one accumulating dQ across KV blocks, one
-accumulating dK/dV across Q blocks (the standard split used because TPU
-grid steps are sequential: each kernel's scratch accumulator matches its
-innermost axis). Long-context training therefore never materializes the
-(L, L) score matrix in either direction.
+probabilities tile by tile under the same plan — one accumulating dQ
+per query tile across key tiles, one accumulating dK/dV per key tile
+across query tiles (the standard split used because TPU grid steps are
+sequential: each kernel's scratch accumulator matches its innermost
+axis). Long-context training therefore never materializes the (L, L)
+score matrix in either direction.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -39,10 +54,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# measured on v5e (H=8-16, D=64-128, causal fwd+bwd): 1024x1024 blocks
-# run ~2x faster than the 256x256 default at every L from 1k to 32k —
-# fewer grid steps and fewer online-softmax rescales per KV element.
-# The backward's (bq, bk) f32 intermediates need the VMEM of v5e+ parts.
+# FETCH blocks. Measured on v5e (H=8-16, D=64-128, causal fwd+bwd):
+# 1024x1024 grid blocks run ~2x faster than 256x256 grid blocks at every
+# L from 1k to 32k — fewer grid steps, each of which costs more than a
+# small product. That compared grid blocks; the compute tiles inside a
+# fetch block (_tile) pay no grid step.
 BLOCK_Q = 1024
 BLOCK_K = 1024
 
@@ -75,26 +91,216 @@ def _block_caps(d: int):
     return min(BLOCK_Q, cap), min(BLOCK_K, cap)
 
 
-def _fully_masked(qi, ki, bq, bk, q_offset, k_offset):
-    """True when KV block ki is entirely above Q block qi's diagonal."""
-    return (ki * bk + k_offset) > (qi * bq + (bq - 1) + q_offset)
+def _blocks(lq, lk, d):
+    cap_q, cap_k = _block_caps(d)
+    bq = min(cap_q, max(8, lq + ((-lq) % 8)))
+    bk = min(cap_k, max(128, lk + ((-lk) % 128)))
+    return bq, bk, (-lq) % bq, (-lk) % bk
 
 
-def _valid_mask(qi, ki, bq, bk, causal, q_offset, k_offset, lk_true):
-    kpos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    valid = kpos < lk_true
-    if causal:
-        qpos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        valid = valid & (qpos + q_offset >= kpos + k_offset)
+# COMPUTE tiles inside a fetch block, chosen on v5e among 64-512 a side
+# at L = 1024, D = 64 by the three kernels' times (PERF.md, PR 33): a
+# query tile is the stretch of rows that shares one walk over the keys
+# (128: more, shorter walks overlap better in the forward, whose row
+# maximum has to be known before its exponentials start); a key tile is
+# the width at which the diagonal is followed and the dK/dV kernel walks
+# the queries (256: at 128 its transposed products double).
+TILE_Q = 128
+TILE_K = 256
+
+
+def _tile(block: int, want: int) -> int:
+    """The compute tile of a fetch block: ``want`` rows where the block
+    holds two or more such tiles, else half of that, else the block
+    itself (a block that is a multiple of neither is one tile)."""
+    for t in (want, want // 2):
+        if block % t == 0 and block >= 2 * t:
+            return t
+    return block
+
+
+def _count(x: int, t: int, n: int) -> int:
+    """floor(x / t) held to [0, n]: how many whole tiles of t lie
+    under x."""
+    return min(max(x, 0), n * t) // t
+
+
+@dataclasses.dataclass(frozen=True)
+class TilePlan:
+    """Which compute tiles of which fetch block run, and how. The only
+    place that knows: the three kernels and the tests read the same
+    methods, and the tests hold the answer to a dense boolean mask.
+
+    A fetch block's tiles follow from two numbers, its *kind*: ``shift``,
+    the position of its first query less that of its first key (held to
+    the range in which it decides anything), and ``real``, how many of
+    its keys are not padding. Everything but the block's indices is a
+    Python int at trace time, so a call has a handful of kinds (one at
+    L <= 1024; below, on and above the diagonal at longer L, each with
+    or without padded keys) and a kernel holds one unrolled body a
+    kind. Padded QUERY rows are not the plan's business (they are
+    computed and sliced away, as ever)."""
+    lq: int
+    lk: int
+    bq: int
+    bk: int
+    tq: int
+    tk: int
+    causal: bool
+    q_offset: int
+    k_offset: int
+
+    @property
+    def grid(self):
+        """(query fetch blocks, key fetch blocks)."""
+        return -(-self.lq // self.bq), -(-self.lk // self.bk)
+
+    @property
+    def tiles(self):
+        """(query tiles, key tiles) of one fetch block."""
+        return self.bq // self.tq, self.bk // self.tk
+
+    def block_kind(self, qi, ki):
+        """(shift, real) of fetch block (qi, ki): Python ints for the
+        plan's own totals and the tests, traced scalars for a kernel,
+        whose block indices are program ids."""
+        clip = (lambda x, lo, hi: min(max(x, lo), hi)) \
+            if isinstance(qi, int) and isinstance(ki, int) else jnp.clip
+        real = clip(self.lk - ki * self.bk, 0, self.bk)
+        if not self.causal:
+            return 0, real
+        shift = (qi * self.bq + self.q_offset
+                 - ki * self.bk - self.k_offset)
+        # at -bq and under no query sees a key, from bk - 1 up all do
+        return clip(shift, -self.bq, self.bk - 1), real
+
+    def kinds(self) -> dict:
+        """kind -> how many fetch blocks of the call are of it."""
+        nq, nk = self.grid
+        found: dict = {}
+        for qi in range(nq):
+            for ki in range(nk):
+                kind = self.block_kind(qi, ki)
+                found[kind] = found.get(kind, 0) + 1
+        return found
+
+    def key_span(self, kind, i: int):
+        """For query tile i of a block of this kind -> (bare, run): key
+        tiles [0, bare) run without the mask, [bare, run) with it, and
+        [run, n) are not visited."""
+        shift, real = kind
+        n = self.bk // self.tk
+        run = _count(real + self.tk - 1, self.tk, n)
+        bare = _count(real, self.tk, n)
+        if self.causal:
+            gap = shift + i * self.tq   # the tile's first query sees 0..gap
+            run = min(run, _count(gap + self.tq - 1 + self.tk, self.tk, n))
+            bare = min(bare, _count(gap + 1, self.tk, n))
+        return min(bare, run), run
+
+    def query_span(self, kind, j: int):
+        """For key tile j of a block of this kind -> (first, bare):
+        query tiles [0, first) are not visited, [first, bare) run with
+        the mask, and [bare, n) without it."""
+        shift, real = kind
+        n = self.bq // self.tq
+        first = bare = 0
+        if self.causal:
+            gap = j * self.tk - shift   # the tile's first key is seen by gap..
+            first = _count(gap, self.tq, n)
+            bare = _count(gap + self.tk - 1 + self.tq - 1, self.tq, n)
+        if j * self.tk >= real:                 # nothing but padding
+            first = n
+        if (j + 1) * self.tk > real:            # some padding
+            bare = n
+        return first, bare
+
+    def runs(self, kind) -> bool:
+        """Whether a block of this kind has any tile to run; the last
+        query tile sees the most."""
+        return self.key_span(kind, self.bq // self.tq - 1)[1] > 0
+
+    def tile_kind(self, kind, i: int, j: int) -> str:
+        bare, run = self.key_span(kind, i)
+        return "bare" if j < bare else "masked" if j < run else "skipped"
+
+    def counts(self) -> dict:
+        """Totals over every fetch block: tiles_run / tiles_square is
+        the share of the square's products and element-wise work that
+        is executed (20 / 32 for a causal 1024 x 1024 in 128 x 256
+        tiles)."""
+        n_i, n_j = self.tiles
+        total = {"tiles_square": 0, "tiles_run": 0, "tiles_masked": 0}
+        for kind, blocks in self.kinds().items():
+            spans = [self.key_span(kind, i) for i in range(n_i)]
+            total["tiles_square"] += blocks * n_i * n_j
+            total["tiles_run"] += blocks * sum(run for _, run in spans)
+            total["tiles_masked"] += blocks * sum(
+                run - bare for bare, run in spans)
+        return total
+
+
+def tile_plan(lq: int, lk: int, d: int, causal: bool, q_offset: int = 0,
+              k_offset: int = 0) -> TilePlan:
+    """The plan of one call, from what the call can observe: lengths,
+    head width (through the block caps), ``causal`` and the offsets."""
+    bq, bk, _, _ = _blocks(lq, lk, d)
+    return TilePlan(lq, lk, bq, bk, _tile(bq, TILE_Q), _tile(bk, TILE_K),
+                    bool(causal), int(q_offset), int(k_offset))
+
+
+def _valid_mask(plan: TilePlan, kind, r0: int, nr: int, c0: int, nc: int):
+    """(nr, nc) mask of query rows r0.. against key rows c0.. of a
+    block of this kind (block-relative): padding keys always; causal by
+    position. Only the comparisons that can fail in there are built."""
+    shift, real = kind
+    col = lax.broadcasted_iota(jnp.int32, (nr, nc), 1)
+    valid = None
+    if real - c0 < nc:
+        valid = col < real - c0
+    gap = shift + r0 - c0               # row r sees columns 0..r+gap
+    if plan.causal and gap < nc - 1:
+        seen = lax.broadcasted_iota(jnp.int32, (nr, nc), 0) + gap >= col
+        valid = seen if valid is None else valid & seen
     return valid
 
 
+def _each_kind(plan: TilePlan, qi, ki, body):
+    """``body(kind)`` once for every kind of fetch block that has a tile
+    to run, each under the ``pl.when`` that picks it by the program's
+    block indices. A block with no tile to run (wholly above the
+    diagonal) leaves the scratch untouched, exactly as if it had
+    contributed nothing (which it would have)."""
+    shift, real = plan.block_kind(qi, ki)
+    for kind in plan.kinds():
+        if plan.runs(kind):
+            pl.when((shift == kind[0]) & (real == kind[1]))(
+                functools.partial(body, kind))
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _mask_from(x, start: int, valid, fill):
+    """x with its columns from ``start`` on set to ``fill`` where not
+    valid; the columns before it are not touched."""
+    tail = jnp.where(valid, x[:, start:], fill)
+    return tail if start == 0 else jnp.concatenate(
+        [x[:, :start], tail], axis=1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, q_offset: int, k_offset: int,
-                lq_true: int, lk_true: int, bq: int, bk: int):
+                *, plan: TilePlan, scale: float):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    tq, tk = plan.tq, plan.tk
 
     @pl.when(ki == 0)
     def _():
@@ -102,37 +308,41 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)                  # (bq, D)
-        k = k_ref[0].astype(jnp.float32)                  # (bk, D)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    def block(kind):
+        # Every query tile's scores and row maximum first, then their
+        # exponentials: a tile's maximum has to be known before its
+        # exponentials start, and in this order the next tile's product
+        # fills that wait.
+        scores = {}
+        for i in range(plan.bq // tq):
+            bare, run = plan.key_span(kind, i)
+            if not run:
+                continue
+            rows = pl.ds(i * tq, tq)
+            keys = pl.ds(0, run * tk)       # every key the tile sees here
+            q = q_ref[0, rows, :].astype(jnp.float32) * scale   # (tq, D)
+            s = _dot(q, k_ref[0, keys, :].astype(jnp.float32), _NT)
+            valid = _valid_mask(plan, kind, i * tq, tq, bare * tk,
+                                (run - bare) * tk) if run > bare else None
+            if valid is not None:
+                s = _mask_from(s, bare * tk, valid, NEG_INF)
+            m_prev = m_scr[rows]                                # (tq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            scores[i] = (rows, keys, bare * tk, s, valid, m_prev, m_new)
+        for rows, keys, start, s, valid, m_prev, m_new in scores.values():
+            p = jnp.exp(s - m_new)                              # (tq, keys)
+            if valid is not None:
+                # fully-masked-so-far rows keep m at NEG_INF, where
+                # exp(s - m) is exp(0) for every masked key
+                p = _mask_from(p, start, valid, 0.0)
+            corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
+            l_scr[rows] = l_scr[rows] * corr + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[rows] = acc_scr[rows] * corr + _dot(
+                p, v_ref[0, keys, :].astype(jnp.float32), _NN)
+            m_scr[rows] = m_new
 
-        # mask: padding keys always; causal by global positions
-        valid = _valid_mask(qi, ki, bq, bk, causal, q_offset, k_offset,
-                            lk_true)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_scr[:]                                  # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # fully-masked-so-far rows keep m at NEG_INF; shift by m_new only
-        # where finite so exp() never sees inf-inf
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (bq, bk)
-        corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-
-    if causal:
-        # skip KV blocks entirely above the diagonal — the scratch
-        # statistics are untouched, exactly as if the block contributed
-        # nothing (which it would have)
-        pl.when(jnp.logical_not(
-            _fully_masked(qi, ki, bq, bk, q_offset, k_offset)))(body)
-    else:
-        body()
+    _each_kind(plan, qi, ki, block)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -145,102 +355,94 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
-               dq_scr, *, scale: float, causal: bool, q_offset: int,
-               k_offset: int, lk_true: int, bq: int, bk: int):
+               dq_scr, *, plan: TilePlan, scale: float):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    tq, tk = plan.tq, plan.tk
 
     @pl.when(ki == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)                  # (bq, D)
-        k = k_ref[0].astype(jnp.float32)                  # (bk, D)
-        v = v_ref[0].astype(jnp.float32)                  # (bk, D)
-        g = g_ref[0].astype(jnp.float32)                  # (bq, D)
-        lse = lse_ref[0]                                  # (bq, 1)
-        delta = dlt_ref[0]                                # (bq, 1)
+    def block(kind):
+        for i in range(plan.bq // tq):
+            bare, run = plan.key_span(kind, i)
+            if not run:
+                continue
+            rows = pl.ds(i * tq, tq)
+            keys = pl.ds(0, run * tk)       # every key the tile sees here
+            q = q_ref[0, rows, :].astype(jnp.float32) * scale   # (tq, D)
+            g = g_ref[0, rows, :].astype(jnp.float32)           # (tq, D)
+            k = k_ref[0, keys, :].astype(jnp.float32)           # (keys, D)
+            v = v_ref[0, keys, :].astype(jnp.float32)           # (keys, D)
+            p = jnp.exp(_dot(q, k, _NT) - lse_ref[0, rows, :])  # (tq, keys)
+            if run > bare:
+                p = _mask_from(p, bare * tk, _valid_mask(
+                    plan, kind, i * tq, tq, bare * tk, (run - bare) * tk),
+                    0.0)
+            ds = p * (_dot(g, v, _NT) - dlt_ref[0, rows, :])
+            dq_scr[rows] = dq_scr[rows] + _dot(ds, k, _NN)
 
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        valid = _valid_mask(qi, ki, bq, bk, causal, q_offset, k_offset,
-                            lk_true)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)        # (bq, bk)
-        dp = lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                      # (bq, bk)
-        dq_scr[:] = dq_scr[:] + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(jnp.logical_not(
-            _fully_masked(qi, ki, bq, bk, q_offset, k_offset)))(body)
-    else:
-        body()
+    _each_kind(plan, qi, ki, block)
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        # ds lacks the scores' scale until here: once a row, not once
+        # a score
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, g_ref, lse_ref, dlt_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                causal: bool, q_offset: int, k_offset: int, lk_true: int,
-                bq: int, bk: int):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, plan: TilePlan,
+                scale: float):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    tq, tk = plan.tq, plan.tk
+    n_i = plan.bq // tq
 
     @pl.when(qi == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)                  # (bq, D)
-        k = k_ref[0].astype(jnp.float32)                  # (bk, D)
-        v = v_ref[0].astype(jnp.float32)                  # (bk, D)
-        g = g_ref[0].astype(jnp.float32)                  # (bq, D)
-        lse = lse_ref[0]                                  # (bq, 1)
-        delta = dlt_ref[0]                                # (bq, 1)
+    def block(kind):
+        for j in range(plan.bk // tk):
+            first, bare = plan.query_span(kind, j)
+            if first == n_i:
+                continue
+            cols = pl.ds(j * tk, tk)
+            k = k_ref[0, cols, :].astype(jnp.float32) * scale   # (tk, D)
+            v = v_ref[0, cols, :].astype(jnp.float32)           # (tk, D)
+            dk, dv = dk_scr[cols], dv_scr[cols]
+            # the queries the diagonal crosses, then those wholly under it
+            for lo, hi, masked in ((first, bare, True), (bare, n_i, False)):
+                if hi == lo:
+                    continue
+                r0, nr = lo * tq, (hi - lo) * tq
+                rows = pl.ds(r0, nr)
+                q = q_ref[0, rows, :].astype(jnp.float32)       # (nr, D)
+                g = g_ref[0, rows, :].astype(jnp.float32)       # (nr, D)
+                p = jnp.exp(_dot(q, k, _NT) - lse_ref[0, rows, :])
+                if masked:
+                    p = jnp.where(_valid_mask(plan, kind, r0, nr,
+                                              j * tk, tk), p, 0.0)
+                # padded Q rows carry g == 0 and delta == 0, so their p
+                # rows cancel out of both accumulations — no extra
+                # masking needed
+                dv = dv + _dot(p, g, _TN)                       # (tk, D)
+                ds = p * (_dot(g, v, _NT) - dlt_ref[0, rows, :])
+                dk = dk + _dot(ds, q, _TN)                      # (tk, D)
+            dk_scr[cols], dv_scr[cols] = dk, dv
 
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        valid = _valid_mask(qi, ki, bq, bk, causal, q_offset, k_offset,
-                            lk_true)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)        # (bq, bk)
-        # padded Q rows carry g == 0 and delta == 0, so their p rows
-        # cancel out of both accumulations — no extra masking needed
-        dv_scr[:] = dv_scr[:] + lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, D)
-        dp = lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                      # (bq, bk)
-        dk_scr[:] = dk_scr[:] + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, D)
-
-    if causal:
-        pl.when(jnp.logical_not(
-            _fully_masked(qi, ki, bq, bk, q_offset, k_offset)))(body)
-    else:
-        body()
+    _each_kind(plan, qi, ki, block)
 
     @pl.when(qi == nq - 1)
     def _():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        # ds lacks the scores' scale until here, as in _dq_kernel
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _blocks(lq, lk, d):
-    cap_q, cap_k = _block_caps(d)
-    bq = min(cap_q, max(8, lq + ((-lq) % 8)))
-    bk = min(cap_k, max(128, lk + ((-lk) % 128)))
-    return bq, bk, (-lq) % bq, (-lk) % bk
 
 
 def _lse_pad(lq: int, d: int) -> int:
@@ -301,6 +503,7 @@ def _flash_forward(q, k, v, causal: bool = False, q_offset: int = 0,
     lk = k.shape[1]
     scale = 1.0 / float(d) ** 0.5
     bq, bk, pad_q, pad_k = _blocks(lq, lk, d)
+    plan = tile_plan(lq, lk, d, causal, q_offset, k_offset)
 
     # heads-major (BH, L, D) layout for per-(batch, head) grid blocks
     qt = _heads_major(q, pad_q)
@@ -309,9 +512,7 @@ def _flash_forward(q, k, v, causal: bool = False, q_offset: int = 0,
 
     grid = (b * h, (lq + pad_q) // bq, (lk + pad_k) // bk)
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, q_offset=q_offset,
-            k_offset=k_offset, lq_true=lq, lk_true=lk, bq=bq, bk=bk),
+        functools.partial(_fwd_kernel, plan=plan, scale=scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -360,8 +561,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
                     axis=-1, keepdims=True)
     # lse already (BH, Lq+pad, 1) from the forward
 
-    kw = dict(scale=scale, causal=causal, q_offset=q_offset,
-              k_offset=k_offset, lk_true=lk, bq=bq, bk=bk)
+    kw = dict(plan=tile_plan(lq, lk, d, causal, q_offset, k_offset),
+              scale=scale)
     nq, nk_blocks = (lq + pad_q) // bq, (lk + pad_k) // bk
 
     dq = pl.pallas_call(
