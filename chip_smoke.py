@@ -3,7 +3,8 @@
 One process drives the normal path once, through the entry points a user
 calls, at the width of a dim-2048 transformer LM: train a few steps,
 score with the model ``fit`` returned, serve a classifier of the same
-trunk over HTTP, boost a HIGGS-shaped forest at 63 and 255 bins, and
+trunk over HTTP, compile the grouped-query flash call and a 1536-wide
+grouped product, boost a HIGGS-shaped forest at 63 and 255 bins, and
 run a fused featurize -> booster pipeline. Weights are random from a
 seed, data is synthetic, nothing touches the network. Every leg is
 fatal: a failure propagates, the exit code is non-zero and no result
@@ -53,6 +54,11 @@ FULL = {
     "gbdt_hist_method": "auto",
     "gbdt_min_auc": 0.75,
     "pipeline_rows": 2000,
+    # the grouped-query flash call and an expert product whose width is
+    # no multiple of the kernel's 1024 tile (LFM2-24B-A2B's shapes)
+    "gqa": {"batch": 1, "length": 2048, "heads": 32, "kv_heads": 8,
+            "head_dim": 64},
+    "grouped": {"rows": 4096, "groups": 8, "k": 2048, "n": 1536},
 }
 
 # a bf16 forward against the float32 reference, as relative L2 error of
@@ -283,6 +289,54 @@ def leg_serve(cfg: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def leg_kernels(cfg: dict) -> dict:
+    """The two kernels that ``hybrid_moe_lm`` asks of the chip beyond
+    what the legs above compile: the flash forward with fewer key/value
+    heads than query heads (no repeated copy of K and V) against the
+    einsum on repeated K and V, and the grouped product at a width the
+    1024 tile does not divide against a loop over the groups."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
+    from mmlspark_tpu.parallel.ring_attention import (
+        attention, dense_attention)
+    g = cfg["gqa"]
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (g["batch"], g["length"], g["heads"],
+                                    g["head_dim"]), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (g["batch"], g["length"], g["kv_heads"],
+                                   g["head_dim"]), jnp.bfloat16)
+            for kk in keys[1:3])
+    got = jax.jit(lambda q, k, v: attention(q, k, v, causal=True))(q, k, v)
+    want = jax.jit(lambda q, k, v: dense_attention(q, k, v, True))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    gqa_err = float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                    / jnp.linalg.norm(want))
+    assert gqa_err < BF16_REL_TOL, f"grouped-query flash: {gqa_err}"
+    m = cfg["grouped"]
+    lhs = jax.random.normal(keys[3], (m["rows"], m["k"]), jnp.bfloat16)
+    rhs = jax.random.normal(keys[4], (m["groups"], m["k"], m["n"]),
+                            jnp.bfloat16) * m["k"] ** -0.5
+    # uneven groups, one empty, the last rows in no group
+    share = np.arange(m["groups"]) % 3
+    sizes = (share * (m["rows"] - 8) // max(1, share.sum())).astype(np.int32)
+    out = jax.jit(lambda a, b, s: grouped_matmul(a, b, s, jnp.float32))(
+        lhs, rhs, jnp.asarray(sizes))
+    want = np.zeros((m["rows"], m["n"]), np.float32)
+    lo = 0
+    for e, size in enumerate(sizes):
+        want[lo:lo + size] = np.asarray(jnp.einsum(
+            "tk,kn->tn", lhs[lo:lo + size], rhs[e],
+            preferred_element_type=jnp.float32))
+        lo += size
+    gm_err = float(np.linalg.norm(np.asarray(out) - want)
+                   / np.linalg.norm(want))
+    assert gm_err < BF16_REL_TOL, f"grouped product: {gm_err}"
+    assert not np.asarray(out[lo:]).any(), "rows of no group are not zero"
+    return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
+            "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)]}
+
+
 def _auc(y: np.ndarray, score: np.ndarray) -> float:
     """Mann-Whitney AUC (ties are measure-zero for float scores)."""
     ranks = np.empty(len(score))
@@ -433,6 +487,7 @@ def main() -> int:
     run("transform", leg_transform, cfg, model, toks, n_dev)
     del model
     run("serve", leg_serve, cfg)
+    run("kernels", leg_kernels, cfg)
     run("gbdt", leg_gbdt, cfg, n_dev)
 
     peaks = device_peaks()      # device 0 also ran the references

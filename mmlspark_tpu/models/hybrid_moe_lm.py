@@ -1,0 +1,275 @@
+"""``hybrid_moe_lm``: a decoder whose layers choose their *operator*.
+
+A third network family beside ``networks.Transformer`` and
+``latent_moe_lm``: each layer's sequence operator comes from
+``layer_types``, one entry a layer, and one kind is not attention at
+all. "conv" is a gated short convolution (a per-channel causal filter of
+``conv_L_cache`` taps between two gates and two projections);
+"full_attention" is grouped-query attention with a per-head RMSNorm on
+queries and keys and rotate-half rotary positions. The first
+``num_dense_layers`` layers have a gated (SwiGLU) feed-forward, the
+others the expert layer of expert_layer.py (sigmoid scores, a bias that
+enters the choice only, no shared expert), with every expert on this
+chip. The field names are those of the
+published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B).
+
+    h = x + Op_i(RMSNorm(x));  x' = h + FFN_i(RMSNorm(h))
+    logits = E^T RMSNorm(x[last])           E the tied (vocab, hidden) embedding
+
+It is a scorer: token ids (b, l) in, float32 next-token logits of the
+last position out. docs/hybrid_moe_lm.md has the equations, the spec
+keys, what ``capture`` returns, the scopes and the counters.
+
+Parameters are held in ``dtype`` (bfloat16 behind the server). Matrix
+products take ``dtype`` operands and accumulate in float32; norms,
+rotary angles, the convolution's gates and taps, router scores, top-k
+and softmax are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import flax.linen as nn
+
+from mmlspark_tpu.models.expert_layer import (
+    ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones, _pass_rows,
+    _row_loads, rms_norm)
+
+Dtype = Any
+OPERATORS = ("conv", "full_attention")
+# positions at a row's end whose chosen experts ride out of the step
+# (``routed_tail``): a choice at position t reaches the last position's
+# logits through the later layers' convolutions, two positions a layer,
+# so with up to 7 of them after an expert layer the last 15 can; every
+# earlier choice reaches it through attention alone, one key in l
+ROUTED_TAIL = 16
+# ... and positions at a row's end whose attention outputs ride out
+# beside them (``attention_tail``), every attention layer's: what a
+# comparison holds the q and k norms and the grouping of heads by, out
+# of the execution that gave the logits
+ATTENTION_TAIL = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridMoEConfig:
+    """The sizes of one ``hybrid_moe_lm``, as ``networkSpec`` names them
+    (docs/hybrid_moe_lm.md). The defaults are LFM2-24B-A2B's published
+    widths with one period of its layers."""
+
+    vocab_size: int = 65536
+    max_len: int = 8192
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = ("conv", "full_attention", "conv",
+                                    "conv", "conv")
+    num_dense_layers: int = 1
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    conv_L_cache: int = 3
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.0
+    gate_norm_eps: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - set(OPERATORS)
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}; a "
+                             f"layer's operator is one of {OPERATORS}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads over "
+                f"{self.num_key_value_heads} key/value heads: not a "
+                f"whole group a head")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size is no multiple of the heads")
+
+    # what the shared expert layer reads, under its names: every expert
+    # is on this chip (there is no all-to-all to combine a share)
+    experts_total = experts_held = property(lambda self: self.num_experts)
+    expert_rank = 0
+    n_shared_experts = 0
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+
+def rope_rotate_half(x, positions, theta: float):
+    """Rotate the pairs (x[i], x[i + d/2]) of the last axis by
+    positions * theta**(-2i/d). x (b, l, h, d) float32, positions (l,)."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    ang = positions.astype(_F32)[:, None] * inv[None, :]       # (l, d/2)
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def short_conv(g, taps):
+    """c[t] = sum_j taps[j] * g[t - (L - 1) + j] a channel, zeros before
+    t = 0: the last tap weighs the present. g (b, l, dim) float32,
+    taps (L, dim)."""
+    n, length = taps.shape[0], g.shape[1]
+    past = jnp.pad(g, ((0, 0), (n - 1, 0), (0, 0)))
+    taps = taps.astype(_F32)
+    return sum(taps[j] * past[:, j:j + length] for j in range(n))
+
+
+class ShortConv(nn.Module):
+    """[B, C, z] = split3(W_in u); Op = W_out (C * conv(B * z))."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        c = self.cfg
+        dim, dt = c.hidden_size, c.dtype
+        w_in = self.param("in_proj", _fan_in(dim), (dim, 3 * dim), dt)
+        taps = self.param("conv", _fan_in(c.conv_L_cache),
+                          (c.conv_L_cache, dim), dt)
+        w_out = self.param("out_proj", _fan_in(dim), (dim, dim), dt)
+        with jax.named_scope("short_conv"):
+            bcz = _mm("bld,de->ble", u, w_in)
+            with jax.named_scope("short_conv_gate"):
+                gate_b, gate_c, z = jnp.split(bcz, 3, axis=-1)
+                y = (gate_c * short_conv(gate_b * z, taps)).astype(dt)
+            return _mm("bld,de->ble", y, w_out, dt)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention, H query heads over H_kv key/value heads, a
+    per-head RMSNorm on q and k before the rotary step."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.parallel.ring_attention import attention
+        c = self.cfg
+        dim, dt = c.hidden_size, c.dtype
+        h, hk, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        w_q = self.param("q_proj", _fan_in(dim), (dim, h, d), dt)
+        w_k = self.param("k_proj", _fan_in(dim), (dim, hk, d), dt)
+        w_v = self.param("v_proj", _fan_in(dim), (dim, hk, d), dt)
+        w_o = self.param("out_proj", _fan_in(h * d), (h, d, dim), dt)
+        q_norm = self.param("q_layernorm", _ones, (d,), dt)
+        k_norm = self.param("k_layernorm", _ones, (d,), dt)
+        pos = jnp.arange(u.shape[1])
+        with jax.named_scope("gqa_project"):
+            q = rms_norm(_mm("bld,dhk->blhk", u, w_q), q_norm, c.norm_eps)
+            k = rms_norm(_mm("bld,dhk->blhk", u, w_k), k_norm, c.norm_eps)
+            q = rope_rotate_half(q, pos, c.rope_theta).astype(dt)
+            k = rope_rotate_half(k, pos, c.rope_theta).astype(dt)
+            v = _mm("bld,dhk->blhk", u, w_v, dt)
+        with jax.named_scope("gqa_attend"):
+            o = attention(q, k, v, causal=True)
+        with jax.named_scope("gqa_project"):
+            return _mm("blhk,hkd->bld", o, w_o, dt)
+
+
+class HybridMoELM(nn.Module):
+    """See the module's docstring. ``cfg`` holds the sizes
+    (``build_network`` makes it from the spec's keys). ``capture``:
+    ``operator_<i>`` the output of layer i's operator (before the
+    residual add), ``block_<i>`` the hidden state after layer i,
+    ``routed_<i>`` the (b, l, k) experts an expert layer chose,
+    ``final`` the normed last position."""
+
+    int_input = True  # consumes token ids, not float features
+    # per-row numbers that ride out with the logits (TPUModel observes
+    # them into histograms of these names, one entry a real row)
+    row_stats = ("moe_tokens_held", "moe_load_max_over_mean", "moe_passes")
+    # ... and per-row arrays that ride out as outputs a ``fetchDict``
+    # can name: ``routed_tail`` (b, expert layers, ROUTED_TAIL, k) int32,
+    # the experts this very execution chose at each row's last positions
+    # (a comparison with a reference has to know them: where score +
+    # bias nearly tie, bfloat16 rightly chooses otherwise now and then),
+    # and ``attention_tail`` (b, attention layers, ATTENTION_TAIL,
+    # hidden), every attention operator's output at those positions
+    row_outputs = ("routed_tail", "attention_tail")
+
+    cfg: HybridMoEConfig = HybridMoEConfig()
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False,
+                 capture: Optional[str] = None):
+        cfg = self.cfg
+        b, l = tokens.shape
+        if l > cfg.max_len:
+            raise ValueError(f"sequence {l} exceeds max_len={cfg.max_len}")
+        dt, dim = cfg.dtype, cfg.hidden_size
+        embed = self.param("embed", nn.initializers.normal(1.0),
+                           (cfg.vocab_size, dim), dt)
+        x = embed[tokens.astype(jnp.int32)]
+        held_tokens = jnp.zeros((b,), _F32)
+        imbalance, passes, expert_layers = jnp.zeros((b,), _F32), 0.0, 0
+        tails, attended = [], []
+        first = cfg.expert_rank * cfg.experts_held
+        pass_rows = _pass_rows(b * l * cfg.num_experts_per_tok,
+                               cfg.experts_held, cfg.num_experts)
+        for i, kind in enumerate(cfg.layer_types):
+            u = rms_norm(x, self.param(f"layer_{i}_operator_norm", _ones,
+                                       (dim,), dt), cfg.norm_eps).astype(dt)
+            if kind == "conv":
+                a = ShortConv(cfg, name=f"layer_{i}_conv")(u)
+            else:
+                a = GroupedQueryAttention(cfg, name=f"layer_{i}_attn")(u)
+                attended.append(a[:, -ATTENTION_TAIL:])
+            if capture == f"operator_{i}":
+                return a
+            x = x + a
+            u = rms_norm(x, self.param(f"layer_{i}_ffn_norm", _ones,
+                                       (dim,), dt), cfg.norm_eps).astype(dt)
+            u = u.reshape(b * l, dim)
+            if i < cfg.num_dense_layers:
+                y = GatedMLP(cfg, cfg.intermediate_size,
+                             name=f"layer_{i}_mlp")(u)
+            else:
+                y, chosen, load = ExpertLayer(cfg, name=f"layer_{i}_moe")(u)
+                if capture == f"routed_{i}":
+                    return chosen.reshape(b, l, -1)
+                tails.append(chosen.reshape(b, l, -1)[:, -ROUTED_TAIL:])
+                by_row = _row_loads(chosen.reshape(b, -1), first,
+                                    cfg.experts_held)
+                held_tokens += by_row.sum(-1)
+                imbalance += by_row.max(-1) / jnp.maximum(
+                    by_row.mean(-1), 1.0)
+                passes += jnp.ceil(jnp.sum(load) / pass_rows)
+                expert_layers += 1
+            x = x + y.reshape(b, l, dim).astype(dt)
+            if capture == f"block_{i}":
+                return x
+        with jax.named_scope("lm_head_last"):
+            last = rms_norm(x[:, -1], self.param(
+                "embedding_norm", _ones, (dim,), dt), cfg.norm_eps).astype(dt)
+            if capture == "final":
+                return last
+            logits = _mm("bd,vd->bv", last, embed)
+        self.sow("stats", "moe_tokens_held", held_tokens)
+        self.sow("stats", "moe_load_max_over_mean",
+                 imbalance / max(expert_layers, 1))
+        self.sow("stats", "moe_passes", jnp.broadcast_to(
+            jnp.asarray(passes / max(expert_layers, 1), _F32), (b,)))
+        if tails:
+            self.sow("stats", "routed_tail",
+                     jnp.stack(tails, axis=1).astype(jnp.int32))
+        if attended:
+            self.sow("stats", "attention_tail", jnp.stack(attended, axis=1))
+        return logits
+
+    def feature_layers(self) -> List[str]:
+        n = len(self.cfg.layer_types)
+        return ([f"block_{i}" for i in range(n)]
+                + [f"operator_{i}" for i in range(n)]
+                + [f"routed_{i}" for i in range(self.cfg.num_dense_layers, n)]
+                + ["final"])

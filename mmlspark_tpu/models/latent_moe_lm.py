@@ -43,36 +43,18 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax import lax
 
-from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+# the expert layer and the pieces around it are shared with
+# ``hybrid_moe_lm`` and live in expert_layer.py; the names stay
+# importable from here
+from mmlspark_tpu.models.expert_layer import (  # noqa: F401
+    PASS_SHARE, ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones,
+    _pass_rows, _row_loads, rms_norm, route, routed_experts, swiglu)
 from mmlspark_tpu.ops.sparse_select import select_keys
 
 Dtype = Any
-_F32 = jnp.float32
 
-# rows of the routed experts' input gathered at a time, as a share of
-# the mean a step routes here: one pass takes the mean and a margin, and
-# a layer routed more than that here takes as many passes as it needs,
-# so no token is dropped
-PASS_SHARE = 1.25
 # the LayerNorm of the selector's key (the published inference code's)
 SELECTOR_KEY_NORM_EPS = 1e-6
-
-
-def _fan_in(fan_in: int):
-    def init(key, shape, dtype):
-        return (jax.random.normal(key, shape, _F32)
-                * fan_in ** -0.5).astype(dtype)
-    return init
-
-
-def _ones(key, shape, dtype):
-    return jnp.ones(shape, dtype)
-
-
-def rms_norm(x, scale, eps: float):
-    xf = x.astype(_F32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return y * scale.astype(_F32)
 
 
 def rope_interleaved(x, positions, theta: float):
@@ -87,18 +69,6 @@ def rope_interleaved(x, positions, theta: float):
     a, b = pair[..., 0], pair[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      axis=-1).reshape(x.shape)
-
-
-def _mm(expr, a, b, out=None):
-    """A matrix product in the operands' dtype, float32 accumulation."""
-    y = jnp.einsum(expr, a, b.astype(a.dtype),
-                   preferred_element_type=_F32)
-    return y if out is None else y.astype(out)
-
-
-def swiglu(u, gate, up, down):
-    h = jax.nn.silu(_mm("tk,kn->tn", u, gate)) * _mm("tk,kn->tn", u, up)
-    return _mm("tn,nk->tk", h.astype(u.dtype), down)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,6 +101,7 @@ class LatentMoEConfig:
     num_experts_per_tok: int = 8
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
+    gate_norm_eps: float = 0.0
     dtype: Dtype = jnp.bfloat16
 
     def __post_init__(self):
@@ -255,119 +226,6 @@ def _select_row(p, c, u, c_q, pos):
     return select_keys(q_i, w, k_i, c.index_topk)
 
 
-class GatedMLP(nn.Module):
-    cfg: Any
-    width: int
-
-    @nn.compact
-    def __call__(self, u):
-        c = self.cfg
-        dim = c.hidden_size
-        gate = self.param("gate", _fan_in(dim), (dim, self.width), c.dtype)
-        up = self.param("up", _fan_in(dim), (dim, self.width), c.dtype)
-        down = self.param("down", _fan_in(self.width),
-                          (self.width, dim), c.dtype)
-        return swiglu(u, gate, up, down)
-
-
-class ExpertLayer(nn.Module):
-    """Routes over all ``experts_total``; computes the experts held here
-    and the shared expert. Returns (y, chosen (t, k), load (held,))."""
-
-    cfg: Any
-
-    @nn.compact
-    def __call__(self, u):
-        c = self.cfg
-        dim, width, held = (c.hidden_size, c.moe_intermediate_size,
-                            c.experts_held)
-        router = self.param("router", _fan_in(dim),
-                            (c.experts_total, dim), c.dtype)
-        bias = self.param("router_bias", nn.initializers.normal(0.02),
-                          (c.experts_total,), _F32)
-        w_gate = self.param("experts_gate", _fan_in(dim),
-                            (held, dim, width), c.dtype)
-        w_up = self.param("experts_up", _fan_in(dim),
-                          (held, dim, width), c.dtype)
-        w_down = self.param("experts_down", _fan_in(width),
-                            (held, width, dim), c.dtype)
-        with jax.named_scope("moe_route"):
-            chosen, gates = route(u, router, bias, c.num_experts_per_tok,
-                                  c.routed_scaling_factor)
-        with jax.named_scope("moe_experts"):
-            y, load = routed_experts(u, chosen, gates, w_gate, w_up,
-                                     w_down, c.expert_rank * held,
-                                     c.experts_total)
-        with jax.named_scope("moe_shared"):
-            for i in range(c.n_shared_experts):
-                y = y + GatedMLP(c, width, name=f"shared_{i}")(u)
-        return y.astype(u.dtype), chosen, load
-
-
-def route(u, router, bias, k: int, scaling: float):
-    """Sigmoid scores over every expert in float32; the k largest of
-    score + bias are chosen, and the chosen scores (without the bias),
-    normalised over the k and scaled, are the gates."""
-    logits = jnp.einsum("td,ed->te", u.astype(_F32), router.astype(_F32),
-                        precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, chosen = lax.top_k(scores + bias[None, :], k)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    gates = scaling * picked / jnp.sum(picked, -1, keepdims=True)
-    return chosen, gates
-
-
-def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
-                   total: int):
-    """The part of sum_i g_i E_i(u) that the experts [first, first +
-    held) give. u (t, dim); chosen, gates (t, k). The (token, expert)
-    pairs routed here are sorted by expert and go through the grouped
-    products in passes of ``PASS_SHARE`` of the mean, as many as it
-    takes (one, unless the experts held are popular)."""
-    t, k = chosen.shape
-    held = w_gate.shape[0]
-    local = (chosen - first).reshape(-1)
-    here = (local >= 0) & (local < held)
-    key = jnp.where(here, local, held)
-    order = jnp.argsort(key, stable=True)
-    load = jnp.sum((key[:, None] == jnp.arange(held)[None, :]
-                    ).astype(jnp.int32), axis=0)
-    ends = jnp.cumsum(load)
-    n_here = ends[-1]
-    rows = _pass_rows(t * k, held, total)
-    pad = -(-t * k // rows) * rows - t * k
-    token_of = jnp.pad(order // k, (0, pad))
-    gate_of = jnp.pad(gates.reshape(-1)[order], (0, pad))
-
-    def one_pass(i, y):
-        lo = i * rows
-        tok = lax.dynamic_slice_in_dim(token_of, lo, rows)
-        gate = lax.dynamic_slice_in_dim(gate_of, lo, rows)
-        sizes = jnp.clip(ends - lo, 0, rows) \
-            - jnp.clip(ends - load - lo, 0, rows)
-        x = u[tok]
-        h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32)) \
-            * grouped_matmul(x, w_up, sizes, _F32)
-        out = grouped_matmul(h.astype(u.dtype), w_down, sizes, _F32)
-        live = (lo + jnp.arange(rows)) < n_here
-        out = jnp.where(live[:, None], out * gate[:, None], 0.0)
-        return y.at[tok].add(out)
-
-    y = lax.fori_loop(0, (n_here + rows - 1) // rows, one_pass,
-                      jnp.zeros((t, u.shape[1]), _F32))
-    return y, load
-
-
-def _pass_rows(pairs: int, held: int, total: int) -> int:
-    """Rows a pass takes: ``PASS_SHARE`` of the mean number of pairs
-    routed here, a multiple of 512 (the kernel's row tile), no more than
-    the pairs there are."""
-    want = int(pairs * held / total * PASS_SHARE)
-    if want >= 512:
-        return min(-(-want // 512) * 512, -(-pairs // 512) * 512)
-    return min(max(8, -(-want // 8) * 8), -(-pairs // 8) * 8)
-
-
 class LatentMoELM(nn.Module):
     """See the module's docstring. ``cfg`` holds the sizes
     (``build_network`` makes it from the spec's keys). ``capture``:
@@ -450,11 +308,3 @@ class LatentMoELM(nn.Module):
                 + [f"routed_{i}" for i, kind in enumerate(kinds)
                    if kind == "sparse"]
                 + ["final"])
-
-
-def _row_loads(chosen, first: int, held: int):
-    """(b, held) float32: the (token, expert) pairs of each row that
-    fall to each expert held here."""
-    local = chosen - first
-    return jnp.sum((local[:, :, None] == jnp.arange(held)[None, None, :]
-                    ).astype(_F32), axis=1)

@@ -348,6 +348,14 @@ def _latent_moe_lm(**spec) -> nn.Module:
     return LatentMoELM(LatentMoEConfig(**spec))
 
 
+def _hybrid_moe_lm(**spec) -> nn.Module:
+    # a layer's operator chosen per layer (gated short convolution or
+    # grouped-query attention); the expert layer is latent_moe_lm's
+    from mmlspark_tpu.models.hybrid_moe_lm import (
+        HybridMoEConfig, HybridMoELM)
+    return HybridMoELM(HybridMoEConfig(**spec))
+
+
 NETWORK_REGISTRY: Dict[str, Callable[..., nn.Module]] = {
     "mlp": MLP,
     "convnet": ConvNet,
@@ -355,6 +363,7 @@ NETWORK_REGISTRY: Dict[str, Callable[..., nn.Module]] = {
     "bilstm": BiLSTMTagger,
     "transformer": Transformer,
     "latent_moe_lm": _latent_moe_lm,
+    "hybrid_moe_lm": _hybrid_moe_lm,
 }
 
 

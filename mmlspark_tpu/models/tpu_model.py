@@ -750,14 +750,23 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             device = [outputs[m].astype(jnp.float32)
                       if outputs[m].dtype == jnp.bfloat16 else outputs[m]
                       for m in fetches.values()]
+            stat_out = {k[len(STAT_PREFIX):]: v for k, v in outputs.items()
+                        if k.startswith(STAT_PREFIX)}
+            # every copy is asked for now, while the step still runs, so
+            # that the runtime moves each result out as the program ends
+            # and not one after another as the reads below ask (a
+            # megabyte of logits and three counters read one by one
+            # ended 4.3-5.4 ms after the step: PERF.md, PR 34)
+            for d in (*device, *stat_out.values()):
+                if hasattr(d, "copy_to_host_async"):
+                    d.copy_to_host_async()
             # the blocked read alone: it ends when the device does
             with phase("tpu_model.readback",
                        hist=self._hists["readback_ms"],
                        rows=true_len) as read:
                 host = [np.asarray(d) for d in device]
-                stats = {k[len(STAT_PREFIX):]: np.asarray(v)[:true_len]
-                         for k, v in outputs.items()
-                         if k.startswith(STAT_PREFIX)}
+                stats = {k: np.asarray(v)[:true_len]
+                         for k, v in stat_out.items()}
             for out_col, val in zip(fetches, host):
                 out_cols[out_col].append(val[:true_len])
             for name, per_row in stats.items():
@@ -841,11 +850,16 @@ class _FlaxApply:
         if self.method is not None:
             return self.module.apply(variables, *args, method=self.method)
         names = getattr(self.module, "row_stats", ())
-        if not names:
+        arrays = getattr(self.module, "row_outputs", ())
+        if not names and not arrays:
             return self.module.apply(variables, *args)
         # a module that sows per-row numbers into "stats" hands them
-        # out beside its output, one (rows,) array a name
+        # out beside its output, one (rows,) array a name; its
+        # ``row_outputs`` are sown there too and go out under their own
+        # names, as outputs that ``fetchDict`` can name
         variables = {k: v for k, v in variables.items() if k != "stats"}
         out, sown = self.module.apply(variables, *args, mutable=["stats"])
         return {"output": out,
-                **{STAT_PREFIX + n: sown["stats"][n][-1] for n in names}}
+                **{STAT_PREFIX + n: sown["stats"][n][-1] for n in names},
+                **{n: sown["stats"][n][-1] for n in arrays
+                   if n in sown["stats"]}}

@@ -12,6 +12,14 @@ k/v (B, Lk, H, D), optional causal masking with global position offsets
 (shards of a longer sequence). Rows whose keys are all masked return 0,
 matching the ring layer's _finalize.
 
+Grouped-query attention: k/v may hold fewer heads, (B, Lk, H_kv, D)
+with H a multiple of H_kv; key/value head h // (H / H_kv) serves query
+head h. The grid still runs over the B*H query heads and the K/V block
+specs read the shared head by its index, so no repeated copy of K and V
+exists in HBM. In the backward each query head's dK/dV part is written
+on its own (float32) and the parts of a group are added up outside the
+kernel. H_kv == H is the call as it always was.
+
 What is fetched and what is computed are two sizes. The grid is
 (B*H, Lq blocks, Lk blocks) with the KV axis innermost, and a grid step
 FETCHES one block of up to 1024 rows of each operand: one DMA an
@@ -454,6 +462,29 @@ def _lse_pad(lq: int, d: int) -> int:
     return lq + pad_q
 
 
+def _kv_group(q, k, v) -> int:
+    """Query heads a key/value head serves: H / H_kv, 1 for plain
+    multi-head attention."""
+    h, hk = q.shape[2], k.shape[2]
+    if v.shape[2] != hk or h % hk:
+        raise ValueError(
+            f"flash_attention needs k and v with the same number of "
+            f"heads, a divisor of q's: q has {h}, k {hk}, v {v.shape[2]}")
+    return h // hk
+
+
+def _kv_block(bk: int, d: int, group: int, key_axis: int):
+    """BlockSpec of a K or V fetch block: the grid's first index is the
+    query head's b*H + h, whose key/value head is b*H_kv + h // group =
+    (b*H + h) // group (the index itself, with no arithmetic, where
+    group is 1). ``key_axis`` says which of the grid's other two indices
+    counts key blocks."""
+    def index(bh, i, j):
+        return (bh if group == 1 else bh // group,
+                i if key_axis == 1 else j, 0)
+    return pl.BlockSpec((1, bk, d), index)
+
+
 def _heads_major(x, pad, lpad_idx=1):
     """(B, L, H, D) -> (B*H, L(+pad), D)."""
     b, l, h, d = x.shape
@@ -501,6 +532,7 @@ def _flash_forward(q, k, v, causal: bool = False, q_offset: int = 0,
                    k_offset: int = 0, interpret: bool = False):
     b, lq, h, d = q.shape
     lk = k.shape[1]
+    group = _kv_group(q, k, v)
     scale = 1.0 / float(d) ** 0.5
     bq, bk, pad_q, pad_k = _blocks(lq, lk, d)
     plan = tile_plan(lq, lk, d, causal, q_offset, k_offset)
@@ -516,8 +548,8 @@ def _flash_forward(q, k, v, causal: bool = False, q_offset: int = 0,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
+            _kv_block(bk, d, group, 2),
+            _kv_block(bk, d, group, 2),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -548,6 +580,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
                     interpret):
     b, lq, h, d = q.shape
     lk = k.shape[1]
+    group = _kv_group(q, k, v)
     scale = 1.0 / float(d) ** 0.5
     bq, bk, pad_q, pad_k = _blocks(lq, lk, d)
 
@@ -570,8 +603,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
         grid=(b * h, nq, nk_blocks),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
+            _kv_block(bk, d, group, 2),
+            _kv_block(bk, d, group, 2),
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
@@ -586,8 +619,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
         functools.partial(_dkv_kernel, **kw),
         grid=(b * h, nk_blocks, nq),
         in_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+            _kv_block(bk, d, group, 1),
+            _kv_block(bk, d, group, 1),
             pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
@@ -597,9 +630,14 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
             pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
         ],
+        # a group's query heads each write their part of dK and dV
+        # (float32, added up below); without groups a head's part is
+        # the whole
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, lk + pad_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, lk + pad_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, lk + pad_k, d),
+                                 k.dtype if group == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((b * h, lk + pad_k, d),
+                                 v.dtype if group == 1 else jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
@@ -609,4 +647,11 @@ def _flash_backward(q, k, v, out, lse, g, causal, q_offset, k_offset,
     def _back(x, l):
         return x[:, :l].reshape(b, h, l, d).transpose(0, 2, 1, 3)
 
-    return _back(dq, lq), _back(dk, lk), _back(dv, lk)
+    if group == 1:
+        return _back(dq, lq), _back(dk, lk), _back(dv, lk)
+
+    def _back_kv(x, like):
+        parts = x[:, :lk].reshape(b, h // group, group, lk, d)
+        return parts.sum(axis=2).transpose(0, 2, 1, 3).astype(like.dtype)
+
+    return _back(dq, lq), _back_kv(dk, k), _back_kv(dv, v)

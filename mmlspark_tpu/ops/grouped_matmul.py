@@ -21,8 +21,23 @@ import jax.numpy as jnp
 
 # m, k and n tile of the kernel: 512 rows amortise an expert's weight
 # tile over the rows routed to it, 1024 x 1024 weight tiles stay under
-# the default VMEM grant with double buffering
+# the default VMEM grant with double buffering. The k and n tiles follow
+# the shape (``_tile``): the kernel wants a tile that divides the side
+# (a ragged last k tile is masked element by element in every grid
+# step), and 1536 is no multiple of 1024
 TILING = (512, 1024, 1024)
+
+
+def _tile(side: int, want: int) -> int:
+    """The widest multiple of 128 up to ``want`` that divides ``side``
+    (1024 for 2048 and 6144, 768 for 1536); ``side`` itself where it is
+    no wider than ``want``, and ``want`` where nothing divides."""
+    if side <= want:
+        return side
+    for t in range(want - want % 128, 0, -128):
+        if side % t == 0:
+            return t
+    return want
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype",))
@@ -30,7 +45,7 @@ def _moe_grouped_matmul(lhs, rhs, group_sizes, out_dtype):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     m, k = lhs.shape
     n = rhs.shape[2]
-    tiling = (min(TILING[0], m), min(TILING[1], k), min(TILING[2], n))
+    tiling = (min(TILING[0], m), _tile(k, TILING[1]), _tile(n, TILING[2]))
     return gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype,
                tiling=tiling)
 

@@ -73,7 +73,12 @@ FLASH_MIN_LEN = 512
 def dense_attention(q, k, v, causal: bool = False,
                     q_offset=0, k_offset=0) -> jnp.ndarray:
     """The dense einsum path — the numerics reference the flash kernel
-    (forward) and its custom_vjp backward are both held to."""
+    (forward) and its custom_vjp backward are both held to. k/v with
+    fewer heads than q (grouped-query attention) are repeated here: key/
+    value head h // (H / H_kv) serves query head h."""
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     s = _block_scores(q.astype(jnp.float32), k.astype(jnp.float32), scale)
     if causal:
@@ -95,8 +100,10 @@ def attention(q, k, v, causal: bool = False,
               q_offset: int = 0, k_offset: int = 0) -> jnp.ndarray:
     """Plain (single-device) attention.
 
-    q (B, Lq, H, D); k/v (B, Lk, H, D). Offsets give global positions for
-    causal masking of sequence shards. Long sequences on TPU run the
+    q (B, Lq, H, D); k/v (B, Lk, H, D), or (B, Lk, H_kv, D) with H a
+    multiple of H_kv (grouped-query attention: the flash kernel reads
+    the shared head, the einsum path repeats it). Offsets give global
+    positions for causal masking of sequence shards. Long sequences on TPU run the
     Pallas flash kernel (O(L) memory, scores never leave VMEM — see
     ops/flash_attention.py); short ones use the fused XLA einsum."""
     if (jax.default_backend() == "tpu"

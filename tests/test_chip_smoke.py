@@ -24,6 +24,9 @@ TINY = {
     "gbdt_hist_method": "pallas",     # interpreted off the chip
     "gbdt_min_auc": 0.6,
     "pipeline_rows": 200,
+    "gqa": {"batch": 1, "length": 48, "heads": 4, "kv_heads": 2,
+            "head_dim": 16},
+    "grouped": {"rows": 64, "groups": 4, "k": 16, "n": 24},
 }
 
 
@@ -37,6 +40,15 @@ def test_train_then_transform():
 def test_serve():
     facts = chip_smoke.leg_serve(TINY)
     assert facts["requests"] == facts["predictions_equal_reference"] == 2
+
+
+def test_kernels_leg():
+    facts = chip_smoke.leg_kernels(TINY)
+    assert facts["gqa_rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
+    assert facts["grouped_rel_l2"] < chip_smoke.BF16_REL_TOL
+    assert facts["grouped_tiles_k_n"] == [16, 24]
+    full = chip_smoke.FULL["grouped"]
+    assert (full["k"], full["n"]) == (2048, 1536)
 
 
 def test_gbdt_and_fused_pipeline():
