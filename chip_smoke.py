@@ -3,8 +3,9 @@
 One process drives the normal path once, through the entry points a user
 calls, at the width of a dim-2048 transformer LM: train a few steps,
 score with the model ``fit`` returned, serve a classifier of the same
-trunk over HTTP, compile the grouped-query flash call and a 1536-wide
-grouped product, boost a HIGGS-shaped forest at 63 and 255 bins, and
+trunk over HTTP, compile the grouped-query flash call, a 1536-wide
+grouped product and the routed experts' gather combine, boost a
+HIGGS-shaped forest at 63 and 255 bins, and
 run a fused featurize -> booster pipeline. Weights are random from a
 seed, data is synthetic, nothing touches the network. Every leg is
 fatal: a failure propagates, the exit code is non-zero and no result
@@ -59,6 +60,9 @@ FULL = {
     "gqa": {"batch": 1, "length": 2048, "heads": 32, "kv_heads": 8,
             "head_dim": 64},
     "grouped": {"rows": 4096, "groups": 8, "k": 2048, "n": 1536},
+    # the routed experts' combine where every expert is held, at the
+    # LFM2 cell's shape: 32,768 tokens x 4 rows of 2048 float32 a layer
+    "combine": {"tokens": 32768, "k": 4, "dim": 2048, "passes": 4},
 }
 
 # a bf16 forward against the float32 reference, as relative L2 error of
@@ -290,11 +294,12 @@ def leg_serve(cfg: dict) -> dict:
 
 
 def leg_kernels(cfg: dict) -> dict:
-    """The two kernels that ``hybrid_moe_lm`` asks of the chip beyond
-    what the legs above compile: the flash forward with fewer key/value
-    heads than query heads (no repeated copy of K and V) against the
-    einsum on repeated K and V, and the grouped product at a width the
-    1024 tile does not divide against a loop over the groups."""
+    """What ``hybrid_moe_lm`` asks of the chip beyond what the legs
+    above compile: the flash forward with fewer key/value heads than
+    query heads (no repeated copy of K and V) against the einsum on
+    repeated K and V, the grouped product at a width the 1024 tile does
+    not divide against a loop over the groups, and the routed experts'
+    gather combine against the scatter-add form."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
@@ -334,7 +339,37 @@ def leg_kernels(cfg: dict) -> dict:
     assert gm_err < BF16_REL_TOL, f"grouped product: {gm_err}"
     assert not np.asarray(out[lo:]).any(), "rows of no group are not zero"
     return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
-            "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)]}
+            "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
+            "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"])}
+
+
+def _combine_gap(c: dict) -> float:
+    """The gather combine of ``routed_experts`` (every expert held)
+    against the scatter-add form on the same sorted rows: the two differ
+    by the order of a k-term float32 sum."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from mmlspark_tpu.models.expert_layer import _gather_combine
+    t, k, dim = c["tokens"], c["k"], c["dim"]
+    rows = t * k // c["passes"]
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    out_all = jax.random.normal(keys[0], (t * k, dim), jnp.float32)
+    order = jax.random.permutation(keys[1], t * k)
+    gates = jax.random.uniform(keys[2], (t, k), jnp.float32)
+
+    def scatter_add(out_all, order, gates):
+        def one_pass(i, y):
+            at = lax.dynamic_slice_in_dim(order, i * rows, rows)
+            out = lax.dynamic_slice_in_dim(out_all, i * rows, rows)
+            return y.at[at // k].add(out * gates.reshape(-1)[at][:, None])
+        return lax.fori_loop(0, c["passes"], one_pass,
+                             jnp.zeros((t, dim), jnp.float32))
+    got = jax.jit(_gather_combine)(out_all, order, gates)
+    want = jax.jit(scatter_add)(out_all, order, gates)
+    gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    assert gap < 1e-6, f"gather combine against the scatter-add: {gap}"
+    return gap
 
 
 def _auc(y: np.ndarray, score: np.ndarray) -> float:

@@ -232,6 +232,12 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                 r.gauge("serving_model_weights_cast_bytes",
                         "weight bytes a model call no longer reads "
                         "for it", m["weights_cast_bytes"], labels)
+            if "moe_gather_combines" in m:
+                r.gauge("serving_model_moe_gather_combines",
+                        "expert layers whose outputs return to their "
+                        "tokens by a gather and a sum of k: every "
+                        "expert is on this chip",
+                        m["moe_gather_combines"], labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

@@ -8,7 +8,13 @@ computes the part of the result that the ``experts_held`` experts of
 rank ``expert_rank`` give, plus ``n_shared_experts`` shared experts. A
 chip that holds every expert is ``experts_held == experts_total``,
 rank 0. No token is dropped: the (token, expert) pairs routed here go
-through the grouped products in passes, as many as it takes.
+through the grouped products in passes. Where a share of the experts is
+held, the passes are as many as the pairs routed here take, and each
+adds its rows into their tokens (a scatter-add). Where every expert is
+held, every pair is routed here: the passes are ``pairs / rows`` whatever
+the router chose, the sorted order is a permutation of the pairs, and
+the outputs return to their tokens once a layer by its inverse: a
+gather and a sum of k (``combines_by_gather``).
 
 The layer reads its sizes from a ``cfg`` with these attributes:
 ``hidden_size``, ``moe_intermediate_size``, ``experts_total``,
@@ -37,10 +43,31 @@ _F32 = jnp.float32
 # so no token is dropped
 PASS_SHARE = 1.25
 # ... and no more rows than this, whatever the share: a pass holds its
-# rows' gathered input and three float32 products (gate, up and down's
-# output), which at a chip that holds every expert would be every pair
-# of the step at once (131,072 rows x 2048: 3.9 GB at LFM2's widths)
+# rows' gathered input and the float32 gate and up products, which at a
+# chip that holds every expert would be every pair of the step at once
+# (131,072 rows at LFM2's widths: 2.1 GB). The down product's float32
+# rows are a pass's too where a share is held; where every expert is,
+# they are kept for the whole layer (131,072 x 2048: 1.07 GB) and
+# combined after the last pass
 PASS_ROWS_MAX = 32768
+
+
+def combines_by_gather(held: int, total: int) -> bool:
+    """Whether ``routed_experts`` returns the experts' outputs to their
+    tokens by a gather: where every expert is held, because only then
+    are the rows routed here a permutation of the (token, slot) pairs.
+    A share of the experts sees a few of the pairs (``held / total`` of
+    them), and a gather over every pair would read far more rows than
+    its scatter-add writes."""
+    return held == total
+
+
+def gather_combines(cfg, expert_layers: int) -> int:
+    """How many of a module's ``expert_layers`` combine by the gather:
+    what the families expose as ``moe_gather_combines`` and
+    ``TPUModel.metrics()`` carries."""
+    every = combines_by_gather(cfg.experts_held, cfg.experts_total)
+    return expert_layers if every else 0
 
 
 def _fan_in(fan_in: int):
@@ -144,11 +171,19 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
     """The part of sum_i g_i E_i(u) that the experts [first, first +
     held) give. u (t, dim); chosen, gates (t, k). The (token, expert)
     pairs routed here are sorted by expert and go through the grouped
-    products in passes of ``_pass_rows`` rows, as many as it takes (one
-    for a share of many experts unless the experts held are popular;
-    pairs / PASS_ROWS_MAX where every expert is here)."""
+    products in passes of ``_pass_rows`` rows.
+
+    A share of the experts (``held < total``): as many passes as the
+    pairs routed here take (one unless the experts held are popular),
+    each scaling its rows by their gates and adding them into their
+    tokens. Every expert (``combines_by_gather``): ``pairs / rows``
+    passes, each writing its down product's float32 rows into its slice
+    of one (pairs, dim) buffer; after the last, token by token, the k
+    rows of the token are gathered through the inverse of the sorted
+    order, scaled and summed in float32, slot 0 first."""
     t, k = chosen.shape
     held = w_gate.shape[0]
+    every = combines_by_gather(held, total)
     local = (chosen - first).reshape(-1)
     here = (local >= 0) & (local < held)
     key = jnp.where(here, local, held)
@@ -158,30 +193,54 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
     ends = jnp.cumsum(load)
     n_here = ends[-1]
     rows = _pass_rows(t * k, held, total)
-    pad = -(-t * k // rows) * rows - t * k
+    passes = -(-t * k // rows)
+    pad = passes * rows - t * k
     token_of = jnp.pad(order // k, (0, pad))
-    gate_of = jnp.pad(gates.reshape(-1)[order], (0, pad))
+    if not every:
+        gate_of = jnp.pad(gates.reshape(-1)[order], (0, pad))
 
-    def one_pass(i, y):
+    def one_pass(i, acc):
         lo = i * rows
         tok = lax.dynamic_slice_in_dim(token_of, lo, rows)
-        gate = lax.dynamic_slice_in_dim(gate_of, lo, rows)
+        if not every:
+            gate = lax.dynamic_slice_in_dim(gate_of, lo, rows)
         sizes = jnp.clip(ends - lo, 0, rows) \
             - jnp.clip(ends - load - lo, 0, rows)
         x = u[tok]
         # the grouped products apart from the sort, gather, scaling and
-        # scatter-add around them (``moe_dispatch_share`` reads the rest)
+        # combine around them (``moe_dispatch_share`` reads the rest)
         with jax.named_scope("moe_grouped"):
             h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32)) \
                 * grouped_matmul(x, w_up, sizes, _F32)
             out = grouped_matmul(h.astype(u.dtype), w_down, sizes, _F32)
+        if every:
+            return lax.dynamic_update_slice_in_dim(acc, out, lo, 0)
         live = (lo + jnp.arange(rows)) < n_here
         out = jnp.where(live[:, None], out * gate[:, None], 0.0)
-        return y.at[tok].add(out)
+        return acc.at[tok].add(out)
 
-    y = lax.fori_loop(0, (n_here + rows - 1) // rows, one_pass,
-                      jnp.zeros((t, u.shape[1]), _F32))
-    return y, load
+    # (every pair is here where every expert is: ``passes`` trips)
+    acc = lax.fori_loop(0, (n_here + rows - 1) // rows, one_pass, jnp.zeros(
+        (passes * rows if every else t, u.shape[1]), _F32))
+    if not every:
+        return acc, load
+    with jax.named_scope("moe_combine"):
+        return _gather_combine(acc, order, gates), load
+
+
+def _gather_combine(out_all, order, gates):
+    """y[token] = sum_j gates[token, j] * out_all[inv[token * k + j]],
+    slot 0 first, in float32. ``order`` is a permutation of the t * k
+    pairs (row p of ``out_all`` is pair ``order[p]``'s) and ``inv`` its
+    inverse; rows of ``out_all`` past the pairs (a last pass not full)
+    are never read. A slot at a time, so that the (t, k, dim) gathered
+    rows never exist at once."""
+    t, k = gates.shape
+    inv = jnp.argsort(order).reshape(t, k)
+    y = gates[:, 0, None] * out_all[inv[:, 0]]
+    for j in range(1, k):
+        y = y + gates[:, j, None] * out_all[inv[:, j]]
+    return y
 
 
 def _pass_rows(pairs: int, held: int, total: int) -> int:

@@ -37,7 +37,7 @@ import flax.linen as nn
 
 from mmlspark_tpu.models.expert_layer import (
     ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones, _pass_rows,
-    _row_loads, rms_norm)
+    _row_loads, gather_combines, rms_norm)
 
 Dtype = Any
 OPERATORS = ("conv", "full_attention")
@@ -199,6 +199,15 @@ class HybridMoELM(nn.Module):
     row_outputs = ("routed_tail", "attention_tail")
 
     cfg: HybridMoEConfig = HybridMoEConfig()
+
+    @property
+    def moe_gather_combines(self) -> int:
+        """Expert layers whose outputs return to their tokens by a
+        gather (``TPUModel.metrics()`` carries it): all of them, every
+        expert being here."""
+        c = self.cfg
+        return gather_combines(
+            c, max(0, len(c.layer_types) - c.num_dense_layers))
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,

@@ -48,7 +48,8 @@ from jax import lax
 # importable from here
 from mmlspark_tpu.models.expert_layer import (  # noqa: F401
     PASS_SHARE, ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones,
-    _pass_rows, _row_loads, rms_norm, route, routed_experts, swiglu)
+    _pass_rows, _row_loads, gather_combines, rms_norm, route,
+    routed_experts, swiglu)
 from mmlspark_tpu.ops.sparse_select import select_keys
 
 Dtype = Any
@@ -241,6 +242,15 @@ class LatentMoELM(nn.Module):
                  "dsa_keys_per_query")
 
     cfg: LatentMoEConfig = LatentMoEConfig()
+
+    @property
+    def moe_gather_combines(self) -> int:
+        """Expert layers whose outputs return to their tokens by a
+        gather (``TPUModel.metrics()`` carries it): none where a share
+        of the experts is held, all of them where every one is."""
+        c = self.cfg
+        return gather_combines(
+            c, sum(kind == "sparse" for kind in c.mlp_layer_types))
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,

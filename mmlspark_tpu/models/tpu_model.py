@@ -551,6 +551,12 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # leaves placed narrower than held (the dtype modelFn reads
         # them in), and the bytes a call no longer reads for it
         out["weights_cast_leaves"], out["weights_cast_bytes"] = self._cast
+        # expert layers of a wrapped module that return the experts'
+        # outputs to their tokens by a gather (expert_layer.py): every
+        # expert is on this chip; 0 for a module that has none
+        out["moe_gather_combines"] = int(getattr(
+            getattr(self.get("modelFn"), "module", None),
+            "moe_gather_combines", 0))
         out["precision"] = self.get("precision")
         out["aot"] = bool(self.aot)
         if self._sharding is not None:

@@ -27,6 +27,7 @@ TINY = {
     "gqa": {"batch": 1, "length": 48, "heads": 4, "kv_heads": 2,
             "head_dim": 16},
     "grouped": {"rows": 64, "groups": 4, "k": 16, "n": 24},
+    "combine": {"tokens": 48, "k": 4, "dim": 16, "passes": 4},
 }
 
 
@@ -49,6 +50,9 @@ def test_kernels_leg():
     assert facts["grouped_tiles_k_n"] == [16, 24]
     full = chip_smoke.FULL["grouped"]
     assert (full["k"], full["n"]) == (2048, 1536)
+    assert facts["combine_rel_l2_vs_scatter_add"] < 1e-6
+    full = chip_smoke.FULL["combine"]
+    assert (full["tokens"] * full["k"], full["dim"]) == (131072, 2048)
 
 
 def test_gbdt_and_fused_pipeline():

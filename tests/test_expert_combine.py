@@ -1,0 +1,240 @@
+"""How the routed experts' outputs return to their tokens
+(``expert_layer.routed_experts``). Where every expert is held the pairs
+are a permutation and the combine is a gather and a sum of k: against a
+plain per-token loop in float32 numpy, in several sizes, with one pass
+and with several, with a last pass that is not full, with a padded row
+and with every pair on one expert; no scatter-add in its jaxpr. Where a
+share is held the scatter-add stays, bit for bit the form it had before
+(kept here as the plain form). The number of expert layers that combine
+by a gather, through ``TPUModel.metrics()``."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from mmlspark_tpu.models import expert_layer as el
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import hybrid_moe_tiny  # noqa: E402
+import latent_moe_tiny  # noqa: E402
+
+# (tokens, k, experts, dim, width, PASS_ROWS_MAX or None, passes)
+SIZES = {
+    "one_pass": (64, 4, 8, 16, 8, None, 1),
+    "four_passes": (512, 4, 8, 16, 8, 512, 4),
+    "last_pass_not_full": (300, 4, 16, 32, 8, 512, 3),
+    "two_a_token": (520, 2, 4, 8, 16, 512, 3),
+}
+ROUTINGS = ("random", "padded_row", "one_expert")
+
+
+def _inputs(name, routing, seed=0):
+    t, k, experts, dim, width, _, _ = SIZES[name]
+    rng = np.random.default_rng(seed)
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    u, gates = f32(t, dim), rng.random((t, k)).astype(np.float32)
+    if routing == "random":
+        # k distinct experts a token, as top-k gives
+        chosen = np.stack([rng.permutation(experts)[:k] for _ in range(t)])
+    elif routing == "padded_row":
+        # one token repeated: every pair to the same k experts
+        u = np.repeat(u[:1], t, axis=0)
+        gates = np.repeat(gates[:1], t, axis=0)
+        chosen = np.repeat(rng.permutation(experts)[None, :k], t, axis=0)
+    else:
+        chosen = np.full((t, k), experts - 1)
+    return (u, chosen.astype(np.int32), gates, f32(experts, dim, width),
+            f32(experts, dim, width), f32(experts, width, dim))
+
+
+def _per_token_loop(u, chosen, gates, w_gate, w_up, w_down):
+    """sum_j g_j E_j(u) a token, slot by slot, in float32 numpy."""
+    y = np.zeros_like(u)
+    for t in range(len(u)):
+        for j in range(chosen.shape[1]):
+            e = chosen[t, j]
+            a = u[t] @ w_gate[e]
+            h = (a / (1.0 + np.exp(-a))) * (u[t] @ w_up[e])
+            y[t] += gates[t, j] * (h @ w_down[e])
+    return y
+
+
+def _cap(monkeypatch, name):
+    t, k, experts, _, _, cap, passes = SIZES[name]
+    if cap is not None:
+        monkeypatch.setattr(el, "PASS_ROWS_MAX", cap)
+    assert -(-t * k // el._pass_rows(t * k, experts, experts)) == passes
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("name", SIZES)
+def test_every_expert_held_equals_a_per_token_loop(monkeypatch, name,
+                                                   routing):
+    _cap(monkeypatch, name)
+    args = _inputs(name, routing)
+    experts = args[3].shape[0]
+    y, load = jax.jit(lambda *a: el.routed_experts(*a, 0, experts))(*args)
+    assert y.dtype == jnp.float32
+    want = _per_token_loop(*args)
+    assert np.linalg.norm(np.asarray(y) - want) \
+        < 2e-6 * np.linalg.norm(want)
+    assert load.tolist() == np.bincount(args[1].reshape(-1),
+                                        minlength=experts).tolist()
+
+
+def _primitives(jaxpr, found=None):
+    """Every primitive's name in a jaxpr, the nested ones too."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("name", SIZES)
+def test_every_expert_held_has_no_scatter_add(monkeypatch, name):
+    _cap(monkeypatch, name)
+    args = _inputs(name, "random")
+    experts = args[3].shape[0]
+    prims = _primitives(jax.make_jaxpr(
+        lambda *a: el.routed_experts(*a, 0, experts))(*args).jaxpr)
+    assert "scatter-add" not in prims and "scatter_add" not in prims
+    assert "gather" in prims
+
+
+def _scatter_add_form(u, chosen, gates, w_gate, w_up, w_down, first, total):
+    """``routed_experts`` as it was for every case before the gather
+    combine, and is for a share of the experts: the plain form."""
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+    f32 = jnp.float32
+    t, k = chosen.shape
+    held = w_gate.shape[0]
+    local = (chosen - first).reshape(-1)
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)
+    order = jnp.argsort(key, stable=True)
+    load = jnp.sum((key[:, None] == jnp.arange(held)[None, :]
+                    ).astype(jnp.int32), axis=0)
+    ends = jnp.cumsum(load)
+    n_here = ends[-1]
+    rows = el._pass_rows(t * k, held, total)
+    pad = -(-t * k // rows) * rows - t * k
+    token_of = jnp.pad(order // k, (0, pad))
+    gate_of = jnp.pad(gates.reshape(-1)[order], (0, pad))
+
+    def one_pass(i, y):
+        lo = i * rows
+        tok = lax.dynamic_slice_in_dim(token_of, lo, rows)
+        gate = lax.dynamic_slice_in_dim(gate_of, lo, rows)
+        sizes = jnp.clip(ends - lo, 0, rows) \
+            - jnp.clip(ends - load - lo, 0, rows)
+        x = u[tok]
+        h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, f32)) \
+            * grouped_matmul(x, w_up, sizes, f32)
+        out = grouped_matmul(h.astype(u.dtype), w_down, sizes, f32)
+        live = (lo + jnp.arange(rows)) < n_here
+        out = jnp.where(live[:, None], out * gate[:, None], 0.0)
+        return y.at[tok].add(out)
+
+    y = lax.fori_loop(0, (n_here + rows - 1) // rows, one_pass,
+                      jnp.zeros((t, u.shape[1]), f32))
+    return y, load
+
+
+# (held, total, first, routing): a share that sees a few pairs, a share
+# that every pair falls to (four passes of 80), the last share
+SHARES = [(4, 16, 4, "random"), (4, 16, 8, "all_here"),
+          (2, 8, 6, "random"), (8, 16, 0, "random")]
+
+
+@pytest.mark.parametrize("held,total,first,routing", SHARES)
+def test_a_share_of_the_experts_keeps_its_scatter_add(held, total, first,
+                                                      routing):
+    t, k, dim, width = 64, 4, 16, 8
+    rng = np.random.default_rng(held + first)
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    if routing == "all_here":
+        chosen = np.tile(first + np.arange(k)[None], (t, 1))
+    else:
+        chosen = np.stack([rng.permutation(total)[:k] for _ in range(t)])
+    args = (f32(t, dim), chosen.astype(np.int32),
+            rng.random((t, k)).astype(np.float32), f32(held, dim, width),
+            f32(held, dim, width), f32(held, width, dim))
+    assert not el.combines_by_gather(held, total)
+    new = lambda *a: el.routed_experts(*a, first, total)  # noqa: E731
+    old = lambda *a: _scatter_add_form(*a, first, total)  # noqa: E731
+    y, load = jax.jit(new)(*args)
+    want, want_load = jax.jit(old)(*args)
+    assert np.array_equal(np.asarray(y), np.asarray(want))    # bit for bit
+    assert load.tolist() == want_load.tolist()
+    prims = _primitives(jax.make_jaxpr(new)(*args).jaxpr)
+    assert prims.count("scatter-add") == 1
+    assert prims == _primitives(jax.make_jaxpr(old)(*args).jaxpr)
+
+
+def test_the_rule_between_the_two_is_a_fact_of_the_shapes():
+    assert el.combines_by_gather(64, 64)
+    assert not el.combines_by_gather(16, 256)
+    assert not el.combines_by_gather(63, 64)
+
+
+# ---------------------------------------------------- the engagement number
+
+def _served(spec):
+    from mmlspark_tpu.models.networks import build_network
+    from mmlspark_tpu.models.tpu_model import TPUModel
+    module = build_network({"dtype": "float32", **spec})
+    params = jax.jit(module.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return TPUModel.from_flax(module, {"params": params},
+                              inputCol="features", outputCol="scores",
+                              batchSize=4)
+
+
+NINE_LAYERS = {**hybrid_moe_tiny.TINY, "layer_types": [
+    "conv", "full_attention", "conv", "conv", "conv",
+    "full_attention", "conv", "conv", "conv"]}
+EVERY_EXPERT = {**latent_moe_tiny.TINY, "experts_held": 16, "expert_rank": 0}
+
+
+@pytest.mark.parametrize("spec,layers", [
+    (NINE_LAYERS, 8), (hybrid_moe_tiny.TINY, 4), (latent_moe_tiny.TINY, 0),
+    (EVERY_EXPERT, 4)], ids=["hybrid_9", "hybrid_5", "latent_share",
+                             "latent_every_expert"])
+def test_metrics_carry_the_layers_that_combine_by_gather(spec, layers):
+    from mmlspark_tpu.core.table import DataTable
+    model = _served(spec)
+    assert model.metrics()["moe_gather_combines"] == layers
+    rows = hybrid_moe_tiny.ROWS.astype(np.float32)
+    model.transform(DataTable({"features": rows}))
+    assert model.metrics()["moe_gather_combines"] == layers
+    # ... and the step the model compiled holds as many scatter-adds as
+    # expert layers that do not
+    module = model.get("modelFn").module
+    prims = _primitives(jax.make_jaxpr(lambda p, t: module.apply(
+        {"params": p}, t))(model.get("weights")["params"],
+                           jnp.asarray(hybrid_moe_tiny.ROWS)).jaxpr)
+    expert_layers = 8 if spec is NINE_LAYERS else 4
+    assert prims.count("scatter-add") == expert_layers - layers
+
+
+def test_a_model_with_no_expert_layer_reads_zero_and_exports_it():
+    import flax.linen as nn
+    from mmlspark_tpu.core.prometheus import PromRenderer, pipeline_families
+    from mmlspark_tpu.models.tpu_model import TPUModel
+    dense = nn.Dense(3)
+    params = dense.init(jax.random.PRNGKey(0), jnp.zeros((1, 4)))
+    model = TPUModel.from_flax(dense, params, inputCol="features",
+                               outputCol="scores")
+    assert model.metrics()["moe_gather_combines"] == 0
+    assert TPUModel.from_fn(lambda w, x: x["features"], {}).metrics()[
+        "moe_gather_combines"] == 0
+    r = PromRenderer()
+    pipeline_families(r, _served(hybrid_moe_tiny.TINY), {})
+    assert "serving_model_moe_gather_combines 4" in r.render()
