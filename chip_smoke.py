@@ -3,8 +3,9 @@
 One process drives the normal path once, through the entry points a user
 calls, at the width of a dim-2048 transformer LM: train a few steps,
 score with the model ``fit`` returned, serve a classifier of the same
-trunk over HTTP, compile the grouped-query flash call, a 1536-wide
-grouped product and the routed experts' gather combine, boost a
+trunk over HTTP, compile the grouped-query flash call (causal, and under a
+sliding window at 16 fetch blocks a side), the 1536-wide and the 896-wide
+grouped products and the routed experts' gather combine, boost a
 HIGGS-shaped forest at 63 and 255 bins, and
 run a fused featurize -> booster pipeline. Weights are random from a
 seed, data is synthetic, nothing touches the network. Every leg is
@@ -60,6 +61,13 @@ FULL = {
     "gqa": {"batch": 1, "length": 2048, "heads": 32, "kv_heads": 8,
             "head_dim": 64},
     "grouped": {"rows": 4096, "groups": 8, "k": 2048, "n": 1536},
+    # a sliding-window grouped-query flash call at 16 fetch blocks a
+    # side and the two products of an expert 896 wide under a hidden
+    # size of 2304 (Mellum2-12B-A2.5B's shapes: tiles of 768 and 896)
+    "swa": {"batch": 1, "length": 16384, "heads": 32, "kv_heads": 4,
+            "head_dim": 128, "window": 1024},
+    "grouped_narrow": [{"rows": 4096, "groups": 8, "k": 2304, "n": 896},
+                       {"rows": 4096, "groups": 8, "k": 896, "n": 2304}],
     # the routed experts' combine where every expert is held, at the
     # LFM2 cell's shape: 32,768 tokens x 4 rows of 2048 float32 a layer
     "combine": {"tokens": 32768, "k": 4, "dim": 2048, "passes": 4},
@@ -297,9 +305,11 @@ def leg_kernels(cfg: dict) -> dict:
     """What ``hybrid_moe_lm`` asks of the chip beyond what the legs
     above compile: the flash forward with fewer key/value heads than
     query heads (no repeated copy of K and V) against the einsum on
-    repeated K and V, the grouped product at a width the 1024 tile does
-    not divide against a loop over the groups, and the routed experts'
-    gather combine against the scatter-add form."""
+    repeated K and V, the same under a sliding window (the band of key
+    blocks alone) against the masked einsum, the grouped product at
+    widths the 1024 tile does not divide (1536; 2304 and 896) against a
+    loop over the groups, and the routed experts' gather combine
+    against the scatter-add form."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
@@ -318,11 +328,67 @@ def leg_kernels(cfg: dict) -> dict:
     gqa_err = float(jnp.linalg.norm(got.astype(jnp.float32) - want)
                     / jnp.linalg.norm(want))
     assert gqa_err < BF16_REL_TOL, f"grouped-query flash: {gqa_err}"
+    swa_err = _windowed_gap(cfg["swa"])
+    assert swa_err < BF16_REL_TOL, f"windowed grouped-query flash: {swa_err}"
+    gm_err = _grouped_gap(cfg["grouped"], keys[3:5])
+    narrow = [_grouped_gap(m, keys[3:5]) for m in cfg["grouped_narrow"]]
     m = cfg["grouped"]
-    lhs = jax.random.normal(keys[3], (m["rows"], m["k"]), jnp.bfloat16)
-    rhs = jax.random.normal(keys[4], (m["groups"], m["k"], m["n"]),
+    return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
+            "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
+            "swa_rel_l2_vs_f32": swa_err,
+            "grouped_narrow_rel_l2": narrow,
+            "grouped_narrow_tiles_k_n": [
+                [_tile(m["k"], 1024), _tile(m["n"], 1024)]
+                for m in cfg["grouped_narrow"]],
+            "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"])}
+
+
+def _windowed_gap(g: dict) -> float:
+    """The flash forward under a sliding window (the band of key blocks
+    alone is visited) against the masked einsum, a block of queries at
+    a time over the keys that block can see."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.flash_attention import tile_plan
+    from mmlspark_tpu.parallel.ring_attention import (
+        attention, dense_attention)
+    length, window = g["length"], g["window"]
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], (g["batch"], length, g["heads"],
+                                    g["head_dim"]), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (g["batch"], length, g["kv_heads"],
+                                   g["head_dim"]), jnp.bfloat16)
+            for kk in keys[1:3])
+    got = jax.jit(lambda q, k, v: attention(
+        q, k, v, causal=True, window=window))(q, k, v)
+    step = min(1024, length)
+    dense = jax.jit(dense_attention, static_argnums=(3, 4, 5, 6))
+    num = den = 0.0
+    for lo in range(0, length, step):
+        first = max(0, lo - window + 1)
+        want = dense(q[:, lo:lo + step].astype(jnp.float32),
+                     k[:, first:lo + step].astype(jnp.float32),
+                     v[:, first:lo + step].astype(jnp.float32),
+                     True, lo, first, window)
+        num += float(jnp.sum((got[:, lo:lo + step].astype(jnp.float32)
+                              - want) ** 2))
+        den += float(jnp.sum(want ** 2))
+    counts = tile_plan(length, length, g["head_dim"], True,
+                       window=window).counts()
+    _log(f"windowed flash: {counts['blocks_run']} fetch blocks a (row, "
+         f"head) of {counts['blocks_grid']} grid steps")
+    return math.sqrt(num / den)
+
+
+def _grouped_gap(m: dict, keys) -> float:
+    """``grouped_matmul`` over uneven groups (one empty, the last rows
+    in no group) against a loop over the groups."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+    lhs = jax.random.normal(keys[0], (m["rows"], m["k"]), jnp.bfloat16)
+    rhs = jax.random.normal(keys[1], (m["groups"], m["k"], m["n"]),
                             jnp.bfloat16) * m["k"] ** -0.5
-    # uneven groups, one empty, the last rows in no group
     share = np.arange(m["groups"]) % 3
     sizes = (share * (m["rows"] - 8) // max(1, share.sum())).astype(np.int32)
     out = jax.jit(lambda a, b, s: grouped_matmul(a, b, s, jnp.float32))(
@@ -336,11 +402,9 @@ def leg_kernels(cfg: dict) -> dict:
         lo += size
     gm_err = float(np.linalg.norm(np.asarray(out) - want)
                    / np.linalg.norm(want))
-    assert gm_err < BF16_REL_TOL, f"grouped product: {gm_err}"
+    assert gm_err < BF16_REL_TOL, f"grouped product {m}: {gm_err}"
     assert not np.asarray(out[lo:]).any(), "rows of no group are not zero"
-    return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
-            "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
-            "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"])}
+    return gm_err
 
 
 def _combine_gap(c: dict) -> float:

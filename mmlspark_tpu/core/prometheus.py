@@ -238,6 +238,16 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "tokens by a gather and a sum of k: every "
                         "expert is on this chip",
                         m["moe_gather_combines"], labels)
+            if "flash_window_blocks" in m:
+                r.gauge("serving_model_flash_window_blocks",
+                        "key fetch blocks a (row, head) of a sliding-"
+                        "window layer's flash call visits at the "
+                        "model's longest row: its band alone",
+                        m["flash_window_blocks"], labels)
+                r.gauge("serving_model_flash_causal_blocks",
+                        "the same for a full causal layer's call: the "
+                        "blocks on and under the diagonal",
+                        m["flash_causal_blocks"], labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

@@ -2,8 +2,10 @@
 ``hybrid_moe_lm``), and the small pieces both build from: RMSNorm, a
 matrix product in the operands' dtype, the gated (SwiGLU) feed-forward.
 
-An expert layer routes over all ``experts_total`` experts with sigmoid
-scores and a score-correction bias that enters the choice only, and
+An expert layer routes over all ``experts_total`` experts (sigmoid
+scores and a score-correction bias that enters the choice only, or,
+by ``scoring_func`` and ``use_expert_bias``, a softmax over every
+expert and no bias), and
 computes the part of the result that the ``experts_held`` experts of
 rank ``expert_rank`` give, plus ``n_shared_experts`` shared experts. A
 chip that holds every expert is ``experts_held == experts_total``,
@@ -21,7 +23,10 @@ The layer reads its sizes from a ``cfg`` with these attributes:
 ``experts_held``, ``expert_rank``, ``num_experts_per_tok``,
 ``n_shared_experts``, ``routed_scaling_factor``, ``gate_norm_eps`` (what
 is added to the sum of the chosen scores before the gates are divided
-by it; 0 in GLM's family, 1e-6 in LFM2's) and ``dtype``.
+by it; 0 in GLM's family, 1e-6 in LFM2's) and ``dtype``; and, where
+it has them, ``scoring_func`` ("sigmoid", the default, or "softmax") and
+``use_expert_bias`` (true by default; false: no ``router_bias``
+parameter, and the scores alone decide the choice).
 """
 
 from __future__ import annotations
@@ -128,7 +133,8 @@ class ExpertLayer(nn.Module):
         router = self.param("router", _fan_in(dim),
                             (c.experts_total, dim), c.dtype)
         bias = self.param("router_bias", nn.initializers.normal(0.02),
-                          (c.experts_total,), _F32)
+                          (c.experts_total,), _F32) \
+            if getattr(c, "use_expert_bias", True) else None
         w_gate = self.param("experts_gate", _fan_in(dim),
                             (held, dim, width), c.dtype)
         w_up = self.param("experts_up", _fan_in(dim),
@@ -137,7 +143,8 @@ class ExpertLayer(nn.Module):
                             (held, width, dim), c.dtype)
         with jax.named_scope("moe_route"):
             chosen, gates = route(u, router, bias, c.num_experts_per_tok,
-                                  c.routed_scaling_factor, c.gate_norm_eps)
+                                  c.routed_scaling_factor, c.gate_norm_eps,
+                                  getattr(c, "scoring_func", "sigmoid"))
         with jax.named_scope("moe_experts"):
             y, load = routed_experts(u, chosen, gates, w_gate, w_up,
                                      w_down, c.expert_rank * held,
@@ -149,15 +156,22 @@ class ExpertLayer(nn.Module):
         return y.astype(u.dtype), chosen, load
 
 
-def route(u, router, bias, k: int, scaling: float, norm_eps: float = 0.0):
-    """Sigmoid scores over every expert in float32; the k largest of
-    score + bias are chosen, and the chosen scores (without the bias),
-    normalised over the k (``norm_eps`` added to their sum) and scaled,
-    are the gates."""
+SCORING = {"sigmoid": jax.nn.sigmoid,
+           "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
+def route(u, router, bias, k: int, scaling: float, norm_eps: float = 0.0,
+          scoring: str = "sigmoid"):
+    """Scores over every expert in float32 (each logit's sigmoid, or
+    the softmax over all of them); the k largest of score + bias are
+    chosen (of the score alone where ``bias`` is None), and the chosen
+    scores (without the bias), normalised over the k (``norm_eps``
+    added to their sum) and scaled, are the gates."""
     logits = jnp.einsum("td,ed->te", u.astype(_F32), router.astype(_F32),
                         precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, chosen = lax.top_k(scores + bias[None, :], k)
+    scores = SCORING[scoring](logits)
+    _, chosen = lax.top_k(
+        scores if bias is None else scores + bias[None, :], k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     total = jnp.sum(picked, -1, keepdims=True)
     if norm_eps:

@@ -6,15 +6,26 @@ A third network family beside ``networks.Transformer`` and
 all. "conv" is a gated short convolution (a per-channel causal filter of
 ``conv_L_cache`` taps between two gates and two projections);
 "full_attention" is grouped-query attention with a per-head RMSNorm on
-queries and keys and rotate-half rotary positions. The first
+queries and keys and rotate-half rotary positions; "sliding_attention"
+is the same operator with each query held to its ``sliding_window``
+newest keys (itself among them), through the same flash kernel, which
+then visits only the band of key blocks a query block needs. Each
+attention kind has a rotary table of its own where ``rope_parameters``
+names them ("default", or "yarn" with its ``attention_factor`` on cos
+and sin); ``head_dim`` is ``hidden_size / num_attention_heads`` unless
+the spec gives it. The first
 ``num_dense_layers`` layers have a gated (SwiGLU) feed-forward, the
-others the expert layer of expert_layer.py (sigmoid scores, a bias that
-enters the choice only, no shared expert), with every expert on this
+others the expert layer of expert_layer.py (sigmoid scores and a bias
+that enters the choice only, or by ``scoring_func`` and
+``use_expert_bias`` a softmax over every expert and no bias; no shared
+expert), with every expert on this
 chip. The field names are those of the
-published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B).
+published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B) and
+of the ``mellum`` family (Mellum2-12B-A2.5B).
 
     h = x + Op_i(RMSNorm(x));  x' = h + FFN_i(RMSNorm(h))
-    logits = E^T RMSNorm(x[last])           E the tied (vocab, hidden) embedding
+    logits = W RMSNorm(x[last])     W the tied (vocab, hidden) embedding,
+                                    or ``lm_head`` where it is not tied
 
 It is a scorer: token ids (b, l) in, float32 next-token logits of the
 last position out. docs/hybrid_moe_lm.md has the equations, the spec
@@ -29,18 +40,22 @@ and softmax are float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import flax.linen as nn
 
 from mmlspark_tpu.models.expert_layer import (
-    ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones, _pass_rows,
+    SCORING, ExpertLayer, GatedMLP, _F32, _fan_in, _mm, _ones, _pass_rows,
     _row_loads, gather_combines, rms_norm)
 
 Dtype = Any
-OPERATORS = ("conv", "full_attention")
+OPERATORS = ("conv", "full_attention", "sliding_attention")
+ATTENTION = OPERATORS[1:]
 # positions at a row's end whose chosen experts ride out of the step
 # (``routed_tail``): a choice at position t reaches the last position's
 # logits through the later layers' convolutions, two positions a layer,
@@ -78,6 +93,18 @@ class HybridMoEConfig:
     routed_scaling_factor: float = 1.0
     gate_norm_eps: float = 1e-6
     dtype: Dtype = jnp.bfloat16
+    # a head's width where it is not hidden_size / num_attention_heads
+    head_dim: Optional[int] = None
+    # keys a "sliding_attention" layer's query sees, itself among them
+    sliding_window: int = 0
+    # {attention kind: {"rope_type": "default" | "yarn", "rope_theta",
+    # and for yarn "factor", "original_max_position_embeddings",
+    # "beta_fast", "beta_slow", "attention_factor"}}; a kind that is not
+    # named turns by ``rope_theta``, rope_type "default"
+    rope_parameters: Any = None
+    tie_word_embeddings: bool = True
+    scoring_func: str = "sigmoid"
+    use_expert_bias: bool = True
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -90,8 +117,34 @@ class HybridMoEConfig:
                 f"{self.num_attention_heads} query heads over "
                 f"{self.num_key_value_heads} key/value heads: not a "
                 f"whole group a head")
-        if self.hidden_size % self.num_attention_heads:
-            raise ValueError("hidden_size is no multiple of the heads")
+        if self.head_dim is None:
+            if self.hidden_size % self.num_attention_heads:
+                raise ValueError("hidden_size is no multiple of the heads")
+            object.__setattr__(
+                self, "head_dim",
+                self.hidden_size // self.num_attention_heads)
+        if self.head_dim <= 0 or self.head_dim % 2:
+            raise ValueError(f"head_dim {self.head_dim}: rotate-half "
+                             f"pairs need an even, positive width")
+        if "sliding_attention" in self.layer_types \
+                and self.sliding_window <= 0:
+            raise ValueError("a sliding_attention layer needs "
+                             "sliding_window > 0")
+        if self.scoring_func not in SCORING:
+            raise ValueError(f"scoring_func {self.scoring_func!r}; one "
+                             f"of {sorted(SCORING)}")
+        # a JSON object by attention kind -> hashable, checked
+        tables = dict(self.rope_parameters or {})
+        for kind, table in tables.items():
+            table = dict(table)
+            if kind not in ATTENTION or table.get(
+                    "rope_type", "default") not in ("default", "yarn"):
+                raise ValueError(
+                    f"rope_parameters[{kind!r}] = {table}: an attention "
+                    f"kind of {ATTENTION}, rope_type default or yarn")
+            tables[kind] = tuple(sorted(table.items()))
+        object.__setattr__(self, "rope_parameters",
+                           tuple(sorted(tables.items())))
 
     # what the shared expert layer reads, under its names: every expert
     # is on this chip (there is no all-to-all to combine a share)
@@ -99,18 +152,67 @@ class HybridMoEConfig:
     expert_rank = 0
     n_shared_experts = 0
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def rope_for(self, kind: str) -> dict:
+        """The rotary table of an attention kind's layers."""
+        return {"rope_type": "default", "rope_theta": self.rope_theta,
+                **dict(dict(self.rope_parameters).get(kind, ()))}
+
+    def flash_blocks(self, kind: str) -> int:
+        """Fetch blocks with a tile to run that one (row, head) of a
+        ``kind`` layer's flash call visits at ``max_len`` (the kernel's
+        own count, ``TilePlan.counts()``); 0 where no layer is of that
+        kind."""
+        if kind not in self.layer_types:
+            return 0
+        window = self.sliding_window if kind == "sliding_attention" else 0
+        return _blocks_run(self.max_len, self.head_dim, window)
 
 
-def rope_rotate_half(x, positions, theta: float):
+@functools.lru_cache(maxsize=None)
+def _blocks_run(length: int, head_dim: int, window: int) -> int:
+    # (a scrape of ``TPUModel.metrics()`` asks every time: counted once)
+    from mmlspark_tpu.ops.flash_attention import tile_plan
+    return tile_plan(length, length, head_dim, True,
+                     window=window).counts()["blocks_run"]
+
+
+def yarn_table(d: int, rope_theta: float, factor: float,
+               original_max_position_embeddings: int, beta_fast: float = 32.0,
+               beta_slow: float = 1.0, attention_factor=None, **_):
+    """YaRN's (inverse frequencies (d/2,) float32, factor on cos and
+    sin). Pair i turns ``beta`` times over the original context at
+    i = d ln(original / (beta 2 pi)) / (2 ln theta): pairs up to
+    ``low`` (``beta_fast``'s, rounded down) keep their frequency, pairs
+    from ``high`` (``beta_slow``'s, rounded up) have it divided by
+    ``factor``, and those between go linearly from the one to the
+    other. ``attention_factor`` is 0.1 ln(factor) + 1 where not given."""
+    def turns(beta):
+        return d * math.log(original_max_position_embeddings
+                            / (beta * 2 * math.pi)) / (2 * math.log(
+                                rope_theta))
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), d - 1)
+    base = rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv = (1 - ramp) * base + ramp * base / factor
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv.astype(np.float32), float(attention_factor)
+
+
+def rope_rotate_half(x, positions, theta: float, inv=None,
+                     factor: float = 1.0):
     """Rotate the pairs (x[i], x[i + d/2]) of the last axis by
-    positions * theta**(-2i/d). x (b, l, h, d) float32, positions (l,)."""
+    positions * theta**(-2i/d), or by positions * ``inv[i]`` where a
+    table gives the frequencies (``yarn_table``), cos and sin then
+    times ``factor``. x (b, l, h, d) float32, positions (l,)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
     ang = positions.astype(_F32)[:, None] * inv[None, :]       # (l, d/2)
     cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     a, b = x[..., :d // 2], x[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
@@ -148,14 +250,23 @@ class ShortConv(nn.Module):
 
 class GroupedQueryAttention(nn.Module):
     """Causal attention, H query heads over H_kv key/value heads, a
-    per-head RMSNorm on q and k before the rotary step."""
+    per-head RMSNorm on q and k before the rotary step. ``kind`` is the
+    layer's: "sliding_attention" holds a query to its ``sliding_window``
+    newest keys (scope ``swa_attend``), and each kind turns by its own
+    rotary table (``HybridMoEConfig.rope_for``)."""
 
     cfg: Any
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, u):
         from mmlspark_tpu.parallel.ring_attention import attention
         c = self.cfg
+        sliding = self.kind == "sliding_attention"
+        table = c.rope_for(self.kind)
+        turn = {"theta": table["rope_theta"]}
+        if table["rope_type"] == "yarn":
+            turn["inv"], turn["factor"] = yarn_table(c.head_dim, **table)
         dim, dt = c.hidden_size, c.dtype
         h, hk, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         w_q = self.param("q_proj", _fan_in(dim), (dim, h, d), dt)
@@ -168,11 +279,12 @@ class GroupedQueryAttention(nn.Module):
         with jax.named_scope("gqa_project"):
             q = rms_norm(_mm("bld,dhk->blhk", u, w_q), q_norm, c.norm_eps)
             k = rms_norm(_mm("bld,dhk->blhk", u, w_k), k_norm, c.norm_eps)
-            q = rope_rotate_half(q, pos, c.rope_theta).astype(dt)
-            k = rope_rotate_half(k, pos, c.rope_theta).astype(dt)
+            q = rope_rotate_half(q, pos, **turn).astype(dt)
+            k = rope_rotate_half(k, pos, **turn).astype(dt)
             v = _mm("bld,dhk->blhk", u, w_v, dt)
-        with jax.named_scope("gqa_attend"):
-            o = attention(q, k, v, causal=True)
+        with jax.named_scope("swa_attend" if sliding else "gqa_attend"):
+            o = attention(q, k, v, causal=True,
+                          window=c.sliding_window if sliding else 0)
         with jax.named_scope("gqa_project"):
             return _mm("blhk,hkd->bld", o, w_o, dt)
 
@@ -209,6 +321,14 @@ class HybridMoELM(nn.Module):
         return gather_combines(
             c, max(0, len(c.layer_types) - c.num_dense_layers))
 
+    # fetch blocks that a (row, head) of a windowed and of a causal
+    # flash call visit at ``max_len`` (``TPUModel.metrics()`` carries
+    # them): what the window saves is their difference a sliding layer
+    flash_window_blocks = property(
+        lambda self: self.cfg.flash_blocks("sliding_attention"))
+    flash_causal_blocks = property(
+        lambda self: self.cfg.flash_blocks("full_attention"))
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  capture: Optional[str] = None):
@@ -232,7 +352,8 @@ class HybridMoELM(nn.Module):
             if kind == "conv":
                 a = ShortConv(cfg, name=f"layer_{i}_conv")(u)
             else:
-                a = GroupedQueryAttention(cfg, name=f"layer_{i}_attn")(u)
+                a = GroupedQueryAttention(cfg, kind,
+                                          name=f"layer_{i}_attn")(u)
                 attended.append(a[:, -ATTENTION_TAIL:])
             if capture == f"operator_{i}":
                 return a
@@ -263,7 +384,9 @@ class HybridMoELM(nn.Module):
                 "embedding_norm", _ones, (dim,), dt), cfg.norm_eps).astype(dt)
             if capture == "final":
                 return last
-            logits = _mm("bd,vd->bv", last, embed)
+            head = embed if cfg.tie_word_embeddings else self.param(
+                "lm_head", _fan_in(dim), (cfg.vocab_size, dim), dt)
+            logits = _mm("bd,vd->bv", last, head)
         self.sow("stats", "moe_tokens_held", held_tokens)
         self.sow("stats", "moe_load_max_over_mean",
                  imbalance / max(expert_layers, 1))

@@ -554,9 +554,15 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # expert layers of a wrapped module that return the experts'
         # outputs to their tokens by a gather (expert_layer.py): every
         # expert is on this chip; 0 for a module that has none
+        module = getattr(self.get("modelFn"), "module", None)
         out["moe_gather_combines"] = int(getattr(
-            getattr(self.get("modelFn"), "module", None),
-            "moe_gather_combines", 0))
+            module, "moe_gather_combines", 0))
+        # fetch blocks a (row, head) of a windowed and of a causal flash
+        # call of the module visit at its longest row (hybrid_moe_lm):
+        # the window's saving is their difference; 0 for a module that
+        # has no such layer
+        for name in ("flash_window_blocks", "flash_causal_blocks"):
+            out[name] = int(getattr(module, name, 0))
         out["precision"] = self.get("precision")
         out["aot"] = bool(self.aot)
         if self._sharding is not None:

@@ -39,6 +39,18 @@ ran 1.4 x slower than no tiles at all). TPU grid steps run sequentially,
 so VMEM scratch carries the running statistics between KV blocks and
 the output block is written once, on the last KV step.
 
+A sliding WINDOW (``window``: query p sees keys j with 0 <= p - j <
+window) is a third bound beside padding and the diagonal, and the one
+that changes the grid: a query block needs only the key blocks of its
+band, so a windowed call's key axis counts the steps of that band (2 at
+1024-row blocks and a window of 1024, whatever L) and the K/V index maps
+offset each step by the query block's band start. A block outside the
+band is neither fetched nor stepped over (skipping it under ``pl.when``
+would still cost a grid step and a fetch each). Forward only: the
+backward kernels below do not know the window and a windowed call's
+gradient is refused. A call without a window is, to the jaxpr, the call
+it was before the window existed.
+
 The BACKWARD is also Pallas (O(L) memory): the forward additionally
 writes the per-row log-sum-exp, and two kernels recompute the
 probabilities tile by tile under the same plan — one accumulating dQ
@@ -147,7 +159,14 @@ class TilePlan:
     L <= 1024; below, on and above the diagonal at longer L, each with
     or without padded keys) and a kernel holds one unrolled body a
     kind. Padded QUERY rows are not the plan's business (they are
-    computed and sliced away, as ever)."""
+    computed and sliced away, as ever).
+
+    ``window`` (0: none) is a third bound beside padding and the
+    diagonal: query p sees key j only where ``0 <= p - j < window``. A
+    tile then has masked key tiles at both ends of its walk
+    (``key_start``), and a query block needs the key blocks of its
+    *band* alone (``band``): a windowed call's grid runs over the band,
+    ``band_blocks`` steps a query block, not over every key block."""
     lq: int
     lk: int
     bq: int
@@ -157,11 +176,38 @@ class TilePlan:
     causal: bool
     q_offset: int
     k_offset: int
+    window: int = 0
 
     @property
     def grid(self):
         """(query fetch blocks, key fetch blocks)."""
         return -(-self.lq // self.bq), -(-self.lk // self.bk)
+
+    def band(self, qi):
+        """(first, last) key fetch block that query block qi needs under
+        the window: from the block of the oldest key its first query
+        sees to the block of its last query's own position, held to the
+        grid. Python ints for an int, traced scalars for a program id;
+        last < first where the block sees no key at all."""
+        ints = isinstance(qi, int)
+        lo = qi * self.bq + self.q_offset - self.k_offset
+        hi = lo + self.bq - 1
+        lo = lo - (self.window - 1)
+        nk = self.grid[1]
+        if ints:
+            return max(lo, 0) // self.bk, min(hi // self.bk, nk - 1)
+        return (jnp.maximum(lo, 0) // self.bk,
+                jnp.minimum(jnp.maximum(hi, -1) // self.bk, nk - 1))
+
+    @property
+    def band_blocks(self) -> int:
+        """Grid steps along the keys: the widest band of any query
+        block where there is a window, every key block where not."""
+        nq, nk = self.grid
+        if not self.window:
+            return nk
+        return max(1, max(hi - lo + 1 for lo, hi in map(
+            self.band, range(nq))))
 
     @property
     def tiles(self):
@@ -179,15 +225,18 @@ class TilePlan:
             return 0, real
         shift = (qi * self.bq + self.q_offset
                  - ki * self.bk - self.k_offset)
-        # at -bq and under no query sees a key, from bk - 1 up all do
-        return clip(shift, -self.bq, self.bk - 1), real
+        # at -bq and under no query sees a key, from bk - 1 up all do;
+        # under a window none does again from window + bk - 1 up
+        top = self.window + self.bk - 1 if self.window else self.bk - 1
+        return clip(shift, -self.bq, top), real
 
     def kinds(self) -> dict:
         """kind -> how many fetch blocks of the call are of it."""
         nq, nk = self.grid
         found: dict = {}
         for qi in range(nq):
-            for ki in range(nk):
+            lo, hi = self.band(qi) if self.window else (0, nk - 1)
+            for ki in range(lo, hi + 1):
                 kind = self.block_kind(qi, ki)
                 found[kind] = found.get(kind, 0) + 1
         return found
@@ -205,6 +254,22 @@ class TilePlan:
             run = min(run, _count(gap + self.tq - 1 + self.tk, self.tk, n))
             bare = min(bare, _count(gap + 1, self.tk, n))
         return min(bare, run), run
+
+    def key_start(self, kind, i: int):
+        """For query tile i of a block of this kind -> (start, clear):
+        under a window key tiles [0, start) are not visited (too old for
+        every query of the tile) and [start, clear) run with the mask;
+        (0, 0) without one."""
+        if not self.window:
+            return 0, 0
+        n = self.bk // self.tk
+        # the first key the tile's first query sees; its last query's
+        # first key lies tq - 1 further on
+        first = kind[0] + i * self.tq - (self.window - 1)
+        if first >= kind[1]:            # ... and that is past the keys
+            return n, n
+        return (_count(first, self.tk, n),
+                _count(first + self.tq - 1 + self.tk - 1, self.tk, n))
 
     def query_span(self, kind, j: int):
         """For key tile j of a block of this kind -> (first, bare):
@@ -224,13 +289,17 @@ class TilePlan:
         return first, bare
 
     def runs(self, kind) -> bool:
-        """Whether a block of this kind has any tile to run; the last
-        query tile sees the most."""
-        return self.key_span(kind, self.bq // self.tq - 1)[1] > 0
+        """Whether a block of this kind has any tile to run (without a
+        window the last query tile sees the most; under one any may)."""
+        return any(self.key_span(kind, i)[1] > self.key_start(kind, i)[0]
+                   for i in range(self.bq // self.tq))
 
     def tile_kind(self, kind, i: int, j: int) -> str:
         bare, run = self.key_span(kind, i)
-        return "bare" if j < bare else "masked" if j < run else "skipped"
+        start, clear = self.key_start(kind, i)
+        if j < start or j >= run:
+            return "skipped"
+        return "bare" if clear <= j < bare else "masked"
 
     def counts(self) -> dict:
         """Totals over every fetch block: tiles_run / tiles_square is
@@ -238,29 +307,40 @@ class TilePlan:
         is executed (20 / 32 for a causal 1024 x 1024 in 128 x 256
         tiles)."""
         n_i, n_j = self.tiles
-        total = {"tiles_square": 0, "tiles_run": 0, "tiles_masked": 0}
+        nq, nk = self.grid
+        # the fetch blocks of one (row, head): those with a tile to run,
+        # and the grid steps spent on them and on the others
+        total = {"tiles_square": nq * nk * n_i * n_j, "tiles_run": 0,
+                 "tiles_masked": 0, "blocks_run": 0,
+                 "blocks_grid": nq * self.band_blocks}
         for kind, blocks in self.kinds().items():
-            spans = [self.key_span(kind, i) for i in range(n_i)]
-            total["tiles_square"] += blocks * n_i * n_j
-            total["tiles_run"] += blocks * sum(run for _, run in spans)
-            total["tiles_masked"] += blocks * sum(
-                run - bare for bare, run in spans)
+            tiles = [self.tile_kind(kind, i, j)
+                     for i in range(n_i) for j in range(n_j)]
+            masked = tiles.count("masked")
+            total["tiles_run"] += blocks * (masked + tiles.count("bare"))
+            total["tiles_masked"] += blocks * masked
+            total["blocks_run"] += blocks * self.runs(kind)
         return total
 
 
 def tile_plan(lq: int, lk: int, d: int, causal: bool, q_offset: int = 0,
-              k_offset: int = 0) -> TilePlan:
+              k_offset: int = 0, window: int = 0) -> TilePlan:
     """The plan of one call, from what the call can observe: lengths,
-    head width (through the block caps), ``causal`` and the offsets."""
+    head width (through the block caps), ``causal``, the offsets and
+    the window (0: none; it narrows a causal call only)."""
+    if window < 0 or (window and not causal):
+        raise ValueError(f"window={window}: a window is a positive count "
+                         f"of keys on a causal call (0 for none)")
     bq, bk, _, _ = _blocks(lq, lk, d)
     return TilePlan(lq, lk, bq, bk, _tile(bq, TILE_Q), _tile(bk, TILE_K),
-                    bool(causal), int(q_offset), int(k_offset))
+                    bool(causal), int(q_offset), int(k_offset), int(window))
 
 
 def _valid_mask(plan: TilePlan, kind, r0: int, nr: int, c0: int, nc: int):
     """(nr, nc) mask of query rows r0.. against key rows c0.. of a
-    block of this kind (block-relative): padding keys always; causal by
-    position. Only the comparisons that can fail in there are built."""
+    block of this kind (block-relative): padding keys always; causal
+    and the window by position. Only the comparisons that can fail in
+    there are built."""
     shift, real = kind
     col = lax.broadcasted_iota(jnp.int32, (nr, nc), 1)
     valid = None
@@ -270,6 +350,11 @@ def _valid_mask(plan: TilePlan, kind, r0: int, nr: int, c0: int, nc: int):
     if plan.causal and gap < nc - 1:
         seen = lax.broadcasted_iota(jnp.int32, (nr, nc), 0) + gap >= col
         valid = seen if valid is None else valid & seen
+    if plan.window and nr - 1 + gap >= plan.window:
+        # ... and none at or under column r + gap - window
+        near = lax.broadcasted_iota(jnp.int32, (nr, nc), 0) \
+            + (gap - plan.window) < col
+        valid = near if valid is None else valid & near
     return valid
 
 
@@ -295,12 +380,17 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _mask_from(x, start: int, valid, fill):
-    """x with its columns from ``start`` on set to ``fill`` where not
-    valid; the columns before it are not touched."""
-    tail = jnp.where(valid, x[:, start:], fill)
-    return tail if start == 0 else jnp.concatenate(
-        [x[:, :start], tail], axis=1)
+def _mask_from(x, start: int, valid, fill, stop=None):
+    """x with its columns from ``start`` on (up to ``stop``, where
+    given) set to ``fill`` where not valid; the other columns are not
+    touched."""
+    if stop is None or stop == x.shape[1]:
+        tail = jnp.where(valid, x[:, start:], fill)
+        return tail if start == 0 else jnp.concatenate(
+            [x[:, :start], tail], axis=1)
+    parts = [x[:, :start], jnp.where(valid, x[:, start:stop], fill),
+             x[:, stop:]]
+    return jnp.concatenate(parts[start == 0:], axis=1)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -324,25 +414,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         scores = {}
         for i in range(plan.bq // tq):
             bare, run = plan.key_span(kind, i)
-            if not run:
+            start, clear = plan.key_start(kind, i)
+            if run <= start:
                 continue
             rows = pl.ds(i * tq, tq)
-            keys = pl.ds(0, run * tk)       # every key the tile sees here
+            # every key the tile sees here
+            keys = pl.ds(start * tk, (run - start) * tk)
             q = q_ref[0, rows, :].astype(jnp.float32) * scale   # (tq, D)
             s = _dot(q, k_ref[0, keys, :].astype(jnp.float32), _NT)
-            valid = _valid_mask(plan, kind, i * tq, tq, bare * tk,
-                                (run - bare) * tk) if run > bare else None
+            # the key tiles that build the mask: [bare, run) at the
+            # diagonal or the end of the keys, [start, clear) at the
+            # window's edge, one stretch where both are in the walk
+            lo = start if clear > start else max(bare, start)
+            hi = run if run > bare else min(clear, run)
+            valid = _valid_mask(plan, kind, i * tq, tq, lo * tk,
+                                (hi - lo) * tk) if hi > lo else None
+            span = ((lo - start) * tk, (hi - start) * tk)
             if valid is not None:
-                s = _mask_from(s, bare * tk, valid, NEG_INF)
+                s = _mask_from(s, span[0], valid, NEG_INF, span[1])
             m_prev = m_scr[rows]                                # (tq, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            scores[i] = (rows, keys, bare * tk, s, valid, m_prev, m_new)
-        for rows, keys, start, s, valid, m_prev, m_new in scores.values():
+            scores[i] = (rows, keys, span, s, valid, m_prev, m_new)
+        for rows, keys, span, s, valid, m_prev, m_new in scores.values():
             p = jnp.exp(s - m_new)                              # (tq, keys)
             if valid is not None:
                 # fully-masked-so-far rows keep m at NEG_INF, where
                 # exp(s - m) is exp(0) for every masked key
-                p = _mask_from(p, start, valid, 0.0)
+                p = _mask_from(p, span[0], valid, 0.0, span[1])
             corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
             l_scr[rows] = l_scr[rows] * corr + jnp.sum(
                 p, axis=-1, keepdims=True)
@@ -350,7 +448,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 p, v_ref[0, keys, :].astype(jnp.float32), _NN)
             m_scr[rows] = m_new
 
-    _each_kind(plan, qi, ki, block)
+    # under a window the grid's key axis counts the steps of the query
+    # block's band, not the key blocks
+    _each_kind(plan, qi, plan.band(qi)[0] + ki if plan.window else ki,
+               block)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -473,13 +574,19 @@ def _kv_group(q, k, v) -> int:
     return h // hk
 
 
-def _kv_block(bk: int, d: int, group: int, key_axis: int):
+def _kv_block(bk: int, d: int, group: int, key_axis: int, plan=None):
     """BlockSpec of a K or V fetch block: the grid's first index is the
     query head's b*H + h, whose key/value head is b*H_kv + h // group =
     (b*H + h) // group (the index itself, with no arithmetic, where
     group is 1). ``key_axis`` says which of the grid's other two indices
-    counts key blocks."""
+    counts key blocks. With a windowed ``plan`` (the forward's) that
+    index counts the steps of the query block's band instead: step j
+    fetches the band's j-th key block, and a step past the band's end
+    names the last block again, which is no new fetch."""
     def index(bh, i, j):
+        if plan is not None and plan.window:
+            first, last = plan.band(i)
+            j = jnp.maximum(jnp.minimum(first + j, last), 0)
         return (bh if group == 1 else bh // group,
                 i if key_axis == 1 else j, 0)
     return pl.BlockSpec((1, bk, d), index)
@@ -494,19 +601,25 @@ def _heads_major(x, pad, lpad_idx=1):
     return xt
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, q_offset, k_offset, interpret):
-    out, _ = _flash_forward(q, k, v, causal, q_offset, k_offset, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, q_offset, k_offset, interpret, window):
+    out, _ = _flash_forward(q, k, v, causal, q_offset, k_offset, interpret,
+                            window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, q_offset, k_offset, interpret):
+def _flash_fwd(q, k, v, causal, q_offset, k_offset, interpret, window):
     out, lse = _flash_forward(q, k, v, causal, q_offset, k_offset,
-                              interpret)
+                              interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, q_offset, k_offset, interpret, res, g):
+def _flash_bwd(causal, q_offset, k_offset, interpret, window, res, g):
+    if window:
+        raise NotImplementedError(
+            f"flash_attention(window={window}) has no backward: the dQ "
+            f"and dK/dV kernels do not know the window (a windowed layer "
+            f"scores; it does not train yet)")
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, q_offset,
                            k_offset, interpret)
@@ -516,40 +629,49 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = False, q_offset: int = 0,
-                    k_offset: int = 0, interpret: bool = False):
+                    k_offset: int = 0, interpret: bool = False,
+                    window: int = 0):
     """Drop-in for ring_attention.attention on big blocks.
     Differentiable with O(L) memory in BOTH directions: the forward saves
     the per-row logsumexp and the custom_vjp backward recomputes
-    probabilities blockwise in two Pallas kernels (dQ; dK/dV)."""
+    probabilities blockwise in two Pallas kernels (dQ; dK/dV).
+
+    ``window`` (0: none) narrows a causal call to the ``window`` newest
+    keys of each query, itself among them: the forward's grid then runs
+    over each query block's band of key blocks alone. Forward only: the
+    gradient of a windowed call is refused."""
     return _flash(q, k, v, bool(causal), int(q_offset), int(k_offset),
-                  bool(interpret))
+                  bool(interpret), int(window))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "q_offset", "k_offset", "interpret"))
+    static_argnames=("causal", "q_offset", "k_offset", "interpret",
+                     "window"))
 def _flash_forward(q, k, v, causal: bool = False, q_offset: int = 0,
-                   k_offset: int = 0, interpret: bool = False):
+                   k_offset: int = 0, interpret: bool = False,
+                   window: int = 0):
     b, lq, h, d = q.shape
     lk = k.shape[1]
     group = _kv_group(q, k, v)
     scale = 1.0 / float(d) ** 0.5
     bq, bk, pad_q, pad_k = _blocks(lq, lk, d)
-    plan = tile_plan(lq, lk, d, causal, q_offset, k_offset)
+    plan = tile_plan(lq, lk, d, causal, q_offset, k_offset, window)
 
     # heads-major (BH, L, D) layout for per-(batch, head) grid blocks
     qt = _heads_major(q, pad_q)
     kt = _heads_major(k, pad_k)
     vt = _heads_major(v, pad_k)
 
-    grid = (b * h, (lq + pad_q) // bq, (lk + pad_k) // bk)
+    # (under a window the key axis is the band: plan.band_blocks steps)
+    grid = (b * h, (lq + pad_q) // bq, plan.band_blocks)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, plan=plan, scale=scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            _kv_block(bk, d, group, 2),
-            _kv_block(bk, d, group, 2),
+            _kv_block(bk, d, group, 2, plan),
+            _kv_block(bk, d, group, 2, plan),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),

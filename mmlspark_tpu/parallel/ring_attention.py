@@ -71,11 +71,15 @@ FLASH_MIN_LEN = 512
 
 
 def dense_attention(q, k, v, causal: bool = False,
-                    q_offset=0, k_offset=0) -> jnp.ndarray:
+                    q_offset=0, k_offset=0, window: int = 0) -> jnp.ndarray:
     """The dense einsum path — the numerics reference the flash kernel
     (forward) and its custom_vjp backward are both held to. k/v with
     fewer heads than q (grouped-query attention) are repeated here: key/
-    value head h // (H / H_kv) serves query head h."""
+    value head h // (H / H_kv) serves query head h. ``window`` (0: none)
+    leaves a causal query its ``window`` newest keys, itself among
+    them."""
+    if window and not causal:
+        raise ValueError("a window narrows a causal call")
     if k.shape[2] != q.shape[2]:
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
                 for x in (k, v))
@@ -85,6 +89,8 @@ def dense_attention(q, k, v, causal: bool = False,
         qpos = q_offset + jnp.arange(q.shape[1])
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window:
+            mask &= qpos[:, None] - kpos[None, :] < window
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if causal:
@@ -97,7 +103,8 @@ def dense_attention(q, k, v, causal: bool = False,
 
 
 def attention(q, k, v, causal: bool = False,
-              q_offset: int = 0, k_offset: int = 0) -> jnp.ndarray:
+              q_offset: int = 0, k_offset: int = 0,
+              window: int = 0) -> jnp.ndarray:
     """Plain (single-device) attention.
 
     q (B, Lq, H, D); k/v (B, Lk, H, D), or (B, Lk, H_kv, D) with H a
@@ -105,19 +112,23 @@ def attention(q, k, v, causal: bool = False,
     the shared head, the einsum path repeats it). Offsets give global
     positions for causal masking of sequence shards. Long sequences on TPU run the
     Pallas flash kernel (O(L) memory, scores never leave VMEM — see
-    ops/flash_attention.py); short ones use the fused XLA einsum."""
+    ops/flash_attention.py); short ones use the fused XLA einsum.
+    ``window`` (0: none) is a sliding-window layer's: a causal query
+    sees its ``window`` newest keys, and the kernel visits only the
+    band of key blocks that holds them (forward only)."""
     if (jax.default_backend() == "tpu"
             and isinstance(q_offset, int) and isinstance(k_offset, int)
             and q.shape[1] >= FLASH_MIN_LEN
             and k.shape[1] >= FLASH_MIN_LEN):
         from mmlspark_tpu.ops.flash_attention import flash_attention
         flash = functools.partial(flash_attention, causal=causal,
-                                  q_offset=q_offset, k_offset=k_offset)
+                                  q_offset=q_offset, k_offset=k_offset,
+                                  window=window)
         mesh = jax.sharding.get_abstract_mesh()
         if mesh.size > 1 and not mesh.manual_axes:
             return flash_per_shard(flash, mesh, q, k, v)
         return flash(q, k, v)
-    return dense_attention(q, k, v, causal, q_offset, k_offset)
+    return dense_attention(q, k, v, causal, q_offset, k_offset, window)
 
 
 def dense_selected_attention(q, k, v, keep) -> jnp.ndarray:

@@ -27,6 +27,10 @@ TINY = {
     "gqa": {"batch": 1, "length": 48, "heads": 4, "kv_heads": 2,
             "head_dim": 16},
     "grouped": {"rows": 64, "groups": 4, "k": 16, "n": 24},
+    "swa": {"batch": 1, "length": 96, "heads": 4, "kv_heads": 1,
+            "head_dim": 16, "window": 20},
+    "grouped_narrow": [{"rows": 64, "groups": 4, "k": 24, "n": 8},
+                       {"rows": 64, "groups": 4, "k": 8, "n": 24}],
     "combine": {"tokens": 48, "k": 4, "dim": 16, "passes": 4},
 }
 
@@ -50,6 +54,16 @@ def test_kernels_leg():
     assert facts["grouped_tiles_k_n"] == [16, 24]
     full = chip_smoke.FULL["grouped"]
     assert (full["k"], full["n"]) == (2048, 1536)
+    assert facts["swa_rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
+    assert max(facts["grouped_narrow_rel_l2"]) < chip_smoke.BF16_REL_TOL
+    assert facts["grouped_narrow_tiles_k_n"] == [[24, 8], [8, 24]]
+    full = chip_smoke.FULL["swa"]
+    assert full["length"] // 1024 == 16 and full["window"] == 1024
+    assert (full["heads"], full["kv_heads"], full["head_dim"]) == (32, 4, 128)
+    from mmlspark_tpu.ops.grouped_matmul import _tile
+    assert [[_tile(m["k"], 1024), _tile(m["n"], 1024)]
+            for m in chip_smoke.FULL["grouped_narrow"]] == [
+        [768, 896], [896, 768]]
     assert facts["combine_rel_l2_vs_scatter_add"] < 1e-6
     full = chip_smoke.FULL["combine"]
     assert (full["tokens"] * full["k"], full["dim"]) == (131072, 2048)

@@ -289,9 +289,13 @@ def test_tile_plan_counts(monkeypatch, case):
                 qi * plan.bq:(qi + 1) * plan.bq,
                 ki * plan.bk:(ki + 1) * plan.bk].any()
     counts = plan.counts()
+    blocks = allowed.reshape(nq, plan.bq, nk, plan.bk).any((1, 3))
     assert counts == {"tiles_square": sum(seen.values()),
                       "tiles_run": seen["bare"] + seen["masked"],
-                      "tiles_masked": seen["masked"]}
+                      "tiles_masked": seen["masked"],
+                      # without a window the grid steps over every block
+                      "blocks_run": int(blocks.sum()),
+                      "blocks_grid": nq * nk}
     if want:
         assert (counts["tiles_run"], counts["tiles_masked"],
                 counts["tiles_square"]) == want

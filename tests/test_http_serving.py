@@ -7,6 +7,7 @@ same with the threaded serving engine.
 
 import json
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -262,6 +263,12 @@ class TestServing:
         try:
             results = [fleet.post({"x": i})["echo"] for i in range(9)]
             assert results == list(range(9))
+            # a handler counts its reply after it has written it, so the
+            # last one may still be on its way to the counter
+            deadline = time.time() + 5.0
+            while fleet.counters()["answered"] < 9 \
+                    and time.time() < deadline:
+                time.sleep(0.01)
             c = fleet.counters()
             assert c["answered"] == 9
             # round-robin really spread the load
