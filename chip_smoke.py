@@ -71,6 +71,11 @@ FULL = {
     # the routed experts' combine where every expert is held, at the
     # LFM2 cell's shape: 32,768 tokens x 4 rows of 2048 float32 a layer
     "combine": {"tokens": 32768, "k": 4, "dim": 2048, "passes": 4},
+    # ... and the down product of every pair of a layer in one call, at
+    # the LFM2 and the Mellum2 cell's shape, against the passes' calls
+    "layer_down": [
+        {"rows": 131072, "passes": 4, "groups": 64, "k": 1536, "n": 2048},
+        {"rows": 262144, "passes": 8, "groups": 64, "k": 896, "n": 2304}],
 }
 
 # a bf16 forward against the float32 reference, as relative L2 error of
@@ -308,8 +313,9 @@ def leg_kernels(cfg: dict) -> dict:
     repeated K and V, the same under a sliding window (the band of key
     blocks alone) against the masked einsum, the grouped product at
     widths the 1024 tile does not divide (1536; 2304 and 896) against a
-    loop over the groups, and the routed experts' gather combine
-    against the scatter-add form."""
+    loop over the groups, the routed experts' gather combine against
+    the scatter-add form, and their layer-wide down product against the
+    per-pass form."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
@@ -340,7 +346,9 @@ def leg_kernels(cfg: dict) -> dict:
             "grouped_narrow_tiles_k_n": [
                 [_tile(m["k"], 1024), _tile(m["n"], 1024)]
                 for m in cfg["grouped_narrow"]],
-            "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"])}
+            "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"]),
+            "layer_down_rel_l2": [_layer_down_gap(m)
+                                  for m in cfg["layer_down"]]}
 
 
 def _windowed_gap(g: dict) -> float:
@@ -434,6 +442,48 @@ def _combine_gap(c: dict) -> float:
     gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert gap < 1e-6, f"gather combine against the scatter-add: {gap}"
     return gap
+
+
+def _layer_down_gap(m: dict) -> float:
+    """The down product where every expert is held: one
+    ``grouped_matmul`` over every pair of a layer, no row zeroed (every
+    row is some group's), against what ``routed_experts`` ran before:
+    a call a pass, each ending in its zeroing ``where``, copied into a
+    zero-filled buffer. Uneven groups, one empty. The same products in
+    the same order: 0 expected."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+    rows, passes = m["rows"], m["passes"]
+    per = rows // passes
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    lhs = jax.random.normal(keys[0], (rows, m["k"]), jnp.bfloat16)
+    rhs = jax.random.normal(keys[1], (m["groups"], m["k"], m["n"]),
+                            jnp.bfloat16) * m["k"] ** -0.5
+    share = np.random.default_rng(3).random(m["groups"])
+    share[1] = 0.0
+    load = np.floor(share / share.sum() * rows).astype(np.int32)
+    load[-1] += rows - load.sum()
+
+    def gap(lhs, rhs, load):
+        whole = grouped_matmul(lhs, rhs, load, jnp.float32,
+                               rest_unread=True)
+        ends = jnp.cumsum(load)
+
+        def one_pass(i, acc):
+            lo = i * per
+            sizes = jnp.clip(ends - lo, 0, per) \
+                - jnp.clip(ends - load - lo, 0, per)
+            out = grouped_matmul(lax.dynamic_slice_in_dim(lhs, lo, per),
+                                 rhs, sizes, jnp.float32)
+            return lax.dynamic_update_slice_in_dim(acc, out, lo, 0)
+        by_pass = lax.fori_loop(0, passes, one_pass,
+                                jnp.zeros((rows, m["n"]), jnp.float32))
+        return jnp.linalg.norm(whole - by_pass) / jnp.linalg.norm(by_pass)
+    got = float(jax.jit(gap)(lhs, rhs, jnp.asarray(load)))
+    assert got <= 1e-7, f"layer-wide down product {m}: {got}"
+    return got
 
 
 def _auc(y: np.ndarray, score: np.ndarray) -> float:

@@ -238,6 +238,12 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "tokens by a gather and a sum of k: every "
                         "expert is on this chip",
                         m["moe_gather_combines"], labels)
+            if "moe_layer_down_products" in m:
+                r.gauge("serving_model_moe_layer_down_products",
+                        "expert layers whose down product runs once a "
+                        "layer over every pair and writes the layer's "
+                        "buffer itself: every expert is on this chip",
+                        m["moe_layer_down_products"], labels)
             if "flash_window_blocks" in m:
                 r.gauge("serving_model_flash_window_blocks",
                         "key fetch blocks a (row, head) of a sliding-"

@@ -12,11 +12,13 @@ chip that holds every expert is ``experts_held == experts_total``,
 rank 0. No token is dropped: the (token, expert) pairs routed here go
 through the grouped products in passes. Where a share of the experts is
 held, the passes are as many as the pairs routed here take, and each
-adds its rows into their tokens (a scatter-add). Where every expert is
-held, every pair is routed here: the passes are ``pairs / rows`` whatever
-the router chose, the sorted order is a permutation of the pairs, and
-the outputs return to their tokens once a layer by its inverse: a
-gather and a sum of k (``combines_by_gather``).
+runs all three products and adds its rows into their tokens (a
+scatter-add). Where every expert is held, every pair is routed here:
+the passes are ``pairs / rows`` whatever the router chose and run what
+needs passes (the gathered input, the gate and up products), the sorted
+order is a permutation of the pairs, so the down product runs once a
+layer over every pair and its output returns to the tokens by the
+order's inverse: a gather and a sum of k (``combines_by_gather``).
 
 The layer reads its sizes from a ``cfg`` with these attributes:
 ``hidden_size``, ``moe_intermediate_size``, ``experts_total``,
@@ -50,10 +52,12 @@ PASS_SHARE = 1.25
 # ... and no more rows than this, whatever the share: a pass holds its
 # rows' gathered input and the float32 gate and up products, which at a
 # chip that holds every expert would be every pair of the step at once
-# (131,072 rows at LFM2's widths: 2.1 GB). The down product's float32
-# rows are a pass's too where a share is held; where every expert is,
-# they are kept for the whole layer (131,072 x 2048: 1.07 GB) and
-# combined after the last pass
+# (131,072 rows at LFM2's widths: 2.1 GB). Where a share is held the
+# down product's float32 rows are a pass's too. Where every expert is,
+# a pass leaves its rows of silu(gate) * up in the model's dtype
+# (131,072 x 1536 bfloat16 a layer: 0.40 GB) and the down product is
+# no pass's: one call over every pair, whose float32 output (131,072 x
+# 2048: 1.07 GB) the combine reads
 PASS_ROWS_MAX = 32768
 
 
@@ -68,9 +72,10 @@ def combines_by_gather(held: int, total: int) -> bool:
 
 
 def gather_combines(cfg, expert_layers: int) -> int:
-    """How many of a module's ``expert_layers`` combine by the gather:
-    what the families expose as ``moe_gather_combines`` and
-    ``TPUModel.metrics()`` carries."""
+    """How many of a module's ``expert_layers`` combine by the gather
+    (and run their down product once a layer, the same branch): what
+    the families expose as ``moe_gather_combines`` and
+    ``moe_layer_down_products``, and ``TPUModel.metrics()`` carries."""
     every = combines_by_gather(cfg.experts_held, cfg.experts_total)
     return expert_layers if every else 0
 
@@ -189,12 +194,17 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
 
     A share of the experts (``held < total``): as many passes as the
     pairs routed here take (one unless the experts held are popular),
-    each scaling its rows by their gates and adding them into their
-    tokens. Every expert (``combines_by_gather``): ``pairs / rows``
-    passes, each writing its down product's float32 rows into its slice
-    of one (pairs, dim) buffer; after the last, token by token, the k
-    rows of the token are gathered through the inverse of the sorted
-    order, scaled and summed in float32, slot 0 first."""
+    each running gate, up and down, scaling its rows by their gates and
+    adding them into their tokens. Every expert (``combines_by_gather``):
+    ``pairs / rows`` passes, each writing its rows of silu(gate) * up,
+    in ``u``'s dtype, into its slice of one (passes * rows, width)
+    buffer that nothing fills first (the passes write every row of
+    it); after the last, one down product over every pair, whose
+    float32 output is the layer's buffer as it stands (no pass zeroes
+    it, copies into it or fills it first: every row up to the pairs is
+    some group's, and nothing reads a row past them); then, token by
+    token, the k rows of the token are gathered through the inverse of
+    the sorted order, scaled and summed in float32, slot 0 first."""
     t, k = chosen.shape
     held = w_gate.shape[0]
     every = combines_by_gather(held, total)
@@ -222,24 +232,35 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
             - jnp.clip(ends - load - lo, 0, rows)
         x = u[tok]
         # the grouped products apart from the sort, gather, scaling and
-        # combine around them (``moe_dispatch_share`` reads the rest)
+        # combine around them (``moe_dispatch_share`` reads the rest).
+        # ``rest_unread``: where every expert is held a row of no group
+        # (past the pairs, in a last pass not full) feeds only the same
+        # row of the down product, which is of no group either
         with jax.named_scope("moe_grouped"):
-            h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32)) \
-                * grouped_matmul(x, w_up, sizes, _F32)
-            out = grouped_matmul(h.astype(u.dtype), w_down, sizes, _F32)
-        if every:
-            return lax.dynamic_update_slice_in_dim(acc, out, lo, 0)
+            h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32,
+                                           rest_unread=every)) \
+                * grouped_matmul(x, w_up, sizes, _F32, rest_unread=every)
+            h = h.astype(u.dtype)
+            if every:
+                return lax.dynamic_update_slice_in_dim(acc, h, lo, 0)
+            out = grouped_matmul(h, w_down, sizes, _F32)
         live = (lo + jnp.arange(rows)) < n_here
         out = jnp.where(live[:, None], out * gate[:, None], 0.0)
         return acc.at[tok].add(out)
 
     # (every pair is here where every expert is: ``passes`` trips)
-    acc = lax.fori_loop(0, (n_here + rows - 1) // rows, one_pass, jnp.zeros(
-        (passes * rows if every else t, u.shape[1]), _F32))
+    trips = (n_here + rows - 1) // rows
     if not every:
-        return acc, load
+        return lax.fori_loop(0, trips, one_pass,
+                             jnp.zeros((t, u.shape[1]), _F32)), load
+    # every one of its rows is some pass's, so the buffer starts as it
+    # is found (zeros off the chip)
+    acc = lax.fori_loop(0, trips, one_pass, lax.empty(
+        (passes * rows, w_gate.shape[2]), u.dtype))
+    with jax.named_scope("moe_grouped"):
+        out_all = grouped_matmul(acc, w_down, load, _F32, rest_unread=True)
     with jax.named_scope("moe_combine"):
-        return _gather_combine(acc, order, gates), load
+        return _gather_combine(out_all, order, gates), load
 
 
 def _gather_combine(out_all, order, gates):

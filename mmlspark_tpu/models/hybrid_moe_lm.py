@@ -321,6 +321,11 @@ class HybridMoELM(nn.Module):
         return gather_combines(
             c, max(0, len(c.layer_types) - c.num_dense_layers))
 
+    # ... and those layers run their down product once a layer, not once
+    # a pass (``TPUModel.metrics()`` carries this too): one branch of
+    # ``routed_experts`` does both
+    moe_layer_down_products = moe_gather_combines
+
     # fetch blocks that a (row, head) of a windowed and of a causal
     # flash call visit at ``max_len`` (``TPUModel.metrics()`` carries
     # them): what the window saves is their difference a sliding layer

@@ -252,6 +252,11 @@ class LatentMoELM(nn.Module):
         return gather_combines(
             c, sum(kind == "sparse" for kind in c.mlp_layer_types))
 
+    # ... and those layers run their down product once a layer, not once
+    # a pass (``TPUModel.metrics()`` carries this too): one branch of
+    # ``routed_experts`` does both
+    moe_layer_down_products = moe_gather_combines
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  capture: Optional[str] = None):

@@ -552,11 +552,13 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # them in), and the bytes a call no longer reads for it
         out["weights_cast_leaves"], out["weights_cast_bytes"] = self._cast
         # expert layers of a wrapped module that return the experts'
-        # outputs to their tokens by a gather (expert_layer.py): every
-        # expert is on this chip; 0 for a module that has none
+        # outputs to their tokens by a gather, and that run their down
+        # product once a layer, its output the layer's buffer
+        # (expert_layer.py): every expert is on this chip; 0 for a
+        # module that has none
         module = getattr(self.get("modelFn"), "module", None)
-        out["moe_gather_combines"] = int(getattr(
-            module, "moe_gather_combines", 0))
+        for name in ("moe_gather_combines", "moe_layer_down_products"):
+            out[name] = int(getattr(module, name, 0))
         # fetch blocks a (row, head) of a windowed and of a causal flash
         # call of the module visit at its longest row (hybrid_moe_lm):
         # the window's saving is their difference; 0 for a module that

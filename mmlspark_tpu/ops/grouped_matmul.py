@@ -6,10 +6,13 @@
 each other; ``rhs`` (groups, k, n) one matrix a group; ``group_sizes``
 (groups,) int32 says how many rows each group has. Their sum may be
 less than m: the rows past it belong to no group and come back as
-zeros. On a TPU this is the megablox Pallas kernel that ships with jax
-(its grid runs over the tiles that hold rows, so the time follows the
-rows routed here, not m); elsewhere ``lax.ragged_dot``. bfloat16
-operands, float32 accumulation.
+zeros, unless the caller says that nothing reads them
+(``rest_unread``): then they hold whatever the kernel left there, and
+the pass over the whole output that would zero them is not made. On a
+TPU this is the megablox Pallas kernel that ships with jax (its grid
+runs over the tiles that hold rows, so the time follows the rows routed
+here, not m); elsewhere ``lax.ragged_dot``. bfloat16 operands, float32
+accumulation.
 """
 
 from __future__ import annotations
@@ -50,7 +53,13 @@ def _moe_grouped_matmul(lhs, rhs, group_sizes, out_dtype):
                tiling=tiling)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16):
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16, *,
+                   rest_unread: bool = False):
+    """``rest_unread`` (static) is the caller's word that no row past
+    the groups' sum is ever read: a row of the product depends on that
+    row of ``lhs`` alone and the kernel's store mask keeps a shared
+    tile's foreign rows out, so whatever such a row holds (zeros off
+    the chip) reaches nothing the caller does read."""
     group_sizes = group_sizes.astype(jnp.int32)
     m = lhs.shape[0]
     if jax.default_backend() == "tpu" and m % min(TILING[0], m) == 0:
@@ -59,6 +68,8 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16):
         out = jax.lax.ragged_dot(
             lhs, rhs, group_sizes,
             preferred_element_type=jnp.float32).astype(out_dtype)
+    if rest_unread:
+        return out
     # rows of no group: the kernel leaves them unwritten
     in_group = jnp.arange(m) < jnp.sum(group_sizes)
     return jnp.where(in_group[:, None], out, jnp.zeros((), out_dtype))

@@ -32,6 +32,8 @@ TINY = {
     "grouped_narrow": [{"rows": 64, "groups": 4, "k": 24, "n": 8},
                        {"rows": 64, "groups": 4, "k": 8, "n": 24}],
     "combine": {"tokens": 48, "k": 4, "dim": 16, "passes": 4},
+    "layer_down": [{"rows": 64, "passes": 4, "groups": 4, "k": 16, "n": 24},
+                   {"rows": 96, "passes": 3, "groups": 8, "k": 8, "n": 16}],
 }
 
 
@@ -67,6 +69,10 @@ def test_kernels_leg():
     assert facts["combine_rel_l2_vs_scatter_add"] < 1e-6
     full = chip_smoke.FULL["combine"]
     assert (full["tokens"] * full["k"], full["dim"]) == (131072, 2048)
+    assert facts["layer_down_rel_l2"] == [0.0, 0.0]
+    assert [(m["rows"], m["k"], m["n"], m["rows"] // m["passes"])
+            for m in chip_smoke.FULL["layer_down"]] == [
+        (131072, 1536, 2048, 32768), (262144, 896, 2304, 32768)]
 
 
 def test_gbdt_and_fused_pipeline():

@@ -5,9 +5,14 @@ plain per-token loop in float32 numpy, in several sizes, with one pass
 and with several, with a last pass that is not full, with a padded row
 and with every pair on one expert; no scatter-add in its jaxpr. Where a
 share is held the scatter-add stays, bit for bit the form it had before
-(kept here as the plain form). The number of expert layers that combine
-by a gather, through ``TPUModel.metrics()``."""
+(kept here as the plain form), and GLM's expert layer traces to the
+jaxpr it had before. Where every expert is held the down product runs
+once a layer, after the passes, and nothing reads a row of no group.
+The number of expert layers that combine by a gather, and that run
+their down product once, through ``TPUModel.metrics()``."""
 
+import hashlib
+import json
 import os
 import sys
 
@@ -108,6 +113,88 @@ def test_every_expert_held_has_no_scatter_add(monkeypatch, name):
     assert "gather" in prims
 
 
+def _outputs(jaxpr, primitive, found=None):
+    """(shape, dtype) of every output of ``primitive`` in a jaxpr, the
+    nested ones too."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found += [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _outputs(sub, primitive, found)
+    return found
+
+
+@pytest.mark.parametrize("name", SIZES)
+def test_the_down_product_runs_once_a_layer(monkeypatch, name):
+    """Gate and up in the passes' loop, down once after it, and its
+    output is the layer's buffer: no (pairs, hidden) float32 array is
+    filled first, the loop carries (passes x rows, width) rows in the
+    input's dtype, and that buffer is not filled either (the passes
+    write all of it)."""
+    _cap(monkeypatch, name)
+    t, k, experts, dim, width, _, passes = SIZES[name]
+    u, *rest = _inputs(name, "random")
+    args = (u.astype(jnp.bfloat16), *rest)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: el.routed_experts(*a, 0, experts))(*args).jaxpr
+    loop, = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert _primitives(loop.params["body_jaxpr"].jaxpr).count(
+        "ragged_dot_general") == 2
+    assert _primitives(jaxpr).count("ragged_dot_general") == 3
+    rows = passes * el._pass_rows(t * k, experts, experts)
+    assert _outputs(jaxpr, "empty") == [((rows, width), jnp.bfloat16)]
+    assert not [shape for shape, _ in _outputs(jaxpr, "broadcast_in_dim")
+                if len(shape) == 2 and shape[0] >= t * k and shape[1] > 1]
+    # no row is selected against its group anywhere (the zeroing pass)
+    assert not [shape for shape, _ in _outputs(jaxpr, "select_n")
+                if len(shape) == 2]
+
+
+def _nan_where_no_group(real):
+    def patched(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16, **kw):
+        out = real(lhs, rhs, group_sizes, out_dtype, **kw)
+        in_group = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(in_group[:, None], out, jnp.nan)
+    return patched
+
+
+@pytest.mark.parametrize("routing", ("random", "one_expert"))
+@pytest.mark.parametrize("name", SIZES)
+def test_nothing_reads_a_row_of_no_group(monkeypatch, name, routing):
+    """What ``rest_unread`` promises ``grouped_matmul``: with NaN in
+    every row of no group (a last pass not full has them in all three
+    products) the result is finite and the same to the bit."""
+    _cap(monkeypatch, name)
+    args = _inputs(name, routing)
+    experts = args[3].shape[0]
+    fn = lambda *a: el.routed_experts(*a, 0, experts)[0]  # noqa: E731
+    want = np.asarray(jax.jit(fn)(*args))
+    monkeypatch.setattr(el, "grouped_matmul",
+                        _nan_where_no_group(el.grouped_matmul))
+    got = np.asarray(jax.jit(fn)(*args))
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, want)
+
+
+def test_rest_unread_skips_the_zeroing_pass_and_nothing_else():
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+    rng = np.random.default_rng(0)
+    lhs = rng.standard_normal((64, 16)).astype(np.float32)
+    rhs = rng.standard_normal((4, 16, 8)).astype(np.float32)
+    sizes = jnp.asarray([10, 0, 30, 8], jnp.int32)
+    kept = grouped_matmul(lhs, rhs, sizes, jnp.float32)
+    raw = grouped_matmul(lhs, rhs, sizes, jnp.float32, rest_unread=True)
+    assert np.array_equal(np.asarray(kept[:48]), np.asarray(raw[:48]))
+    assert not np.asarray(kept[48:]).any()
+    with pytest.raises(TypeError):      # keyword-only: no caller by accident
+        grouped_matmul(lhs, rhs, sizes, jnp.float32, True)
+    sel = lambda **kw: _primitives(jax.make_jaxpr(  # noqa: E731
+        lambda a, b, s: grouped_matmul(a, b, s, jnp.float32, **kw))(
+        lhs, rhs, sizes).jaxpr).count("select_n")
+    assert sel() == sel(rest_unread=True) + 1
+
+
 def _scatter_add_form(u, chosen, gates, w_gate, w_up, w_down, first, total):
     """``routed_experts`` as it was for every case before the gather
     combine, and is for a share of the experts: the plain form."""
@@ -178,6 +265,53 @@ def test_a_share_of_the_experts_keeps_its_scatter_add(held, total, first,
     assert prims == _primitives(jax.make_jaxpr(old)(*args).jaxpr)
 
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _spec(config):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        return json.load(f)["networkSpec"]
+
+
+# sha256 of str(jax.make_jaxpr(ExpertLayer(cfg).apply)) for the share
+# branch, taken at commit 375de4a (the parent of the PR that moved the
+# down product out of the passes where every expert is held) with this
+# test's own code: the tiny preset; ``glm-5.2-ep16`` at its cell's 4 x
+# 8192 tokens off the chip (``ragged_dot``) and as the chip traces it
+# (the megablox kernel), traced and never run
+_PARENT = {
+    "tiny": (latent_moe_tiny.TINY, 96, "float32", "cpu",
+             "f1bd425d9906bd45c355eb41f80af316"
+             "c8523a9ab88aaa7adc5525d471bf7cd4"),
+    "cell": ("glm-5.2-ep16", 4 * 8192, "bfloat16", "cpu",
+             "3ef63ec1f00574c732feb097b024a0ab"
+             "e2516c9b398d208bfd434c8e5465ee2a"),
+    "cell_on_the_chip": ("glm-5.2-ep16", 4 * 8192, "bfloat16", "tpu",
+                         "4a6febdfec18138fd0636bbe21312ff5"
+                         "5de8df884e342beb5423b14df1a1d2f5"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PARENT))
+def test_a_share_of_the_experts_traces_to_the_parent_s_jaxpr(monkeypatch,
+                                                             case):
+    from mmlspark_tpu.models.networks import build_network
+    spec, tokens, dtype, backend, digest = _PARENT[case]
+    if isinstance(spec, str):
+        spec = _spec(spec)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = build_network({"dtype": dtype, **spec}).cfg
+    assert not el.combines_by_gather(cfg.experts_held, cfg.experts_total)
+    layer = el.ExpertLayer(cfg)
+    u = jax.ShapeDtypeStruct((tokens, cfg.hidden_size), cfg.dtype)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), u)
+    text = str(jax.make_jaxpr(layer.apply)(params, u))
+    assert "expert_layer.py" not in text        # no line numbers in it
+    assert text.count("scatter-add") >= 1
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
 def test_the_rule_between_the_two_is_a_fact_of_the_shapes():
     assert el.combines_by_gather(64, 64)
     assert not el.combines_by_gather(16, 256)
@@ -238,3 +372,31 @@ def test_a_model_with_no_expert_layer_reads_zero_and_exports_it():
     r = PromRenderer()
     pipeline_families(r, _served(hybrid_moe_tiny.TINY), {})
     assert "serving_model_moe_gather_combines 4" in r.render()
+
+
+TINY_WIDTHS = {"vocab_size": 128, "max_len": 32, "hidden_size": 64,
+               "num_attention_heads": 8, "num_key_value_heads": 2,
+               "head_dim": 16, "sliding_window": 8, "intermediate_size": 128,
+               "moe_intermediate_size": 32, "num_experts": 16}
+GLM_WIDTHS = {k: v for k, v in latent_moe_tiny.TINY.items()
+              if k not in ("indexer_types", "mlp_layer_types")}
+# the benchmark's configurations, their layers kept and their widths cut
+CONFIGS = {
+    "lfm2-24b-a2b-stage": (TINY_WIDTHS, 8),
+    "mellum2-12b-a2.5b-stage": (TINY_WIDTHS, 8),
+    "glm-5.2-ep16": (GLM_WIDTHS, 0),
+    "gpt2-xl": ({"vocab_size": 64, "dim": 32, "depth": 2, "heads": 2,
+                 "max_len": 32, "num_classes": 4}, 0),
+}
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_metrics_carry_the_layers_whose_down_product_runs_once(config):
+    from mmlspark_tpu.core.prometheus import PromRenderer, pipeline_families
+    widths, layers = CONFIGS[config]
+    model = _served({**_spec(config), **widths})
+    assert model.metrics()["moe_layer_down_products"] == layers
+    assert model.metrics()["moe_gather_combines"] == layers
+    r = PromRenderer()
+    pipeline_families(r, model, {})
+    assert f"serving_model_moe_layer_down_products {layers}" in r.render()
