@@ -224,6 +224,14 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
     if callable(stage_metrics):
         try:
             m = stage_metrics()
+            if "rows_real" in m:
+                r.counter("serving_model_rows_real_total",
+                          "rows the model's micro-batches held",
+                          m["rows_real"], labels)
+                r.counter("serving_model_rows_bucket_total",
+                          "rows of the buckets they were padded to: "
+                          "real over bucket is how full the steps ran",
+                          m["rows_bucket"], labels)
             if "weights_cast_leaves" in m:
                 r.gauge("serving_model_weights_cast_leaves",
                         "weight leaves placed on the device in a "

@@ -732,13 +732,20 @@ REQUEST_STAGES = ("queue_wait", "collect_wait", "token_wait", "decode",
 # What a thread of the hot paths executes, by the name it has on the
 # profiler's clock (the ``/host:CPU`` plane of an xplane profile).
 HOST_PHASES = (
-    "serve.token_wait", "serve.decode", "serve.execute", "serve.respond",
+    "serve.token_wait", "serve.decode", "serve.idle", "serve.execute",
+    "serve.respond",
     "tpu_model.pad", "tpu_model.dispatch", "tpu_model.readback",
     "learner.chunk", "learner.step", "learner.flush_logs",
     "learner.checkpoint", "learner.feed_wait", "learner.final_wait")
+# Phases in which a thread waits for requests to arrive. They are kept
+# out of HOST_PHASES because such a thread is in one whenever the
+# engine is short of work: it covers every gap of the device and is the
+# cause of none, so a reader that charges gaps to host phases must not
+# charge them to these.
+ARRIVAL_WAITS = ("serve.collect",)
 # Every name ``phase`` and ``record`` are called with: the tests,
 # docs/observability.md and PERF.md enumerate this tuple.
-STAGES = REQUEST_STAGES + HOST_PHASES
+STAGES = REQUEST_STAGES + HOST_PHASES + ARRIVAL_WAITS
 
 _annotation_cls = None
 _current_phase: "contextvars.ContextVar[Optional[phase]]" = \

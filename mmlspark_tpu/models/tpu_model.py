@@ -201,6 +201,10 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # read of the outputs alone (exported through ServingEngine
         # /healthz via the duck-typed .metrics hook)
         self._hists = histogram_set("pad_ms", "device_ms", "readback_ms")
+        # rows the micro-batches held and rows of the buckets they were
+        # padded to, summed: their ratio is how full the buckets ran
+        self._rows = [0, 0]
+        self._rows_lock = threading.Lock()
 
     def _on_param_change(self, name: str) -> None:
         if name in ("weights", "modelFn"):
@@ -548,6 +552,9 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         out: Dict[str, Any] = {k: h.summary()
                                for k, h in self._hists.items()}
         out["jit_cache_misses"] = self.jit_cache_misses
+        # real rows over bucket rows is the fill: a step costs what its
+        # bucket costs whatever it holds
+        out["rows_real"], out["rows_bucket"] = self._rows
         # leaves placed narrower than held (the dtype modelFn reads
         # them in), and the bytes a call no longer reads for it
         out["weights_cast_leaves"], out["weights_cast_bytes"] = self._cast
@@ -707,32 +714,21 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # their ids through float compute dtypes
         int_input = bool(getattr(self.get("modelFn"), "int_input", False))
 
-        def _bucket(rows: int) -> int:
-            """Pad partial batches up to a power-of-two row count (capped
-            at batchSize): the jitted forward is shape-keyed, so ragged
-            batch sizes — serving micro-batches drain whatever is queued
-            — would each trigger a fresh XLA compile (seconds through a
-            remote backend). Buckets bound the distinct shapes to
-            log2(batchSize)+1 (see bucket_sizes/bucket_for); padded rows
-            are sliced off by the [:true_len] readback."""
-            b = MIN_BUCKET
-            while b < rows:
-                b *= 2
-            return min(b, batch_size)
-
         def prepare(start):
             """Host batch assembly + device_put — runs on the prefetch
             thread so transfers overlap the current batch's compute
-            (the host-bound loop VERDICT flagged in :168-190)."""
+            (the host-bound loop VERDICT flagged in :168-190). A
+            partial batch is padded up to its bucket; padded rows are
+            sliced off by the [:true_len] readback."""
             stop = min(start + batch_size, n)
             rows = stop - start
-            bucket = _bucket(rows)
+            bucket = self.bucket_for(rows)
             inputs = {}
             with phase("tpu_model.pad", hist=self._hists["pad_ms"],
-                       rows=rows):
+                       rows=rows, bucket=bucket):
                 for model_in, col_name in feeds.items():
                     inputs[model_in] = place(col_name, start, stop, bucket)
-            return rows, inputs
+            return rows, bucket, inputs
 
         def place(col_name, start, stop, bucket):
             """One feed column's rows as a padded array on the device."""
@@ -792,13 +788,16 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             self._hists["device_ms"].observe(
                 (read.end - t_dispatch) * 1e3)
 
-        def dispatch(inputs, rows):
+        def dispatch(rows, bucket, inputs):
             # traced under the mesh: kernels that XLA cannot partition
             # (ring_attention.flash_per_shard) read it
-            with phase("tpu_model.dispatch", rows=rows) as sent, \
-                    jax.set_mesh(mesh):
+            with phase("tpu_model.dispatch", rows=rows,
+                       bucket=bucket) as sent, jax.set_mesh(mesh):
                 outputs = self._compiled()(
                     self._weights_on_device(inputs), inputs)
+            with self._rows_lock:
+                self._rows[0] += rows
+                self._rows[1] += bucket
             for model_out in fetches.values():
                 if model_out not in outputs:
                     raise KeyError(
@@ -811,17 +810,17 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             # read back inline. The prefetcher buys nothing here and
             # costs a thread spawn + queue handshake per request batch
             # on accelerator backends.
-            true_len, inputs = prepare(0)
-            flush((true_len, *dispatch(inputs, true_len)))
+            rows, bucket, inputs = prepare(0)
+            flush((rows, *dispatch(rows, bucket, inputs)))
         else:
             from mmlspark_tpu.utils.prefetch import make_prefetcher
             feed = make_prefetcher(iter(range(0, n, batch_size)), prepare,
                                    depth=2)
             pending: List[Tuple[int, Dict[str, jnp.ndarray], float]] = []
             try:
-                for true_len, inputs in feed:
-                    pending.append((true_len,
-                                    *dispatch(inputs, true_len)))
+                for rows, bucket, inputs in feed:
+                    pending.append((rows,
+                                    *dispatch(rows, bucket, inputs)))
                     if len(pending) > 1:
                         # delayed-by-one readback: batch k's D2H happens
                         # while batch k+1 runs on device

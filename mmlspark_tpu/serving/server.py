@@ -915,14 +915,18 @@ class _BatchCtx:
         """Each wait before the device stage summed over the rows, in
         microseconds: what ``serve.execute`` carries into the
         profiler's file, so that a reader of it gets per-request means
-        and not per-batch ones."""
+        and not per-batch ones. And ``oldest_wait_us``, from the
+        batch's earliest ``enqueued_at`` to ``taken_at``: for that long
+        before the phase's start the engine held a request that had
+        not reached a worker."""
         enq, deq, begun = (sum(col) for col in zip(*self.stamps))
         sums = (deq - enq, begun - deq,
                 self.rows * self.granted_at - begun,
-                self.rows * (taken_at - self.dispatched_at))
+                self.rows * (taken_at - self.dispatched_at),
+                taken_at - min(stamp[0] for stamp in self.stamps))
         return {f"{name}_us": round(v * 1e6, 3) for name, v in zip(
             ("queue_wait", "collect_wait", "token_wait",
-             "dispatch_wait"), sums)}
+             "dispatch_wait", "oldest_wait"), sums)}
 
 
 class _PendingGroup:
@@ -1137,7 +1141,8 @@ class ServingEngine:
         self.hists = histogram_set("queue_wait_ms", "collect_wait_ms",
                                    "token_wait_ms", "decode_ms",
                                    "dispatch_wait_ms", "pipeline_ms",
-                                   "respond_ms", "batch_rows")
+                                   "respond_ms", "batch_rows",
+                                   "worker_idle_ms")
         self._batch_seq = itertools.count(1)
 
     # -- versioned pipeline access ------------------------------------------
@@ -1565,13 +1570,15 @@ class ServingEngine:
                     # continuous mode: absorb what is already queued
                     # (bounded poll so pending work keeps pumping),
                     # never block batch-formation on a full drain
-                    parked = self.source.drain_parked(
-                        self.batch_size, 0.0, poll_s=0.002)
+                    with phase("serve.collect"):
+                        parked = self.source.drain_parked(
+                            self.batch_size, 0.0, poll_s=0.002)
                     if parked:
                         self.source.top_up(parked, self.batch_size)
                 else:
-                    parked = self.source.drain_parked(
-                        self.batch_size, self.max_wait_ms / 1e3)
+                    with phase("serve.collect"):
+                        parked = self.source.drain_parked(
+                            self.batch_size, self.max_wait_ms / 1e3)
             except Exception as e:  # noqa: BLE001 — keep collecting
                 log.error("serving batcher error (continuing): %s", e)
                 time.sleep(0.005)
@@ -2083,12 +2090,22 @@ class ServingEngine:
         return out
 
     def _worker_loop(self):
-        while not self._stop.is_set():
-            try:
-                item = self._dispatch_q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            item[4].taken_at = time.perf_counter()
+        while True:
+            # one phase from asking for a batch to having one (the
+            # polls only keep a stopping engine responsive): while it
+            # lasts this worker starves, and ``worker_idle_ms`` over the
+            # wall is the share of its time that it does
+            item = None
+            with phase("serve.idle",
+                       hist=self.hists["worker_idle_ms"]) as idle:
+                while item is None and not self._stop.is_set():
+                    try:
+                        item = self._dispatch_q.get(timeout=0.05)
+                    except queue.Empty:
+                        pass
+            if item is None:
+                return
+            item[4].taken_at = idle.end
             try:
                 self._plan_run_ahead(item, item[4].taken_at)
                 self._execute_batch(*item)

@@ -68,7 +68,9 @@ def forced_host_device_count():
 # test name, and the new cell's own test file makes the same checks as the
 # benchmark stands: PR 34's three in tests/benchmark_cells/test_lfm2_cell.py
 # (a fourth cell), PR 36's four in test_mellum2_cell.py (a fifth, which
-# outdates three of test_lfm2_cell.py's own). The ``benchmark`` PR that
+# outdates three of test_lfm2_cell.py's own), PR 38's four in
+# test_occupancy.py (three per-layer entries that every serve cell
+# reports outdate four of test_mellum2_cell.py's own). The ``benchmark`` PR that
 # relaxes those assertions deletes this hook and
 # tests/benchmark_cells/conftest.py together (PERF.md, Open questions 0i).
 # ---------------------------------------------------------------------------
@@ -106,6 +108,23 @@ _SUPERSEDED_BENCHMARK_CASES = {
         "asserts moe_load_max_over_mean lists the GLM and LFM2 cells alone "
         "(test_mellum2_cell.py::test_the_glm_cell_reports_what_it_did "
         "makes the checks with the new cell appended)",
+    # since PR 38: three per-layer entries that every serve cell reports
+    ("test_mellum2_cell.py", "test_the_cell_and_what_it_reports"):
+        "asserts the cell reports PR 36's readers and no later one "
+        "(test_occupancy.py::test_the_mellum2_cell_and_what_it_reports "
+        "makes the checks with ISSUE 38's three added)",
+    ("test_mellum2_cell.py", "test_the_lfm2_cell_reports_what_it_did"):
+        "asserts the LFM2 cell reports PR 36's list of readers "
+        "(test_occupancy.py::test_the_lfm2_cell_reports_what_it_did makes "
+        "the checks with ISSUE 38's three added)",
+    ("test_mellum2_cell.py", "test_the_glm_cell_reports_what_it_did"):
+        "asserts the GLM cell's readers end with its own "
+        "(test_occupancy.py::test_the_glm_cell_reports_what_it_did makes "
+        "the checks with ISSUE 38's three after them)",
+    ("test_mellum2_cell.py", "test_benchmark_json_is_still_well_formed"):
+        "asserts the per-layer list ends with PR 36's entries "
+        "(test_occupancy.py::test_benchmark_json_is_still_well_formed "
+        "makes the checks with ISSUE 38's three at the end)",
 }
 
 

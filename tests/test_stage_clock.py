@@ -2,6 +2,10 @@
 request's stages tile its time in the engine, a span and the histogram
 of one stage measure one interval, a phase costs next to nothing when
 nobody listens, and no hot path times a stage the tuple does not name.
+What the engine says of its own occupancy rides the same clock: the
+worker's wait for a batch is one phase, a batch carries how long its
+oldest request had waited, the scorer's phases carry the bucket beside
+the rows.
 """
 
 import json
@@ -15,9 +19,10 @@ import pytest
 
 from mmlspark_tpu.core.metrics import LatencyHistogram
 from mmlspark_tpu.core.trace import (
-    HOST_PHASES, REQUEST_STAGES, STAGES, Tracer, phase, record,
+    ARRIVAL_WAITS, HOST_PHASES, REQUEST_STAGES, STAGES, Tracer, phase,
+    record,
 )
-from mmlspark_tpu.serving.server import serve_model
+from mmlspark_tpu.serving.server import _BatchCtx, serve_model
 from mmlspark_tpu.stages.basic import Lambda
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -265,13 +270,180 @@ def test_the_stage_tuple_is_what_the_hot_paths_use():
             r'\b(?:phase|record|_stage)\(\s*"([^"]+)"', src))
         used |= set(re.findall(r'\bspan="([^"]+)"', src))
     assert used == set(STAGES)
-    assert STAGES == REQUEST_STAGES + HOST_PHASES
+    assert STAGES == REQUEST_STAGES + HOST_PHASES + ARRIVAL_WAITS
+    # a thread that waits for arrivals covers every gap and explains
+    # none: the benchmark charges gaps to HOST_PHASES alone
+    assert "serve.idle" in HOST_PHASES and ARRIVAL_WAITS == ("serve.collect",)
     assert len(set(STAGES)) == len(STAGES)
     # ... and the documents enumerate it
     for doc in ("docs/observability.md", "PERF.md"):
         text = open(os.path.join(ROOT, doc)).read()
         missing = [s for s in STAGES if f"`{s}`" not in text]
         assert not missing, f"{doc} does not name {missing}"
+
+
+# ------------------------------------------------ what the engine says it held
+
+class _Recorded:
+    """Stands where ``TraceAnnotation`` does and keeps what a profiler
+    session would: (name, attrs, thread, start, end) of every phase."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name, attrs):
+        return _RecordedPhase(self.events, name, dict(attrs))
+
+    def named(self, name):
+        return [e for e in self.events if e[0] == name]
+
+
+class _RecordedPhase:
+    def __init__(self, events, name, attrs):
+        self.events, self.name, self.attrs = events, name, attrs
+
+    def __enter__(self):
+        self.start = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.events.append((self.name, self.attrs, threading.get_ident(),
+                            self.start, time.perf_counter()))
+
+
+@pytest.fixture()
+def recorded(monkeypatch):
+    from mmlspark_tpu.core import trace as trace_mod
+    rec = _Recorded()
+    monkeypatch.setattr(trace_mod, "_annotation", rec)
+    return rec
+
+
+def test_the_worker_lies_in_serve_idle_while_its_queue_is_empty(recorded):
+    engine = serve_model(_slow_pipeline(0.03), port=0, batch_size=4,
+                         max_wait_ms=2.0, workers=1, pipeline_depth=2,
+                         tracing=False)
+    try:
+        time.sleep(0.25)        # five polls of an empty dispatch queue
+        _post(engine.source.address, 1)
+    finally:
+        engine.stop()
+    idle, executes = recorded.named("serve.idle"), \
+        recorded.named("serve.execute")
+    assert len(executes) == 1
+    # one phase from asking to having, not one a poll; the second ends
+    # with the engine and no batch
+    assert len(idle) == 2 and idle[0][4] - idle[0][3] >= 0.2
+    # the batch's device stage starts where the wait ended, on the
+    # worker's thread, and nothing of the worker's own work is inside
+    assert idle[0][2] == executes[0][2]
+    assert idle[0][4] <= executes[0][3] + 1e-3 < idle[1][3] + 1e-3
+    snap = engine.hists["worker_idle_ms"].snapshot()
+    assert snap["count"] == 2
+    assert snap["sum"] == pytest.approx(
+        sum(e[4] - e[3] for e in idle) * 1e3, abs=2.0)
+    # the batcher's wait for arrivals is a phase of its own thread, and
+    # each is one drain: the poll or the first arrival, then max_wait_ms
+    collects = recorded.named("serve.collect")
+    assert collects and {e[2] for e in collects} != {idle[0][2]}
+    assert len({e[2] for e in collects}) == 1
+    assert max(e[4] - e[3] for e in collects) < 0.05 + 0.002 + 0.05
+
+
+def _parked(rid, enqueued_at, dequeued_at):
+    import types
+    return types.SimpleNamespace(id=rid, trace=None,
+                                 enqueued_at=enqueued_at,
+                                 dequeued_at=dequeued_at)
+
+
+def test_a_batch_carries_how_long_its_oldest_request_waited():
+    parked = [_parked("a", 10.000, 10.200), _parked("b", 10.050, 10.200),
+              _parked("c", 10.150, 10.201)]
+    tctx = _BatchCtx(7, parked, sealed_at=10.205, granted_at=10.300)
+    tctx.dispatched_at = 10.310
+    sums = tctx.wait_sums_us(10.400)
+    assert sums["oldest_wait_us"] == pytest.approx(400000.0)
+    assert sums["queue_wait_us"] == pytest.approx(401000.0)
+    assert sums["dispatch_wait_us"] == pytest.approx(3 * 90000.0)
+    assert set(sums) == {"queue_wait_us", "collect_wait_us",
+                         "token_wait_us", "dispatch_wait_us",
+                         "oldest_wait_us"}
+    # cut down to the requests that survived decode, it is the oldest
+    # of those
+    tctx.keep([1, 2], ["b", "c"])
+    assert tctx.wait_sums_us(10.400)["oldest_wait_us"] == \
+        pytest.approx(350000.0)
+
+
+def test_serve_execute_carries_the_oldest_wait_into_the_profile(recorded):
+    engine = serve_model(_slow_pipeline(0.01), port=0, batch_size=4,
+                         max_wait_ms=2.0, workers=1, pipeline_depth=2,
+                         tracing=False)
+    try:
+        _post(engine.source.address, 1)
+    finally:
+        engine.stop()
+    (_, attrs, *_), = recorded.named("serve.execute")
+    (_, _, _, decode_start, decode_end), = recorded.named("serve.decode")
+    assert attrs["rows"] == 1
+    # one request: it is the oldest, and that wait is its four waits and
+    # the decode between the third and the fourth
+    waits = sum(attrs[w + "_us"] for w in ("queue_wait", "collect_wait",
+                                           "token_wait", "dispatch_wait"))
+    decode_us = (decode_end - decode_start) * 1e6
+    assert waits < attrs["oldest_wait_us"] < waits + decode_us + 2000.0
+
+
+def test_the_scorer_says_the_bucket_and_counts_its_fill(recorded):
+    import jax
+    import numpy as np
+
+    from mmlspark_tpu.core.prometheus import PromRenderer, pipeline_families
+    from mmlspark_tpu.core.table import DataTable
+    from mmlspark_tpu.models.tpu_model import TPUModel
+    from mmlspark_tpu.parallel import mesh as mesh_lib
+    model = TPUModel.from_fn(lambda w, ins: ins["input"] * w["k"],
+                             {"k": np.float32(2.0)}, inputCol="x",
+                             outputCol="y", batchSize=16)
+    # one device: the CI mesh of 8 would pad every batch to 8 anyway
+    model.set_mesh(mesh_lib.make_mesh({"data": 1},
+                                      devices=[jax.devices()[0]]))
+    assert (model.metrics()["rows_real"], model.metrics()["rows_bucket"]) \
+        == (0, 0)
+    x = np.arange(37 * 3, dtype=np.float32).reshape(37, 3)
+    out = model.transform(DataTable({"x": x}))["y"]     # 16, 16 and 5 rows
+    assert np.array_equal(out, 2 * x)
+    model.transform(DataTable({"x": x[:3]}))            # the serving path
+    model.transform(DataTable({"x": x[:11]}))
+    want = [(16, 16), (16, 16), (5, 8), (3, 8), (11, 16)]
+    for name in ("tpu_model.pad", "tpu_model.dispatch"):
+        assert [(a["rows"], a["bucket"])
+                for _, a, *_ in recorded.named(name)] == want, name
+    assert all(a["bucket"] == model.bucket_for(a["rows"])
+               for _, a, *_ in recorded.named("tpu_model.dispatch"))
+    m = model.metrics()
+    assert (m["rows_real"], m["rows_bucket"]) == (51, 64)
+    r = PromRenderer()
+    pipeline_families(r, model)
+    text = r.render()
+    assert "serving_model_rows_real_total 51" in text
+    assert "serving_model_rows_bucket_total 64" in text
+
+
+def test_the_new_families_pass_the_exposition_audit():
+    from tools.check_metrics import DYNAMIC_OK, main
+    assert main() == 0
+    engine = serve_model(_slow_pipeline(0.0), port=0, batch_size=4,
+                         max_wait_ms=2.0, workers=1, tracing=False)
+    try:
+        _post(engine.source.address, 1)
+        text = engine.metrics_text()
+        names = {f"serving_{k}" for k in engine.hists}
+    finally:
+        engine.stop()
+    assert "serving_worker_idle_ms" in names
+    assert names <= set(DYNAMIC_OK["serving_{}"])
+    assert "serving_worker_idle_ms_count" in text
 
 
 def test_one_primitive():

@@ -110,7 +110,7 @@ DYNAMIC_OK: Dict[str, Tuple[str, ...]] = {
                    "serving_token_wait_ms", "serving_decode_ms",
                    "serving_dispatch_wait_ms", "serving_pipeline_ms",
                    "serving_respond_ms", "serving_batch_rows",
-                   "serving_model_warmup_ms"),
+                   "serving_worker_idle_ms", "serving_model_warmup_ms"),
     # pipeline_families: the model's own histogram hooks (TPUModel
     # pad/device/readback split)
     "serving_model_{}": ("serving_model_pad_ms",
