@@ -68,6 +68,11 @@ FULL = {
             "head_dim": 128, "window": 1024},
     "grouped_narrow": [{"rows": 4096, "groups": 8, "k": 2304, "n": 896},
                        {"rows": 4096, "groups": 8, "k": 896, "n": 2304}],
+    # gate, up and silu * up of a pass as one kernel, at both cells'
+    # widths (LFM2's 2048 -> 1536, Mellum2's 2304 -> 896), written into
+    # the second of three slices of a buffer
+    "fused_swiglu": [{"rows": 4096, "groups": 8, "k": 2048, "n": 1536},
+                     {"rows": 4096, "groups": 8, "k": 2304, "n": 896}],
     # the routed experts' combine where every expert is held, at the
     # LFM2 cell's shape: 32,768 tokens x 4 rows of 2048 float32 a layer
     "combine": {"tokens": 32768, "k": 4, "dim": 2048, "passes": 4},
@@ -314,8 +319,9 @@ def leg_kernels(cfg: dict) -> dict:
     blocks alone) against the masked einsum, the grouped product at
     widths the 1024 tile does not divide (1536; 2304 and 896) against a
     loop over the groups, the routed experts' gather combine against
-    the scatter-add form, and their layer-wide down product against the
-    per-pass form."""
+    the scatter-add form, their layer-wide down product against the
+    per-pass form, and a pass's gate and up products and silu * up as
+    one kernel against the float32 reference."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
@@ -346,6 +352,9 @@ def leg_kernels(cfg: dict) -> dict:
             "grouped_narrow_tiles_k_n": [
                 [_tile(m["k"], 1024), _tile(m["n"], 1024)]
                 for m in cfg["grouped_narrow"]],
+            # [into float32, into bfloat16] a shape
+            "fused_swiglu_rel_l2": [_fused_swiglu_gap(m, keys[3:5])
+                                    for m in cfg["fused_swiglu"]],
             "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"]),
             "layer_down_rel_l2": [_layer_down_gap(m)
                                   for m in cfg["layer_down"]]}
@@ -388,6 +397,13 @@ def _windowed_gap(g: dict) -> float:
     return math.sqrt(num / den)
 
 
+def _uneven_sizes(m: dict) -> np.ndarray:
+    """Group sizes in the ratio 0 : 1 : 2, repeated: every third group
+    empty, the last 8 rows or more in no group."""
+    share = np.arange(m["groups"]) % 3
+    return (share * (m["rows"] - 8) // max(1, share.sum())).astype(np.int32)
+
+
 def _grouped_gap(m: dict, keys) -> float:
     """``grouped_matmul`` over uneven groups (one empty, the last rows
     in no group) against a loop over the groups."""
@@ -397,8 +413,7 @@ def _grouped_gap(m: dict, keys) -> float:
     lhs = jax.random.normal(keys[0], (m["rows"], m["k"]), jnp.bfloat16)
     rhs = jax.random.normal(keys[1], (m["groups"], m["k"], m["n"]),
                             jnp.bfloat16) * m["k"] ** -0.5
-    share = np.arange(m["groups"]) % 3
-    sizes = (share * (m["rows"] - 8) // max(1, share.sum())).astype(np.int32)
+    sizes = _uneven_sizes(m)
     out = jax.jit(lambda a, b, s: grouped_matmul(a, b, s, jnp.float32))(
         lhs, rhs, jnp.asarray(sizes))
     want = np.zeros((m["rows"], m["n"]), np.float32)
@@ -413,6 +428,46 @@ def _grouped_gap(m: dict, keys) -> float:
     assert gm_err < BF16_REL_TOL, f"grouped product {m}: {gm_err}"
     assert not np.asarray(out[lo:]).any(), "rows of no group are not zero"
     return gm_err
+
+
+def _fused_swiglu_gap(m: dict, keys) -> list:
+    """``grouped_swiglu`` over uneven groups (one empty, the last rows
+    in no group) into the middle slice of a buffer, against
+    silu(x @ w_gate[g]) * (x @ w_up[g]) a group in float32; the other
+    slices are left as they were. Into a float32 buffer (the kernel's
+    arithmetic alone, beside ``grouped_rel_l2``) and into a bfloat16 one
+    (as ``routed_experts`` calls it: the one cast's rounding on top)."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.grouped_matmul import grouped_swiglu
+    rows, n = m["rows"], m["n"]
+    x = jax.random.normal(keys[0], (rows, m["k"]), jnp.bfloat16)
+    w_gate, w_up = (jax.random.normal(k, (m["groups"], m["k"], n),
+                                      jnp.bfloat16) * m["k"] ** -0.5
+                    for k in jax.random.split(keys[1]))
+    sizes = _uneven_sizes(m)
+    f32 = jnp.float32
+    want, lo = np.zeros((int(sizes.sum()), n), np.float32), 0
+    for e, size in enumerate(sizes):
+        rows_e = x[lo:lo + size].astype(f32)
+        want[lo:lo + size] = np.asarray(
+            jax.nn.silu(jnp.dot(rows_e, w_gate[e].astype(f32),
+                                precision=jax.lax.Precision.HIGHEST))
+            * jnp.dot(rows_e, w_up[e].astype(f32),
+                      precision=jax.lax.Precision.HIGHEST))
+        lo += size
+    gaps = []
+    for dtype in (f32, jnp.bfloat16):
+        out = np.asarray(jax.jit(grouped_swiglu)(
+            x, w_gate, w_up, jnp.asarray(sizes),
+            jnp.full((3 * rows, n), 7, dtype), jnp.int32(rows)).astype(f32))
+        gaps.append(float(np.linalg.norm(out[rows:rows + lo] - want)
+                          / np.linalg.norm(want)))
+        assert (out[:rows] == 7).all() and (out[2 * rows:] == 7).all(), \
+            "a slice of the buffer that is not the pass's was written"
+    assert gaps[0] < 1e-5 and gaps[1] < BF16_REL_TOL, \
+        f"fused gate, up and silu * up {m}: {gaps}"
+    return gaps
 
 
 def _combine_gap(c: dict) -> float:

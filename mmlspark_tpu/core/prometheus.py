@@ -252,6 +252,12 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "layer over every pair and writes the layer's "
                         "buffer itself: every expert is on this chip",
                         m["moe_layer_down_products"], labels)
+            if "moe_fused_swiglu_layers" in m:
+                r.gauge("serving_model_moe_fused_swiglu_layers",
+                        "expert layers whose passes run gate, up and "
+                        "silu * up as one grouped kernel that writes "
+                        "the layer's buffer: every expert is on this "
+                        "chip", m["moe_fused_swiglu_layers"], labels)
             if "flash_window_blocks" in m:
                 r.gauge("serving_model_flash_window_blocks",
                         "key fetch blocks a (row, head) of a sliding-"

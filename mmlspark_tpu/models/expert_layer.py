@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax import lax
 
-from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+from mmlspark_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 
 _F32 = jnp.float32
 
@@ -50,14 +50,18 @@ _F32 = jnp.float32
 # so no token is dropped
 PASS_SHARE = 1.25
 # ... and no more rows than this, whatever the share: a pass holds its
-# rows' gathered input and the float32 gate and up products, which at a
-# chip that holds every expert would be every pair of the step at once
-# (131,072 rows at LFM2's widths: 2.1 GB). Where a share is held the
-# down product's float32 rows are a pass's too. Where every expert is,
-# a pass leaves its rows of silu(gate) * up in the model's dtype
-# (131,072 x 1536 bfloat16 a layer: 0.40 GB) and the down product is
-# no pass's: one call over every pair, whose float32 output (131,072 x
-# 2048: 1.07 GB) the combine reads
+# rows' gathered input, which at a chip that holds every expert would
+# be every pair of the step at once (131,072 rows of 2048 at LFM2's
+# widths: 0.54 GB; 262,144 of 2304 at Mellum2's: 1.21 GB), and its
+# sorted rows cover ``rows / pairs`` of the experts, whose weight
+# blocks the grouped kernels fetch once a pass. Where a share is held
+# the float32 gate, up and down products are a pass's too (three
+# ``grouped_matmul``). Where every expert is, gate and up never leave
+# the kernel that multiplies them out (``grouped_swiglu``): a pass
+# writes its rows of silu(gate) * up in the model's dtype into the
+# layer's buffer (131,072 x 1536 bfloat16: 0.40 GB) and the down
+# product is no pass's: one call over every pair, whose float32 output
+# (131,072 x 2048: 1.07 GB) the combine reads
 PASS_ROWS_MAX = 32768
 
 
@@ -73,9 +77,10 @@ def combines_by_gather(held: int, total: int) -> bool:
 
 def gather_combines(cfg, expert_layers: int) -> int:
     """How many of a module's ``expert_layers`` combine by the gather
-    (and run their down product once a layer, the same branch): what
-    the families expose as ``moe_gather_combines`` and
-    ``moe_layer_down_products``, and ``TPUModel.metrics()`` carries."""
+    (and run their down product once a layer and a pass's gate, up and
+    silu * up as one kernel, the same branch): what the families expose
+    as ``moe_gather_combines``, ``moe_layer_down_products`` and
+    ``moe_fused_swiglu_layers``, and ``TPUModel.metrics()`` carries."""
     every = combines_by_gather(cfg.experts_held, cfg.experts_total)
     return expert_layers if every else 0
 
@@ -233,17 +238,17 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
         x = u[tok]
         # the grouped products apart from the sort, gather, scaling and
         # combine around them (``moe_dispatch_share`` reads the rest).
-        # ``rest_unread``: where every expert is held a row of no group
-        # (past the pairs, in a last pass not full) feeds only the same
-        # row of the down product, which is of no group either
+        # Where every expert is held, gate, up and silu * up are one
+        # call that writes its rows into slice ``lo`` of the buffer; a
+        # row of no group (past the pairs, in a last pass not full) is
+        # left as found: it feeds only the same row of the down
+        # product, which is of no group either
         with jax.named_scope("moe_grouped"):
-            h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32,
-                                           rest_unread=every)) \
-                * grouped_matmul(x, w_up, sizes, _F32, rest_unread=every)
-            h = h.astype(u.dtype)
             if every:
-                return lax.dynamic_update_slice_in_dim(acc, h, lo, 0)
-            out = grouped_matmul(h, w_down, sizes, _F32)
+                return grouped_swiglu(x, w_gate, w_up, sizes, acc, lo)
+            h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32)) \
+                * grouped_matmul(x, w_up, sizes, _F32)
+            out = grouped_matmul(h.astype(u.dtype), w_down, sizes, _F32)
         live = (lo + jnp.arange(rows)) < n_here
         out = jnp.where(live[:, None], out * gate[:, None], 0.0)
         return acc.at[tok].add(out)

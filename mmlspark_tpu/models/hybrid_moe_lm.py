@@ -322,9 +322,11 @@ class HybridMoELM(nn.Module):
             c, max(0, len(c.layer_types) - c.num_dense_layers))
 
     # ... and those layers run their down product once a layer, not once
-    # a pass (``TPUModel.metrics()`` carries this too): one branch of
-    # ``routed_experts`` does both
+    # a pass, and a pass's gate and up products and their silu * up as
+    # one kernel (``TPUModel.metrics()`` carries both counts too): one
+    # branch of ``routed_experts`` does all three
     moe_layer_down_products = moe_gather_combines
+    moe_fused_swiglu_layers = moe_gather_combines
 
     # fetch blocks that a (row, head) of a windowed and of a causal
     # flash call visit at ``max_len`` (``TPUModel.metrics()`` carries

@@ -253,9 +253,11 @@ class LatentMoELM(nn.Module):
             c, sum(kind == "sparse" for kind in c.mlp_layer_types))
 
     # ... and those layers run their down product once a layer, not once
-    # a pass (``TPUModel.metrics()`` carries this too): one branch of
-    # ``routed_experts`` does both
+    # a pass, and a pass's gate and up products and their silu * up as
+    # one kernel (``TPUModel.metrics()`` carries both counts too): one
+    # branch of ``routed_experts`` does all three
     moe_layer_down_products = moe_gather_combines
+    moe_fused_swiglu_layers = moe_gather_combines
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,

@@ -559,12 +559,14 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # them in), and the bytes a call no longer reads for it
         out["weights_cast_leaves"], out["weights_cast_bytes"] = self._cast
         # expert layers of a wrapped module that return the experts'
-        # outputs to their tokens by a gather, and that run their down
-        # product once a layer, its output the layer's buffer
-        # (expert_layer.py): every expert is on this chip; 0 for a
-        # module that has none
+        # outputs to their tokens by a gather, that run their down
+        # product once a layer, its output the layer's buffer, and
+        # that run a pass's gate and up products and their silu * up
+        # as one kernel (expert_layer.py): every expert is on this
+        # chip; 0 for a module that has none
         module = getattr(self.get("modelFn"), "module", None)
-        for name in ("moe_gather_combines", "moe_layer_down_products"):
+        for name in ("moe_gather_combines", "moe_layer_down_products",
+                     "moe_fused_swiglu_layers"):
             out[name] = int(getattr(module, name, 0))
         # fetch blocks a (row, head) of a windowed and of a causal flash
         # call of the module visit at its longest row (hybrid_moe_lm):

@@ -13,6 +13,14 @@ TPU this is the megablox Pallas kernel that ships with jax (its grid
 runs over the tiles that hold rows, so the time follows the rows routed
 here, not m); elsewhere ``lax.ragged_dot``. bfloat16 operands, float32
 accumulation.
+
+``grouped_swiglu`` is the first half of a gated feed-forward over the
+same groups, in one kernel of this repo's own:
+
+    into[lo + r] = silu(x[r] @ w_gate[g]) * (x[r] @ w_up[g])
+
+with both products' float32 sums kept on the chip and one cast at the
+store (below).
 """
 
 from __future__ import annotations
@@ -21,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 # m, k and n tile of the kernel: 512 rows amortise an expert's weight
 # tile over the rows routed to it, 1024 x 1024 weight tiles stay under
@@ -73,3 +82,136 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16, *,
     # rows of no group: the kernel leaves them unwritten
     in_group = jnp.arange(m) < jnp.sum(group_sizes)
     return jnp.where(in_group[:, None], out, jnp.zeros((), out_dtype))
+
+
+# ``grouped_swiglu``'s tiles. The k tile is the whole of k: an expert's
+# two weight blocks then keep their block index across that expert's
+# row tiles and are fetched once a group and n tile, where a k tile of
+# half of k streams them again for every row tile. The n tile is the
+# widest ``_tile`` of n whose two double-buffered weight blocks stay
+# under SWIGLU_WEIGHT_BYTES (all of n at the served widths: 25 MB for
+# 2048 x 1536 bfloat16, 17 MB for 2304 x 896), so ``x`` is read once.
+# With the weights resident a row tile need not amortise them: 256 rows
+# halve the tiles two experts share and both visit (16 of 144 visits a
+# pass of 32,768 rows over 16 experts, where 512 rows make 16 of 80).
+# On a v5e, a pass at LFM2's widths: 2.48 ms at 256 rows, 2.50 at 128,
+# 2.69 at 512, 3.67 at 1024; at Mellum2's: 1.56, 1.59, 1.62 (PERF.md
+# section 6, PR 39)
+SWIGLU_ROWS = 256
+SWIGLU_WEIGHT_BYTES = 48 * 2 ** 20
+
+
+def _swiglu_tiles(m: int, k: int, n: int, itemsize: int):
+    """(row tile, n tile) for (m, k) rows against (k, n) weights of
+    ``itemsize`` bytes an element."""
+    tn = n
+    while 4 * k * tn * itemsize > SWIGLU_WEIGHT_BYTES and tn > 128:
+        narrower = _tile(n, tn - 128)
+        if n % narrower:        # nothing narrower divides n
+            break
+        tn = narrower
+    return min(SWIGLU_ROWS, m), tn
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def _grouped_swiglu(x, w_gate, w_up, group_sizes, into, lo, *, tiles,
+                    interpret=False):
+    """The kernel. The grid runs over the n tiles and, inside each,
+    over the row tiles that hold rows (megablox's metadata: a tile two
+    groups share is visited once for each, one after the other, and a
+    store mask keeps each visit to its own rows; tiles past the groups'
+    sum are not visited). ``into`` is aliased to the output and never
+    read: the output's row-block index is offset by ``lo // tm``, so
+    the rows land in slice ``lo`` of the buffer and every other block
+    of it is left as it was."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+    m, k = x.shape
+    n = w_gate.shape[2]
+    tm, tn = tiles
+    (offsets, group_ids, tile_ids), visits = make_group_metadata(
+        group_sizes=group_sizes, m=m, tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=w_gate.shape[0], visit_empty_groups=False)
+    first_block = (jnp.asarray(lo, jnp.int32) // tm).reshape(1)
+
+    def kernel(offsets, group_ids, tile_ids, first_block, x_ref, gate_ref,
+               up_ref, into_ref, out_ref):
+        del first_block, into_ref
+        visit = pl.program_id(1)
+        group = group_ids[visit]
+        row = tile_ids[visit] * tm + lax.broadcasted_iota(
+            jnp.int32, (tm, tn), 0)
+        mine = (row >= offsets[group]) & (row < offsets[group + 1])
+        rows = x_ref[...]
+        gate = jnp.dot(rows, gate_ref[...],
+                       preferred_element_type=jnp.float32)
+        up = jnp.dot(rows, up_ref[...], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(gate) * up).astype(out_ref.dtype)
+        # a shared tile's other rows: the neighbour's visit wrote them,
+        # or will
+        out_ref[...] = jnp.where(mine, h, out_ref[...])
+
+    def x_block(n_i, visit, offsets, group_ids, tile_ids, first_block):
+        return tile_ids[visit], 0
+
+    def weight_block(n_i, visit, offsets, group_ids, tile_ids, first_block):
+        return group_ids[visit], 0, n_i
+
+    def out_block(n_i, visit, offsets, group_ids, tile_ids, first_block):
+        return first_block[0] + tile_ids[visit], n_i
+
+    weights = pl.BlockSpec((None, k, tn), weight_block)
+    item, out_item = x.dtype.itemsize, into.dtype.itemsize
+    # the blocks twice (the pipeline's two buffers), the float32 sums
+    # and their product, and as much again for what the compiler keeps
+    vmem = 2 * (2 * (tm * k * item + 2 * k * tn * w_gate.dtype.itemsize
+                     + tm * tn * out_item) + 4 * tm * tn * 4)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(into.shape, into.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[pl.BlockSpec((tm, k), x_block), weights, weights,
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tm, tn), out_block),
+            grid=(n // tn, visits)),
+        input_output_aliases={7: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=min(max(vmem, 32 * 2 ** 20), 112 * 2 ** 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * m * k * n, transcendentals=m * n,
+            bytes_accessed=(n // tn) * m * k * item + m * n * out_item
+            + 2 * w_gate.size * w_gate.dtype.itemsize),
+        interpret=interpret,
+        name="grouped_swiglu",
+    )(offsets, group_ids, tile_ids, first_block, x, w_gate, w_up, into)
+
+
+def grouped_swiglu(x, w_gate, w_up, group_sizes, into, lo):
+    """``into`` with rows [lo, lo + m) set to silu(x @ w_gate[g]) *
+    (x @ w_up[g]) for the rows of group g: ``x`` (m, k) sorted by group,
+    ``w_gate`` and ``w_up`` (groups, k, n), ``into`` (a multiple of m,
+    n) in the dtype the rows are kept in (``x``'s where
+    ``routed_experts`` calls), ``lo`` a traced multiple of m. Operands as
+    they come, both sums, silu and the product in float32, one cast at
+    the store: what two ``grouped_matmul`` to float32 and the
+    element-wise operations after them compute, without the float32
+    rows ever leaving the chip's VMEM. Rows past the groups' sum belong
+    to no group: their rows of ``into`` hold whatever they held or the
+    kernel left there, and the caller reads none of them
+    (``rest_unread``). Off a TPU, or where the row tile does not divide
+    m: two ``lax.ragged_dot`` and a ``dynamic_update_slice``."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    m, k = x.shape
+    tiles = _swiglu_tiles(m, k, w_gate.shape[2], w_gate.dtype.itemsize)
+    if jax.default_backend() == "tpu" and m % tiles[0] == 0:
+        return _grouped_swiglu(x, w_gate, w_up, group_sizes, into, lo,
+                               tiles=tiles)
+    gate, up = (lax.ragged_dot(x, w, group_sizes,
+                               preferred_element_type=jnp.float32)
+                for w in (w_gate, w_up))
+    h = (jax.nn.silu(gate) * up).astype(into.dtype)
+    return lax.dynamic_update_slice_in_dim(into, h, lo, 0)

@@ -31,6 +31,8 @@ TINY = {
             "head_dim": 16, "window": 20},
     "grouped_narrow": [{"rows": 64, "groups": 4, "k": 24, "n": 8},
                        {"rows": 64, "groups": 4, "k": 8, "n": 24}],
+    "fused_swiglu": [{"rows": 64, "groups": 4, "k": 16, "n": 24},
+                     {"rows": 64, "groups": 4, "k": 24, "n": 8}],
     "combine": {"tokens": 48, "k": 4, "dim": 16, "passes": 4},
     "layer_down": [{"rows": 64, "passes": 4, "groups": 4, "k": 16, "n": 24},
                    {"rows": 96, "passes": 3, "groups": 8, "k": 8, "n": 16}],
@@ -66,6 +68,11 @@ def test_kernels_leg():
     assert [[_tile(m["k"], 1024), _tile(m["n"], 1024)]
             for m in chip_smoke.FULL["grouped_narrow"]] == [
         [768, 896], [896, 768]]
+    for into_f32, into_bf16 in facts["fused_swiglu_rel_l2"]:
+        assert into_f32 < 1e-5 and into_bf16 < chip_smoke.BF16_REL_TOL
+    assert [(m["rows"], m["groups"], m["k"], m["n"])
+            for m in chip_smoke.FULL["fused_swiglu"]] == [
+        (4096, 8, 2048, 1536), (4096, 8, 2304, 896)]
     assert facts["combine_rel_l2_vs_scatter_add"] < 1e-6
     full = chip_smoke.FULL["combine"]
     assert (full["tokens"] * full["k"], full["dim"]) == (131072, 2048)

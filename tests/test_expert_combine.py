@@ -8,8 +8,12 @@ share is held the scatter-add stays, bit for bit the form it had before
 (kept here as the plain form), and GLM's expert layer traces to the
 jaxpr it had before. Where every expert is held the down product runs
 once a layer, after the passes, and nothing reads a row of no group.
-The number of expert layers that combine by a gather, and that run
-their down product once, through ``TPUModel.metrics()``."""
+On a TPU a pass of that branch is one grouped call (gate, up and
+silu * up in one kernel, no float32 rows outside it), and the branch
+with the kernel interpreted in its loop equals the per-token loop. The
+number of expert layers that combine by a gather, that run their down
+product once, and whose passes are that one kernel, through
+``TPUModel.metrics()``."""
 
 import hashlib
 import json
@@ -151,6 +155,57 @@ def test_the_down_product_runs_once_a_layer(monkeypatch, name):
                 if len(shape) == 2]
 
 
+def test_on_the_chip_a_pass_is_one_grouped_call(monkeypatch):
+    """As the chip traces the branch: one Pallas call a pass (gate, up
+    and silu * up: ``grouped_swiglu``) and one after the loop (the down
+    product), no ``ragged_dot``, and outside the kernels no float32
+    (rows, width) value: the gate and up rows never reach HBM."""
+    from test_grouped_swiglu import values_outside_kernels
+    name = "four_passes"
+    _cap(monkeypatch, name)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, k, experts, dim, width, _, passes = SIZES[name]
+    u, *rest = _inputs(name, "random")
+    jaxpr = jax.make_jaxpr(lambda *a: el.routed_experts(*a, 0, experts))(
+        u.astype(jnp.bfloat16), *rest).jaxpr
+    loop, = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert _primitives(loop.params["body_jaxpr"].jaxpr).count(
+        "pallas_call") == 1
+    prims = _primitives(jaxpr)
+    assert prims.count("pallas_call") == 2
+    assert "ragged_dot_general" not in prims
+    rows = el._pass_rows(t * k, experts, experts)
+    values = values_outside_kernels(jaxpr)
+    assert ((passes * rows, width), jnp.bfloat16) in values
+    assert not [shape for shape, dtype in values
+                if dtype == jnp.float32 and shape in (
+                    (rows, width), (passes * rows, width))]
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("name", SIZES)
+def test_the_kernel_in_the_passes_equals_a_per_token_loop(monkeypatch, name,
+                                                          routing):
+    """The whole branch with the Pallas kernel, interpreted, in its
+    loop (the buffer aliased to the kernel's output, a slice a pass, a
+    last pass not full among the sizes; a row tile that divides these
+    small passes) against the per-token loop."""
+    from mmlspark_tpu.ops.grouped_matmul import _grouped_swiglu
+    _cap(monkeypatch, name)
+    monkeypatch.setattr(
+        el, "grouped_swiglu",
+        lambda x, w_gate, w_up, sizes, into, lo: _grouped_swiglu(
+            x, w_gate, w_up, sizes.astype(jnp.int32), into, lo,
+            tiles=(min(128, x.shape[0]), w_gate.shape[2]), interpret=True))
+    args = _inputs(name, routing)
+    experts = args[3].shape[0]
+    y, _ = jax.jit(lambda *a: el.routed_experts(*a, 0, experts))(*args)
+    want = _per_token_loop(*args)
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.linalg.norm(np.asarray(y) - want) \
+        < 2e-6 * np.linalg.norm(want)
+
+
 def _nan_where_no_group(real):
     def patched(lhs, rhs, group_sizes, out_dtype=jnp.bfloat16, **kw):
         out = real(lhs, rhs, group_sizes, out_dtype, **kw)
@@ -159,12 +214,24 @@ def _nan_where_no_group(real):
     return patched
 
 
+def _nan_past_the_pairs(real):
+    def patched(x, w_gate, w_up, group_sizes, into, lo):
+        out = real(x, w_gate, w_up, group_sizes, into, lo)
+        m = x.shape[0]
+        rows = lax.dynamic_slice_in_dim(out, lo, m)
+        in_group = jnp.arange(m) < jnp.sum(group_sizes)
+        return lax.dynamic_update_slice_in_dim(
+            out, jnp.where(in_group[:, None], rows, jnp.nan), lo, 0)
+    return patched
+
+
 @pytest.mark.parametrize("routing", ("random", "one_expert"))
 @pytest.mark.parametrize("name", SIZES)
 def test_nothing_reads_a_row_of_no_group(monkeypatch, name, routing):
-    """What ``rest_unread`` promises ``grouped_matmul``: with NaN in
-    every row of no group (a last pass not full has them in all three
-    products) the result is finite and the same to the bit."""
+    """What ``rest_unread`` promises ``grouped_matmul``, and what
+    ``grouped_swiglu`` leaves in a pass's rows past the pairs: with NaN
+    in every row of no group (a last pass not full has them in all
+    three products) the result is finite and the same to the bit."""
     _cap(monkeypatch, name)
     args = _inputs(name, routing)
     experts = args[3].shape[0]
@@ -172,6 +239,8 @@ def test_nothing_reads_a_row_of_no_group(monkeypatch, name, routing):
     want = np.asarray(jax.jit(fn)(*args))
     monkeypatch.setattr(el, "grouped_matmul",
                         _nan_where_no_group(el.grouped_matmul))
+    monkeypatch.setattr(el, "grouped_swiglu",
+                        _nan_past_the_pairs(el.grouped_swiglu))
     got = np.asarray(jax.jit(fn)(*args))
     assert np.isfinite(got).all()
     assert np.array_equal(got, want)
@@ -400,3 +469,18 @@ def test_metrics_carry_the_layers_whose_down_product_runs_once(config):
     r = PromRenderer()
     pipeline_families(r, model, {})
     assert f"serving_model_moe_layer_down_products {layers}" in r.render()
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_metrics_carry_the_layers_whose_passes_are_one_kernel(config):
+    """``moe_fused_swiglu_layers``: the expert layers whose passes run
+    gate, up and silu * up as ``grouped_swiglu``: the branch that
+    combines by a gather, so 8, 8, 0 and 0."""
+    from mmlspark_tpu.core.prometheus import PromRenderer, pipeline_families
+    widths, layers = CONFIGS[config]
+    model = _served({**_spec(config), **widths})
+    assert model.metrics()["moe_fused_swiglu_layers"] == layers
+    assert model.metrics()["moe_layer_down_products"] == layers
+    r = PromRenderer()
+    pipeline_families(r, model, {})
+    assert f"serving_model_moe_fused_swiglu_layers {layers}" in r.render()
