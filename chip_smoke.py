@@ -72,7 +72,11 @@ FULL = {
     # widths (LFM2's 2048 -> 1536, Mellum2's 2304 -> 896), written into
     # the second of three slices of a buffer
     "fused_swiglu": [{"rows": 4096, "groups": 8, "k": 2048, "n": 1536},
-                     {"rows": 4096, "groups": 8, "k": 2304, "n": 896}],
+                     {"rows": 4096, "groups": 8, "k": 2304, "n": 896},
+                     # a pass of Trinity-Mini's: 32,768 rows that fall
+                     # to 16 of the 128 experts (2048 -> 1024)
+                     {"rows": 32768, "groups": 128, "k": 2048, "n": 1024,
+                      "live": [48, 16]}],
     # the routed experts' combine where every expert is held, at the
     # LFM2 cell's shape: 32,768 tokens x 4 rows of 2048 float32 a layer
     "combine": {"tokens": 32768, "k": 4, "dim": 2048, "passes": 4},
@@ -80,7 +84,15 @@ FULL = {
     # the LFM2 and the Mellum2 cell's shape, against the passes' calls
     "layer_down": [
         {"rows": 131072, "passes": 4, "groups": 64, "k": 1536, "n": 2048},
-        {"rows": 262144, "passes": 8, "groups": 64, "k": 896, "n": 2304}],
+        {"rows": 262144, "passes": 8, "groups": 64, "k": 896, "n": 2304},
+        {"rows": 262144, "passes": 8, "groups": 128, "k": 1024, "n": 2048}],
+    # Trinity-Mini's shapes: a window of 2048 (three fetch blocks a
+    # query block, two of them whole) at 16 fetch blocks a side, and
+    # the down product of a layer's 262,144 pairs over 128 experts of
+    # width 1024 against plain products
+    "swa_wide": {"batch": 1, "length": 16384, "heads": 32, "kv_heads": 4,
+                 "head_dim": 128, "window": 2048},
+    "grouped_wide": {"rows": 262144, "groups": 128, "k": 1024, "n": 2048},
 }
 
 # a bf16 forward against the float32 reference, as relative L2 error of
@@ -317,8 +329,8 @@ def leg_kernels(cfg: dict) -> dict:
     query heads (no repeated copy of K and V) against the einsum on
     repeated K and V, the same under a sliding window (the band of key
     blocks alone) against the masked einsum, the grouped product at
-    widths the 1024 tile does not divide (1536; 2304 and 896) against a
-    loop over the groups, the routed experts' gather combine against
+    widths the 1024 tile does not divide (1536; 2304 and 896) and over 128
+    groups of width 1024 against a loop over the groups, the routed experts' gather combine against
     the scatter-add form, their layer-wide down product against the
     per-pass form, and a pass's gate and up products and silu * up as
     one kernel against the float32 reference."""
@@ -342,12 +354,17 @@ def leg_kernels(cfg: dict) -> dict:
     assert gqa_err < BF16_REL_TOL, f"grouped-query flash: {gqa_err}"
     swa_err = _windowed_gap(cfg["swa"])
     assert swa_err < BF16_REL_TOL, f"windowed grouped-query flash: {swa_err}"
+    wide_err = _windowed_gap(cfg["swa_wide"])
+    assert wide_err < BF16_REL_TOL, f"a window of two fetch blocks: {wide_err}"
     gm_err = _grouped_gap(cfg["grouped"], keys[3:5])
     narrow = [_grouped_gap(m, keys[3:5]) for m in cfg["grouped_narrow"]]
     m = cfg["grouped"]
     return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
             "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
             "swa_rel_l2_vs_f32": swa_err,
+            "swa_wide_rel_l2_vs_f32": wide_err,
+            "grouped_wide_rel_l2": _grouped_gap(cfg["grouped_wide"],
+                                                keys[3:5]),
             "grouped_narrow_rel_l2": narrow,
             "grouped_narrow_tiles_k_n": [
                 [_tile(m["k"], 1024), _tile(m["n"], 1024)]
@@ -399,8 +416,12 @@ def _windowed_gap(g: dict) -> float:
 
 def _uneven_sizes(m: dict) -> np.ndarray:
     """Group sizes in the ratio 0 : 1 : 2, repeated: every third group
-    empty, the last 8 rows or more in no group."""
-    share = np.arange(m["groups"]) % 3
+    empty, the last 8 rows or more in no group. With ``live`` (first,
+    count) only those groups have rows, as in a pass whose sorted rows
+    fall to a few of the experts."""
+    first, count = m.get("live", (0, m["groups"]))
+    share = np.zeros(m["groups"], np.int64)
+    share[first:first + count] = np.arange(count) % 3
     return (share * (m["rows"] - 8) // max(1, share.sum())).astype(np.int32)
 
 

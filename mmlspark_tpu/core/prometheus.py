@@ -268,6 +268,21 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "the same for a full causal layer's call: the "
                         "blocks on and under the diagonal",
                         m["flash_causal_blocks"], labels)
+            if "attn_gated_layers" in m:
+                r.gauge("serving_model_attn_gated_layers",
+                        "attention operators whose heads' outputs a "
+                        "sigmoid gate of the layer's input multiplies "
+                        "before the output projection",
+                        m["attn_gated_layers"], labels)
+                r.gauge("serving_model_rope_free_layers",
+                        "attention layers that take no rotary step: "
+                        "order reaches them through the causal mask "
+                        "and the other layers", m["rope_free_layers"],
+                        labels)
+                r.gauge("serving_model_moe_shared_experts",
+                        "shared experts that an expert layer of the "
+                        "model adds beside its routed ones",
+                        m["moe_shared_experts"], labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

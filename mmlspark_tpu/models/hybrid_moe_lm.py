@@ -12,18 +12,26 @@ newest keys (itself among them), through the same flash kernel, which
 then visits only the band of key blocks a query block needs. Each
 attention kind has a rotary table of its own where ``rope_parameters``
 names them ("default", or "yarn" with its ``attention_factor`` on cos
-and sin); ``head_dim`` is ``hidden_size / num_attention_heads`` unless
-the spec gives it. The first
+and sin, or "none": that kind's layers take no rotary step at all, and
+order reaches them through the causal mask and the other kind's
+layers); ``head_dim`` is ``hidden_size / num_attention_heads`` unless
+the spec gives it. With ``attention_output_gate`` a fifth projection of
+the same normed input, through a sigmoid, multiplies the heads' outputs
+before the output projection. The first
 ``num_dense_layers`` layers have a gated (SwiGLU) feed-forward, the
 others the expert layer of expert_layer.py (sigmoid scores and a bias
 that enters the choice only, or by ``scoring_func`` and
-``use_expert_bias`` a softmax over every expert and no bias; no shared
-expert), with every expert on this
-chip. The field names are those of the
-published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B) and
-of the ``mellum`` family (Mellum2-12B-A2.5B).
+``use_expert_bias`` a softmax over every expert and no bias;
+``num_shared_experts`` shared experts beside the routed ones), with
+every expert on this chip. The field names are those of the
+published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B), of
+the ``mellum`` family (Mellum2-12B-A2.5B) and of the ``afmoe`` family
+(Trinity-Mini).
 
     h = x + Op_i(RMSNorm(x));  x' = h + FFN_i(RMSNorm(h))
+    with ``sandwich_norms``: h = x + RMSNorm(Op_i(RMSNorm(x))), and the
+        same around FFN_i: four gains a layer
+    x_0 = embed[tokens], times sqrt(hidden_size) with ``mup_enabled``
     logits = W RMSNorm(x[last])     W the tied (vocab, hidden) embedding,
                                     or ``lm_head`` where it is not tied
 
@@ -56,6 +64,7 @@ from mmlspark_tpu.models.expert_layer import (
 Dtype = Any
 OPERATORS = ("conv", "full_attention", "sliding_attention")
 ATTENTION = OPERATORS[1:]
+ROPE_TYPES = ("default", "yarn", "none")
 # positions at a row's end whose chosen experts ride out of the step
 # (``routed_tail``): a choice at position t reaches the last position's
 # logits through the later layers' convolutions, two positions a layer,
@@ -97,14 +106,26 @@ class HybridMoEConfig:
     head_dim: Optional[int] = None
     # keys a "sliding_attention" layer's query sees, itself among them
     sliding_window: int = 0
-    # {attention kind: {"rope_type": "default" | "yarn", "rope_theta",
-    # and for yarn "factor", "original_max_position_embeddings",
-    # "beta_fast", "beta_slow", "attention_factor"}}; a kind that is not
-    # named turns by ``rope_theta``, rope_type "default"
+    # {attention kind: {"rope_type": "default" | "yarn" | "none",
+    # "rope_theta", and for yarn "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "attention_factor"}}; a kind that is not named turns by
+    # ``rope_theta``, rope_type "default"; "none" takes no rotary step
     rope_parameters: Any = None
     tie_word_embeddings: bool = True
     scoring_func: str = "sigmoid"
     use_expert_bias: bool = True
+    # a fifth projection W_g of the operator's input: sigmoid(W_g u)
+    # multiplies the heads' outputs before W_o
+    attention_output_gate: bool = False
+    # a branch's output is normed before the residual add as well
+    sandwich_norms: bool = False
+    # the embedding times sqrt(hidden_size) (and drawn at variance
+    # 1 / hidden_size, so that it enters the first norm at variance 1)
+    mup_enabled: bool = False
+    # shared experts of width moe_intermediate_size beside the routed
+    # ones, added unweighted
+    num_shared_experts: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -138,10 +159,10 @@ class HybridMoEConfig:
         for kind, table in tables.items():
             table = dict(table)
             if kind not in ATTENTION or table.get(
-                    "rope_type", "default") not in ("default", "yarn"):
+                    "rope_type", "default") not in ROPE_TYPES:
                 raise ValueError(
                     f"rope_parameters[{kind!r}] = {table}: an attention "
-                    f"kind of {ATTENTION}, rope_type default or yarn")
+                    f"kind of {ATTENTION}, rope_type one of {ROPE_TYPES}")
             tables[kind] = tuple(sorted(table.items()))
         object.__setattr__(self, "rope_parameters",
                            tuple(sorted(tables.items())))
@@ -150,7 +171,7 @@ class HybridMoEConfig:
     # is on this chip (there is no all-to-all to combine a share)
     experts_total = experts_held = property(lambda self: self.num_experts)
     expert_rank = 0
-    n_shared_experts = 0
+    n_shared_experts = property(lambda self: self.num_shared_experts)
 
     def rope_for(self, kind: str) -> dict:
         """The rotary table of an attention kind's layers."""
@@ -253,7 +274,9 @@ class GroupedQueryAttention(nn.Module):
     per-head RMSNorm on q and k before the rotary step. ``kind`` is the
     layer's: "sliding_attention" holds a query to its ``sliding_window``
     newest keys (scope ``swa_attend``), and each kind turns by its own
-    rotary table (``HybridMoEConfig.rope_for``)."""
+    rotary table (``HybridMoEConfig.rope_for``) or, where that is
+    "none", not at all. With ``attention_output_gate``,
+    Op = W_o (o * sigmoid(W_g u)) (scope ``attn_gate``)."""
 
     cfg: Any
     kind: str = "full_attention"
@@ -267,24 +290,37 @@ class GroupedQueryAttention(nn.Module):
         turn = {"theta": table["rope_theta"]}
         if table["rope_type"] == "yarn":
             turn["inv"], turn["factor"] = yarn_table(c.head_dim, **table)
+
+        def turned(x):      # a kind with no table is not turned at all
+            if table["rope_type"] == "none":
+                return x
+            return rope_rotate_half(x, pos, **turn)
         dim, dt = c.hidden_size, c.dtype
         h, hk, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         w_q = self.param("q_proj", _fan_in(dim), (dim, h, d), dt)
         w_k = self.param("k_proj", _fan_in(dim), (dim, hk, d), dt)
         w_v = self.param("v_proj", _fan_in(dim), (dim, hk, d), dt)
         w_o = self.param("out_proj", _fan_in(h * d), (h, d, dim), dt)
+        if c.attention_output_gate:
+            w_g = self.param("gate_proj", _fan_in(dim), (dim, h, d), dt)
         q_norm = self.param("q_layernorm", _ones, (d,), dt)
         k_norm = self.param("k_layernorm", _ones, (d,), dt)
         pos = jnp.arange(u.shape[1])
         with jax.named_scope("gqa_project"):
             q = rms_norm(_mm("bld,dhk->blhk", u, w_q), q_norm, c.norm_eps)
             k = rms_norm(_mm("bld,dhk->blhk", u, w_k), k_norm, c.norm_eps)
-            q = rope_rotate_half(q, pos, **turn).astype(dt)
-            k = rope_rotate_half(k, pos, **turn).astype(dt)
+            q = turned(q).astype(dt)
+            k = turned(k).astype(dt)
             v = _mm("bld,dhk->blhk", u, w_v, dt)
         with jax.named_scope("swa_attend" if sliding else "gqa_attend"):
             o = attention(q, k, v, causal=True,
                           window=c.sliding_window if sliding else 0)
+        if c.attention_output_gate:
+            # the gate's projection, its sigmoid (float32) and the
+            # product with the heads' outputs (``attn_gate_share``)
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(_mm("bld,dhk->blhk", u, w_g))
+                o = (o.astype(_F32) * gate).astype(dt)
         with jax.named_scope("gqa_project"):
             return _mm("blhk,hkd->bld", o, w_o, dt)
 
@@ -293,7 +329,8 @@ class HybridMoELM(nn.Module):
     """See the module's docstring. ``cfg`` holds the sizes
     (``build_network`` makes it from the spec's keys). ``capture``:
     ``operator_<i>`` the output of layer i's operator (before the
-    residual add), ``block_<i>`` the hidden state after layer i,
+    residual add, and before its post norm where ``sandwich_norms``),
+    ``block_<i>`` the hidden state after layer i,
     ``routed_<i>`` the (b, l, k) experts an expert layer chose,
     ``final`` the normed last position."""
 
@@ -336,6 +373,27 @@ class HybridMoELM(nn.Module):
     flash_causal_blocks = property(
         lambda self: self.cfg.flash_blocks("full_attention"))
 
+    # attention operators with an output gate, attention layers that
+    # take no rotary step, and shared experts an expert layer adds
+    # (``TPUModel.metrics()`` carries them; static, from the spec)
+    @property
+    def attn_gated_layers(self) -> int:
+        c = self.cfg
+        return sum(k in ATTENTION for k in c.layer_types) \
+            if c.attention_output_gate else 0
+
+    @property
+    def rope_free_layers(self) -> int:
+        c = self.cfg
+        return sum(k in ATTENTION and c.rope_for(k)["rope_type"] == "none"
+                   for k in c.layer_types)
+
+    @property
+    def moe_shared_experts(self) -> int:
+        c = self.cfg
+        return c.num_shared_experts \
+            if len(c.layer_types) > c.num_dense_layers else 0
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  capture: Optional[str] = None):
@@ -344,9 +402,18 @@ class HybridMoELM(nn.Module):
         if l > cfg.max_len:
             raise ValueError(f"sequence {l} exceeds max_len={cfg.max_len}")
         dt, dim = cfg.dtype, cfg.hidden_size
-        embed = self.param("embed", nn.initializers.normal(1.0),
-                           (cfg.vocab_size, dim), dt)
+        embed = self.param(
+            "embed", _fan_in(dim) if cfg.mup_enabled
+            else nn.initializers.normal(1.0), (cfg.vocab_size, dim), dt)
         x = embed[tokens.astype(jnp.int32)]
+        if cfg.mup_enabled:
+            x = (x.astype(_F32) * math.sqrt(dim)).astype(dt)
+
+        def post(y, name):      # the branch's output, normed (or as is)
+            if not cfg.sandwich_norms:
+                return y
+            return rms_norm(y, self.param(name, _ones, (dim,), dt),
+                            cfg.norm_eps).astype(dt)
         held_tokens = jnp.zeros((b,), _F32)
         imbalance, passes, expert_layers = jnp.zeros((b,), _F32), 0.0, 0
         tails, attended = [], []
@@ -364,7 +431,7 @@ class HybridMoELM(nn.Module):
                 attended.append(a[:, -ATTENTION_TAIL:])
             if capture == f"operator_{i}":
                 return a
-            x = x + a
+            x = x + post(a, f"layer_{i}_operator_post_norm")
             u = rms_norm(x, self.param(f"layer_{i}_ffn_norm", _ones,
                                        (dim,), dt), cfg.norm_eps).astype(dt)
             u = u.reshape(b * l, dim)
@@ -383,7 +450,8 @@ class HybridMoELM(nn.Module):
                     by_row.mean(-1), 1.0)
                 passes += jnp.ceil(jnp.sum(load) / pass_rows)
                 expert_layers += 1
-            x = x + y.reshape(b, l, dim).astype(dt)
+            x = x + post(y.reshape(b, l, dim).astype(dt),
+                         f"layer_{i}_ffn_post_norm")
             if capture == f"block_{i}":
                 return x
         with jax.named_scope("lm_head_last"):

@@ -259,6 +259,12 @@ class LatentMoELM(nn.Module):
     moe_layer_down_products = moe_gather_combines
     moe_fused_swiglu_layers = moe_gather_combines
 
+    # shared experts an expert layer adds beside its routed ones
+    # (``TPUModel.metrics()`` carries it, as for ``hybrid_moe_lm``)
+    moe_shared_experts = property(
+        lambda self: self.cfg.n_shared_experts
+        if "sparse" in self.cfg.mlp_layer_types else 0)
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  capture: Optional[str] = None):

@@ -574,6 +574,12 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # has no such layer
         for name in ("flash_window_blocks", "flash_causal_blocks"):
             out[name] = int(getattr(module, name, 0))
+        # attention operators of the module with an output gate,
+        # attention layers that take no rotary step, and shared experts
+        # an expert layer adds (hybrid_moe_lm); 0 for a module without
+        for name in ("attn_gated_layers", "rope_free_layers",
+                     "moe_shared_experts"):
+            out[name] = int(getattr(module, name, 0))
         out["precision"] = self.get("precision")
         out["aot"] = bool(self.aot)
         if self._sharding is not None:

@@ -70,7 +70,10 @@ def forced_host_device_count():
 # (a fourth cell), PR 36's four in test_mellum2_cell.py (a fifth, which
 # outdates three of test_lfm2_cell.py's own), PR 38's four in
 # test_occupancy.py (three per-layer entries that every serve cell
-# reports outdate four of test_mellum2_cell.py's own). The ``benchmark`` PR that
+# reports outdate four of test_mellum2_cell.py's own), PR 40's six in
+# test_trinity_cell.py (a sixth cell and configuration, four more
+# per-layer entries and fifteen longer ``workloads`` lists outdate five of
+# test_occupancy.py's own). The ``benchmark`` PR that
 # relaxes those assertions deletes this hook and
 # tests/benchmark_cells/conftest.py together (PERF.md, Open questions 0i).
 # ---------------------------------------------------------------------------
@@ -125,6 +128,37 @@ _SUPERSEDED_BENCHMARK_CASES = {
         "asserts the per-layer list ends with PR 36's entries "
         "(test_occupancy.py::test_benchmark_json_is_still_well_formed "
         "makes the checks with ISSUE 38's three at the end)",
+    # since PR 40: a sixth cell, a sixth (cut) configuration, four more
+    # per-layer entries, this cell's name at the end of fifteen lists
+    ("test_benchmark_cells.py",
+     "test_config_entry_and_its_file[trinity-mini-stage]"):
+        "asserts reduced == [] and GPT-2's spec keys; this configuration "
+        "is cut in depth (test_trinity_cell.py::"
+        "test_config_entry_and_its_file_with_cuts makes the checks)",
+    ("test_occupancy.py", "test_the_three_entries_as_issue_38_asks"):
+        "asserts the three entries list PR 38's four serve cells and no "
+        "later one (test_trinity_cell.py::"
+        "test_the_three_entries_as_issue_38_asks makes the checks with "
+        "the new cell appended)",
+    ("test_occupancy.py", "test_the_mellum2_cell_and_what_it_reports"):
+        "asserts the generic readers' workloads end with the Mellum2 cell "
+        "and its flash and window readers are its alone "
+        "(test_trinity_cell.py::test_the_mellum2_cell_and_what_it_reports "
+        "makes the checks with the new cell appended)",
+    ("test_occupancy.py", "test_the_lfm2_cell_reports_what_it_did"):
+        "asserts moe_dispatch_share and the generic readers list no cell "
+        "after Mellum2's (test_trinity_cell.py::"
+        "test_the_lfm2_cell_reports_what_it_did makes the checks with the "
+        "new cell appended)",
+    ("test_occupancy.py", "test_the_glm_cell_reports_what_it_did"):
+        "asserts moe_load_max_over_mean lists three cells "
+        "(test_trinity_cell.py::test_the_glm_cell_reports_what_it_did "
+        "makes the checks with the new cell appended)",
+    ("test_occupancy.py", "test_benchmark_json_is_still_well_formed"):
+        "asserts five cells, five configurations and the per-layer list's "
+        "end as PR 38 left it (test_trinity_cell.py::"
+        "test_benchmark_json_is_still_well_formed makes the checks over "
+        "six, with ISSUE 40's four entries at the end)",
 }
 
 

@@ -2,6 +2,7 @@
 where the code has a route to them), and its refusal to run without a
 chip. The real check is ``python chip_smoke.py`` on a TPU."""
 
+import numpy as np
 import pytest
 
 import chip_smoke
@@ -32,10 +33,16 @@ TINY = {
     "grouped_narrow": [{"rows": 64, "groups": 4, "k": 24, "n": 8},
                        {"rows": 64, "groups": 4, "k": 8, "n": 24}],
     "fused_swiglu": [{"rows": 64, "groups": 4, "k": 16, "n": 24},
-                     {"rows": 64, "groups": 4, "k": 24, "n": 8}],
+                     {"rows": 64, "groups": 4, "k": 24, "n": 8},
+                     {"rows": 64, "groups": 8, "k": 16, "n": 8,
+                      "live": [3, 4]}],
     "combine": {"tokens": 48, "k": 4, "dim": 16, "passes": 4},
     "layer_down": [{"rows": 64, "passes": 4, "groups": 4, "k": 16, "n": 24},
-                   {"rows": 96, "passes": 3, "groups": 8, "k": 8, "n": 16}],
+                   {"rows": 96, "passes": 3, "groups": 8, "k": 8, "n": 16},
+                   {"rows": 64, "passes": 4, "groups": 16, "k": 8, "n": 16}],
+    "swa_wide": {"batch": 1, "length": 96, "heads": 4, "kv_heads": 1,
+                 "head_dim": 16, "window": 40},
+    "grouped_wide": {"rows": 64, "groups": 16, "k": 8, "n": 16},
 }
 
 
@@ -72,14 +79,29 @@ def test_kernels_leg():
         assert into_f32 < 1e-5 and into_bf16 < chip_smoke.BF16_REL_TOL
     assert [(m["rows"], m["groups"], m["k"], m["n"])
             for m in chip_smoke.FULL["fused_swiglu"]] == [
-        (4096, 8, 2048, 1536), (4096, 8, 2304, 896)]
+        (4096, 8, 2048, 1536), (4096, 8, 2304, 896),
+        (32768, 128, 2048, 1024)]
+    # Trinity-Mini's pass: its rows fall to 16 of the 128 experts
+    sizes = chip_smoke._uneven_sizes(chip_smoke.FULL["fused_swiglu"][2])
+    assert (np.flatnonzero(sizes).min(), np.flatnonzero(sizes).max(),
+            (sizes[48:64] > 0).sum()) == (49, 62, 10)
+    assert 32768 - 8 - 16 < sizes.sum() <= 32768 - 8
     assert facts["combine_rel_l2_vs_scatter_add"] < 1e-6
     full = chip_smoke.FULL["combine"]
     assert (full["tokens"] * full["k"], full["dim"]) == (131072, 2048)
-    assert facts["layer_down_rel_l2"] == [0.0, 0.0]
+    assert facts["layer_down_rel_l2"] == [0.0, 0.0, 0.0]
     assert [(m["rows"], m["k"], m["n"], m["rows"] // m["passes"])
             for m in chip_smoke.FULL["layer_down"]] == [
-        (131072, 1536, 2048, 32768), (262144, 896, 2304, 32768)]
+        (131072, 1536, 2048, 32768), (262144, 896, 2304, 32768),
+        (262144, 1024, 2048, 32768)]
+    # a window of 2048 at 16,384 tokens, and the down product over 128
+    assert facts["swa_wide_rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
+    assert facts["grouped_wide_rel_l2"] < chip_smoke.BF16_REL_TOL
+    wide = chip_smoke.FULL["swa_wide"]
+    assert (wide["length"], wide["window"], wide["heads"],
+            wide["kv_heads"], wide["head_dim"]) == (16384, 2048, 32, 4, 128)
+    assert chip_smoke.FULL["grouped_wide"] == {
+        "rows": 262144, "groups": 128, "k": 1024, "n": 2048}
 
 
 def test_gbdt_and_fused_pipeline():
