@@ -358,6 +358,7 @@ def leg_kernels(cfg: dict) -> dict:
     assert wide_err < BF16_REL_TOL, f"a window of two fetch blocks: {wide_err}"
     gm_err = _grouped_gap(cfg["grouped"], keys[3:5])
     narrow = [_grouped_gap(m, keys[3:5]) for m in cfg["grouped_narrow"]]
+    fused = [_fused_swiglu_gap(m, keys[3:5]) for m in cfg["fused_swiglu"]]
     m = cfg["grouped"]
     return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
             "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
@@ -370,8 +371,10 @@ def leg_kernels(cfg: dict) -> dict:
                 [_tile(m["k"], 1024), _tile(m["n"], 1024)]
                 for m in cfg["grouped_narrow"]],
             # [into float32, into bfloat16] a shape
-            "fused_swiglu_rel_l2": [_fused_swiglu_gap(m, keys[3:5])
-                                    for m in cfg["fused_swiglu"]],
+            "fused_swiglu_rel_l2": [f[:2] for f in fused],
+            # values of the rows read through ids that differ from the
+            # gathered rows' (none may)
+            "fused_swiglu_ids_differ": [f[2] for f in fused],
             "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"]),
             "layer_down_rel_l2": [_layer_down_gap(m)
                                   for m in cfg["layer_down"]]}
@@ -457,12 +460,19 @@ def _fused_swiglu_gap(m: dict, keys) -> list:
     silu(x @ w_gate[g]) * (x @ w_up[g]) a group in float32; the other
     slices are left as they were. Into a float32 buffer (the kernel's
     arithmetic alone, beside ``grouped_rel_l2``) and into a bfloat16 one
-    (as ``routed_experts`` calls it: the one cast's rounding on top)."""
+    (as ``routed_experts`` calls it: the one cast's rounding on top).
+    The rows are those of a table of ``rows / 2`` tokens that shuffled
+    ids name, each token twice; the same call reading them through the
+    ids (as ``routed_experts`` calls it) has to give the bfloat16
+    buffer of the gathered rows to the bit: [float32 gap, bfloat16
+    gap, values that differ]."""
     import jax
     import jax.numpy as jnp
-    from mmlspark_tpu.ops.grouped_matmul import grouped_swiglu
+    from mmlspark_tpu.ops.grouped_matmul import grouped_swiglu, row_table
     rows, n = m["rows"], m["n"]
-    x = jax.random.normal(keys[0], (rows, m["k"]), jnp.bfloat16)
+    u = jax.random.normal(keys[0], (rows // 2, m["k"]), jnp.bfloat16)
+    tok = jax.random.permutation(jax.random.fold_in(keys[0], 1), rows) // 2
+    x = u[tok]
     w_gate, w_up = (jax.random.normal(k, (m["groups"], m["k"], n),
                                       jnp.bfloat16) * m["k"] ** -0.5
                     for k in jax.random.split(keys[1]))
@@ -479,7 +489,8 @@ def _fused_swiglu_gap(m: dict, keys) -> list:
         lo += size
     gaps = []
     for dtype in (f32, jnp.bfloat16):
-        out = np.asarray(jax.jit(grouped_swiglu)(
+        out = np.asarray(jax.jit(lambda x, *a: grouped_swiglu(
+            row_table(x, rows), *a, tok=jnp.arange(rows, dtype=jnp.int32)))(
             x, w_gate, w_up, jnp.asarray(sizes),
             jnp.full((3 * rows, n), 7, dtype), jnp.int32(rows)).astype(f32))
         gaps.append(float(np.linalg.norm(out[rows:rows + lo] - want)
@@ -488,7 +499,13 @@ def _fused_swiglu_gap(m: dict, keys) -> list:
             "a slice of the buffer that is not the pass's was written"
     assert gaps[0] < 1e-5 and gaps[1] < BF16_REL_TOL, \
         f"fused gate, up and silu * up {m}: {gaps}"
-    return gaps
+    through_ids = jax.jit(lambda u, tok, *a: grouped_swiglu(
+        row_table(u, rows), *a, tok=tok))(
+        u, tok, w_gate, w_up, jnp.asarray(sizes),
+        jnp.full((3 * rows, n), 7, jnp.bfloat16), jnp.int32(rows))
+    differ = int(np.sum(np.asarray(through_ids.astype(f32)) != out))
+    assert differ == 0, f"rows read through ids {m}: {differ} differ"
+    return gaps + [differ]
 
 
 def _combine_gap(c: dict) -> float:

@@ -258,6 +258,13 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "silu * up as one grouped kernel that writes "
                         "the layer's buffer: every expert is on this "
                         "chip", m["moe_fused_swiglu_layers"], labels)
+            if "moe_row_fetch_layers" in m:
+                r.gauge("serving_model_moe_row_fetch_layers",
+                        "expert layers whose passes read their rows "
+                        "through their token ids inside the grouped "
+                        "kernel, with no gathered copy of them: every "
+                        "expert is on this chip",
+                        m["moe_row_fetch_layers"], labels)
             if "flash_window_blocks" in m:
                 r.gauge("serving_model_flash_window_blocks",
                         "key fetch blocks a (row, head) of a sliding-"

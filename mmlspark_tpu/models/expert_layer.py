@@ -15,7 +15,8 @@ held, the passes are as many as the pairs routed here take, and each
 runs all three products and adds its rows into their tokens (a
 scatter-add). Where every expert is held, every pair is routed here:
 the passes are ``pairs / rows`` whatever the router chose and run what
-needs passes (the gathered input, the gate and up products), the sorted
+needs passes (the gate and up products, which read their rows through
+the token ids: no gathered input), the sorted
 order is a permutation of the pairs, so the down product runs once a
 layer over every pair and its output returns to the tokens by the
 order's inverse: a gather and a sum of k (``combines_by_gather``).
@@ -40,7 +41,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax import lax
 
-from mmlspark_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
+from mmlspark_tpu.ops.grouped_matmul import (grouped_matmul, grouped_swiglu,
+                                             row_table)
 
 _F32 = jnp.float32
 
@@ -49,15 +51,16 @@ _F32 = jnp.float32
 # a layer routed more than that here takes as many passes as it needs,
 # so no token is dropped
 PASS_SHARE = 1.25
-# ... and no more rows than this, whatever the share: a pass holds its
-# rows' gathered input, which at a chip that holds every expert would
-# be every pair of the step at once (131,072 rows of 2048 at LFM2's
-# widths: 0.54 GB; 262,144 of 2304 at Mellum2's: 1.21 GB), and its
-# sorted rows cover ``rows / pairs`` of the experts, whose weight
-# blocks the grouped kernels fetch once a pass. Where a share is held
-# the float32 gate, up and down products are a pass's too (three
-# ``grouped_matmul``). Where every expert is, gate and up never leave
-# the kernel that multiplies them out (``grouped_swiglu``): a pass
+# ... and no more rows than this, whatever the share: a pass's sorted
+# rows cover ``rows / pairs`` of the experts, whose weight blocks the
+# grouped kernels fetch once a pass. Where a share is held a pass holds
+# its rows' gathered input (a pass of every pair of a step would be
+# 131,072 rows of 2048 at LFM2's widths: 0.54 GB) and the float32
+# gate, up and down products (three ``grouped_matmul``). Where every
+# expert is, the rows are never gathered: the kernel that multiplies
+# them out (``grouped_swiglu``) reads each through its token id from a
+# copy of the layer's input in 32-bit words made once (``row_table``), and
+# gate and up never leave that kernel: a pass
 # writes its rows of silu(gate) * up in the model's dtype into the
 # layer's buffer (131,072 x 1536 bfloat16: 0.40 GB) and the down
 # product is no pass's: one call over every pair, whose float32 output
@@ -78,9 +81,11 @@ def combines_by_gather(held: int, total: int) -> bool:
 def gather_combines(cfg, expert_layers: int) -> int:
     """How many of a module's ``expert_layers`` combine by the gather
     (and run their down product once a layer and a pass's gate, up and
-    silu * up as one kernel, the same branch): what the families expose
-    as ``moe_gather_combines``, ``moe_layer_down_products`` and
-    ``moe_fused_swiglu_layers``, and ``TPUModel.metrics()`` carries."""
+    silu * up as one kernel that reads the pass's rows through their
+    token ids, the same branch): what the families expose as
+    ``moe_gather_combines``, ``moe_layer_down_products``,
+    ``moe_fused_swiglu_layers`` and ``moe_row_fetch_layers``, and
+    ``TPUModel.metrics()`` carries."""
     every = combines_by_gather(cfg.experts_held, cfg.experts_total)
     return expert_layers if every else 0
 
@@ -235,17 +240,21 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
             gate = lax.dynamic_slice_in_dim(gate_of, lo, rows)
         sizes = jnp.clip(ends - lo, 0, rows) \
             - jnp.clip(ends - load - lo, 0, rows)
-        x = u[tok]
         # the grouped products apart from the sort, gather, scaling and
         # combine around them (``moe_dispatch_share`` reads the rest).
         # Where every expert is held, gate, up and silu * up are one
-        # call that writes its rows into slice ``lo`` of the buffer; a
-        # row of no group (past the pairs, in a last pass not full) is
-        # left as found: it feeds only the same row of the down
-        # product, which is of no group either
+        # call that reads the pass's rows of ``u`` through their token
+        # ids (no gathered copy of them) and writes its rows into slice
+        # ``lo`` of the buffer; a row of no group (past the pairs, in a
+        # last pass not full: token 0's) is left as found: it feeds
+        # only the same row of the down product, which is of no group
+        # either
+        if every:
+            with jax.named_scope("moe_grouped"):
+                return grouped_swiglu(table, w_gate, w_up, sizes, acc, lo,
+                                      tok=tok)
+        x = u[tok]
         with jax.named_scope("moe_grouped"):
-            if every:
-                return grouped_swiglu(x, w_gate, w_up, sizes, acc, lo)
             h = jax.nn.silu(grouped_matmul(x, w_gate, sizes, _F32)) \
                 * grouped_matmul(x, w_up, sizes, _F32)
             out = grouped_matmul(h.astype(u.dtype), w_down, sizes, _F32)
@@ -258,8 +267,11 @@ def routed_experts(u, chosen, gates, w_gate, w_up, w_down, first: int,
     if not every:
         return lax.fori_loop(0, trips, one_pass,
                              jnp.zeros((t, u.shape[1]), _F32)), load
-    # every one of its rows is some pass's, so the buffer starts as it
+    # the rows the passes read through their ids, in the form the kernel
+    # reads them from, made once for every pass (``u`` itself off the
+    # chip). Every row of the buffer is some pass's, so it starts as it
     # is found (zeros off the chip)
+    table = row_table(u, rows)
     acc = lax.fori_loop(0, trips, one_pass, lax.empty(
         (passes * rows, w_gate.shape[2]), u.dtype))
     with jax.named_scope("moe_grouped"):

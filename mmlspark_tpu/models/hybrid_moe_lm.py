@@ -360,10 +360,12 @@ class HybridMoELM(nn.Module):
 
     # ... and those layers run their down product once a layer, not once
     # a pass, and a pass's gate and up products and their silu * up as
-    # one kernel (``TPUModel.metrics()`` carries both counts too): one
-    # branch of ``routed_experts`` does all three
+    # one kernel that reads the pass's rows through their token ids
+    # (``TPUModel.metrics()`` carries the three counts too): one branch
+    # of ``routed_experts`` does all four
     moe_layer_down_products = moe_gather_combines
     moe_fused_swiglu_layers = moe_gather_combines
+    moe_row_fetch_layers = moe_gather_combines
 
     # fetch blocks that a (row, head) of a windowed and of a causal
     # flash call visit at ``max_len`` (``TPUModel.metrics()`` carries

@@ -254,10 +254,12 @@ class LatentMoELM(nn.Module):
 
     # ... and those layers run their down product once a layer, not once
     # a pass, and a pass's gate and up products and their silu * up as
-    # one kernel (``TPUModel.metrics()`` carries both counts too): one
-    # branch of ``routed_experts`` does all three
+    # one kernel that reads the pass's rows through their token ids
+    # (``TPUModel.metrics()`` carries the three counts too): one branch
+    # of ``routed_experts`` does all four
     moe_layer_down_products = moe_gather_combines
     moe_fused_swiglu_layers = moe_gather_combines
+    moe_row_fetch_layers = moe_gather_combines
 
     # shared experts an expert layer adds beside its routed ones
     # (``TPUModel.metrics()`` carries it, as for ``hybrid_moe_lm``)

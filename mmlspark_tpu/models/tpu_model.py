@@ -562,11 +562,12 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         # outputs to their tokens by a gather, that run their down
         # product once a layer, its output the layer's buffer, and
         # that run a pass's gate and up products and their silu * up
-        # as one kernel (expert_layer.py): every expert is on this
-        # chip; 0 for a module that has none
+        # as one kernel, and whose passes read their rows through their
+        # token ids (expert_layer.py): every expert is on this chip; 0
+        # for a module that has none
         module = getattr(self.get("modelFn"), "module", None)
         for name in ("moe_gather_combines", "moe_layer_down_products",
-                     "moe_fused_swiglu_layers"):
+                     "moe_fused_swiglu_layers", "moe_row_fetch_layers"):
             out[name] = int(getattr(module, name, 0))
         # fetch blocks a (row, head) of a windowed and of a causal flash
         # call of the module visit at its longest row (hybrid_moe_lm):

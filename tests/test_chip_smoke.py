@@ -77,6 +77,8 @@ def test_kernels_leg():
         [768, 896], [896, 768]]
     for into_f32, into_bf16 in facts["fused_swiglu_rel_l2"]:
         assert into_f32 < 1e-5 and into_bf16 < chip_smoke.BF16_REL_TOL
+    # the rows read through their ids are the gathered rows, to the bit
+    assert facts["fused_swiglu_ids_differ"] == [0, 0, 0]
     assert [(m["rows"], m["groups"], m["k"], m["n"])
             for m in chip_smoke.FULL["fused_swiglu"]] == [
         (4096, 8, 2048, 1536), (4096, 8, 2304, 896),

@@ -182,6 +182,34 @@ def test_on_the_chip_a_pass_is_one_grouped_call(monkeypatch):
                     (rows, width), (passes * rows, width))]
 
 
+def test_a_module_builds_the_kernel_once_for_all_its_layers(monkeypatch):
+    """Eight expert layers of one shape, as the chip traces them: the
+    kernel's body is traced and built once (``_grouped_swiglu`` is one
+    jitted function of its shapes), not once a layer, and the step's
+    program names it once."""
+    from jax.experimental import pallas
+    from mmlspark_tpu.models.networks import build_network
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    built = []
+    real = pallas.pallas_call
+
+    def counting(*a, **kw):
+        built.append(kw.get("name"))
+        return real(*a, **kw)
+    monkeypatch.setattr(pallas, "pallas_call", counting)
+    module = build_network({"dtype": "bfloat16", **NINE_LAYERS,
+                            "max_len": 256})
+    assert module.moe_row_fetch_layers == 8
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))
+    built.clear()
+    text = str(jax.make_jaxpr(
+        lambda v, t: module.apply(v, t, mutable=["stats"]))(
+        variables, jax.ShapeDtypeStruct((2, 256), jnp.int32)))
+    assert built.count("grouped_swiglu") == 1
+    assert text.count("name=grouped_swiglu") == 1
+
+
 @pytest.mark.parametrize("routing", ROUTINGS)
 @pytest.mark.parametrize("name", SIZES)
 def test_the_kernel_in_the_passes_equals_a_per_token_loop(monkeypatch, name,
@@ -190,13 +218,17 @@ def test_the_kernel_in_the_passes_equals_a_per_token_loop(monkeypatch, name,
     loop (the buffer aliased to the kernel's output, a slice a pass, a
     last pass not full among the sizes; a row tile that divides these
     small passes) against the per-token loop."""
-    from mmlspark_tpu.ops.grouped_matmul import _grouped_swiglu
+    from mmlspark_tpu.ops.grouped_matmul import _grouped_swiglu, _table
     _cap(monkeypatch, name)
+    # the passes read their rows through ids from the table the chip
+    # reads them from
+    monkeypatch.setattr(el, "row_table", lambda u, rows: _table(u))
     monkeypatch.setattr(
         el, "grouped_swiglu",
-        lambda x, w_gate, w_up, sizes, into, lo: _grouped_swiglu(
-            x, w_gate, w_up, sizes.astype(jnp.int32), into, lo,
-            tiles=(min(128, x.shape[0]), w_gate.shape[2]), interpret=True))
+        lambda table, w_gate, w_up, sizes, into, lo, tok: _grouped_swiglu(
+            table, tok, w_gate, w_up, sizes.astype(jnp.int32), into, lo,
+            tiles=(min(128, tok.shape[0]), w_gate.shape[2]),
+            interpret=True))
     args = _inputs(name, routing)
     experts = args[3].shape[0]
     y, _ = jax.jit(lambda *a: el.routed_experts(*a, 0, experts))(*args)
@@ -215,9 +247,9 @@ def _nan_where_no_group(real):
 
 
 def _nan_past_the_pairs(real):
-    def patched(x, w_gate, w_up, group_sizes, into, lo):
-        out = real(x, w_gate, w_up, group_sizes, into, lo)
-        m = x.shape[0]
+    def patched(table, w_gate, w_up, group_sizes, into, lo, tok):
+        out = real(table, w_gate, w_up, group_sizes, into, lo, tok=tok)
+        m = tok.shape[0]
         rows = lax.dynamic_slice_in_dim(out, lo, m)
         in_group = jnp.arange(m) < jnp.sum(group_sizes)
         return lax.dynamic_update_slice_in_dim(
@@ -484,3 +516,21 @@ def test_metrics_carry_the_layers_whose_passes_are_one_kernel(config):
     r = PromRenderer()
     pipeline_families(r, model, {})
     assert f"serving_model_moe_fused_swiglu_layers {layers}" in r.render()
+
+
+@pytest.mark.parametrize("config,layers", [
+    ("lfm2-24b-a2b-stage", 8), ("mellum2-12b-a2.5b-stage", 8),
+    ("trinity-mini-stage", 4), ("glm-5.2-ep16", 0), ("gpt2-xl", 0)])
+def test_metrics_carry_the_layers_whose_passes_read_rows_through_ids(
+        config, layers):
+    """``moe_row_fetch_layers``: the expert layers whose passes read
+    their rows through their token ids inside ``grouped_swiglu`` (no
+    dispatch gather): every expert held, so 8, 8, 4, 0 and 0."""
+    from mmlspark_tpu.core.prometheus import PromRenderer, pipeline_families
+    widths = CONFIGS.get(config, (TINY_WIDTHS,))[0]
+    model = _served({**_spec(config), **widths})
+    assert model.metrics()["moe_row_fetch_layers"] == layers
+    assert model.metrics()["moe_fused_swiglu_layers"] == layers
+    r = PromRenderer()
+    pipeline_families(r, model, {})
+    assert f"serving_model_moe_row_fetch_layers {layers}" in r.render()

@@ -7,6 +7,8 @@ several tiles, a last pass not full (its rows past the pairs are never
 read), several n tiles, and the write landing in slice ``lo`` of the
 buffer with every other slice left as it was."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,12 +54,29 @@ def _reference(x, w_gate, w_up, sizes, dtype):
                                   ).astype(dtype).astype(jnp.float32))
 
 
+def _in_order(x, tok):
+    return jnp.arange(x.shape[0], dtype=jnp.int32) if tok is None else tok
+
+
 def _kernel(tiles):
-    return lambda *a: gm._grouped_swiglu(*a, tiles=tiles, interpret=True)
+    """The kernel at these tiles, interpreted, reading the rows of
+    ``x`` through ``tok`` (in order where none are given) from their
+    table."""
+    def run(x, w_gate, w_up, group_sizes, into, lo, tok=None):
+        return gm._grouped_swiglu(gm._table(x), _in_order(x, tok), w_gate,
+                                  w_up, group_sizes, into, lo, tiles=tiles,
+                                  interpret=True)
+    return run
+
+
+def _off_the_chip(x, w_gate, w_up, group_sizes, into, lo, tok=None):
+    tok = _in_order(x, tok)
+    return gm.grouped_swiglu(gm.row_table(x, tok.shape[0]), w_gate, w_up,
+                             group_sizes, into, lo, tok=tok)
 
 
 FORMS = {**{name: _kernel(tiles) for name, tiles in TILES.items()},
-         "off_the_chip": gm.grouped_swiglu}
+         "off_the_chip": _off_the_chip}
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -85,6 +104,109 @@ def test_rows_of_a_group_against_the_float32_reference(case, form, dtype):
     assert (got[:lo] == 7.0).all() and (got[lo + M:] == 7.0).all()
 
 
+# the pass's token ids, and the sizes each is read with: the rows of a
+# table of TOKENS tokens that ``tok`` names, against the same rows
+# gathered first (``grouped_swiglu(u[tok], ...)``)
+TOKENS = 40
+IDS = {
+    # every token once, shuffled; a tile two groups share is visited
+    # twice and its second visit finds its rows in the slot
+    "permuted": "boundary_inside_a_tile",
+    # each token in k = 2 groups, as ``routed_experts`` sorts its pairs
+    "repeated_across_groups": "a_group_over_several_tiles",
+    # ids past the pairs are token 0's (``token_of``'s padding), in a
+    # last pass not full
+    "padded_past_the_pairs": "last_pass_not_full",
+    # an empty group between two others
+    "repeated_with_an_empty_group": "an_empty_group",
+}
+
+
+def _ids(kind, sizes, seed=5):
+    rng = np.random.default_rng(seed)
+    if kind == "permuted":
+        tok = np.concatenate([rng.permutation(TOKENS),
+                              rng.permutation(TOKENS)[:M - TOKENS]])
+    else:
+        # token t's two pairs, sorted by expert as a stable sort of a
+        # random choice would leave them
+        chosen = np.stack([rng.choice(GROUPS, 2, replace=False)
+                           for _ in range(M // 2)])
+        tok = (np.argsort(chosen.reshape(-1), kind="stable") // 2) % TOKENS
+    tok = tok.astype(np.int32)
+    if kind == "padded_past_the_pairs":
+        tok[sum(sizes):] = 0
+    return jnp.asarray(tok)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("kind", IDS)
+def test_rows_read_through_ids_equal_the_gathered_rows_to_the_bit(
+        kind, form, dtype):
+    """The same kernel, its rows read through their ids from the table
+    or handed in gathered: the same rows reach the same products in the
+    same tiles, so the buffer is the same to the bit, and every slice
+    but the pass's is as it was."""
+    dtype = jnp.dtype(dtype)
+    x, w_gate, w_up = _operands(dtype, seed=4)
+    u = x[:TOKENS]
+    sizes = SIZES[IDS[kind]]
+    tok = _ids(kind, sizes)
+    filled = jnp.full((PASSES * M, N), 7.0, dtype)
+    run = functools.partial(FORMS[form], w_gate=w_gate, w_up=w_up,
+                            group_sizes=jnp.asarray(sizes, jnp.int32),
+                            into=filled, lo=jnp.int32(M))
+    got = np.asarray(run(u, tok=tok).astype(jnp.float32))
+    want = np.asarray(run(u[tok]).astype(jnp.float32))
+    assert np.array_equal(got, want)
+    pairs = sum(sizes)
+    assert np.isfinite(got[M:M + pairs]).all()
+    assert np.abs(got[M:M + pairs]).max() > 0
+    assert (got[:M] == 7.0).all() and (got[2 * M:] == 7.0).all()
+
+
+@pytest.mark.parametrize("tiles", [(16, 256), (16, 128)],
+                         ids=["one_n_tile", "two_n_tiles"])
+def test_the_copies_a_visit_ahead_are_waited_for(tiles):
+    """Under the TPU interpreter a copy runs as on the chip: started,
+    in flight, done only when waited for, and a read of a slot with a
+    copy in flight is a race. Visits that start the next visit's rows
+    into the other slot and wait for their own give the gathered rows'
+    buffer to the bit, and no race."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    x, w_gate, w_up = _operands(jnp.bfloat16, seed=7)
+    u = x[:TOKENS]
+    sizes = SIZES["boundary_inside_a_tile"]
+    tok = _ids("permuted", sizes)
+    args = (w_gate, w_up, jnp.asarray(sizes, jnp.int32),
+            jnp.full((PASSES * M, N), 7.0, jnp.bfloat16), jnp.int32(M))
+    got = gm._grouped_swiglu(
+        gm._table(u), tok, *args, tiles=tiles,
+        interpret=pltpu.InterpretParams(detect_races=True))
+    assert not interpret_pallas_call.races.races_found
+    want = _kernel(tiles)(u[tok], *args)
+    assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                          np.asarray(want.astype(jnp.float32)))
+
+
+def test_the_ids_cover_what_they_are_for():
+    for kind, case in IDS.items():
+        tok = np.asarray(_ids(kind, SIZES[case]))
+        assert tok.min() >= 0 and tok.max() < TOKENS
+    # a token repeated across groups
+    tok = np.asarray(_ids("repeated_across_groups",
+                          SIZES["a_group_over_several_tiles"]))
+    assert np.bincount(tok).max() > 1
+    tok = np.asarray(_ids("padded_past_the_pairs",
+                          SIZES["last_pass_not_full"]))
+    assert (tok[37:] == 0).all()
+    # the permuted ids reach across several row tiles of 16
+    tok = np.asarray(_ids("permuted", SIZES["boundary_inside_a_tile"]))
+    assert sorted(tok[:TOKENS]) == list(range(TOKENS))
+
+
 @pytest.mark.parametrize("form", TILES)
 def test_the_kernel_rounds_as_the_path_off_the_chip(form):
     """bfloat16 operands, float32 sums, silu and product in float32,
@@ -95,7 +217,7 @@ def test_the_kernel_rounds_as_the_path_off_the_chip(form):
     into = jnp.zeros((M, N), jnp.bfloat16)
     got, want = (np.asarray(f(x, w_gate, w_up, sizes, into, jnp.int32(0)
                               ).astype(jnp.float32))
-                 for f in (FORMS[form], gm.grouped_swiglu))
+                 for f in (FORMS[form], FORMS["off_the_chip"]))
     # the sums' order differs (the interpreter's dot against
     # ragged_dot's): a unit in the last place now and then, no more
     assert np.abs(got - want).max() <= 2 ** -7 * np.abs(want).max()
@@ -158,8 +280,9 @@ def test_off_the_chip_is_two_ragged_products_and_an_update_slice():
     x, w_gate, w_up = _operands(jnp.bfloat16)
     into = jnp.zeros((PASSES * M, N), jnp.bfloat16)
     sizes = jnp.asarray(SIZES["tiles_whole"], jnp.int32)
-    jaxpr = jax.make_jaxpr(gm.grouped_swiglu)(x, w_gate, w_up, sizes, into,
-                                              jnp.int32(M))
+    jaxpr = jax.make_jaxpr(gm.grouped_swiglu)(
+        x, w_gate, w_up, sizes, into, jnp.int32(M),
+        tok=jnp.arange(M, dtype=jnp.int32))
     prims = [e.primitive.name for e in jaxpr.jaxpr.eqns]
     assert prims.count("ragged_dot_general") == 2
     assert prims[-1] == "dynamic_update_slice"
@@ -190,6 +313,43 @@ def test_the_tile_plan_follows_the_shape(m, k, n, itemsize, tiles):
     assert 4 * k * tn * itemsize <= gm.SWIGLU_WEIGHT_BYTES
 
 
+@pytest.mark.parametrize("form", FORMS)
+def test_rows_keep_their_own_dtype(form):
+    """float32 rows against bfloat16 weights: the rows reach the
+    products as they are, on every path (rounded to the weights'
+    dtype they would miss the float32 reference by ~1e-3)."""
+    x, _, _ = _operands(jnp.float32, seed=8)
+    _, w_gate, w_up = _operands(jnp.bfloat16, seed=8)
+    sizes = SIZES["boundary_inside_a_tile"]
+    got = FORMS[form](x, w_gate, w_up, jnp.asarray(sizes, jnp.int32),
+                      jnp.zeros((M, N), jnp.float32), jnp.int32(0))
+    want = _reference(x, w_gate, w_up, sizes, jnp.float32)
+    assert np.linalg.norm(np.asarray(got) - want) \
+        <= 1e-5 * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("dtype,k", [("bfloat16", 32), ("bfloat16", 33),
+                                     ("bfloat16", 2304), ("float32", 24)])
+def test_a_row_comes_back_from_its_table_to_the_bit(dtype, k):
+    """``_table``'s 32-bit words, put side by side again by ``_rows`` (as
+    the kernel does in VMEM), are the row: a bfloat16 row in half as many
+    words (at an odd k too, whose last word has no high half), a float32
+    row as it is."""
+    dtype = jnp.dtype(dtype)
+    x = jnp.asarray(np.random.default_rng(k).standard_normal((5, k)),
+                    dtype).at[0, :4].set(
+        jnp.asarray([0.0, -0.0, np.inf, -1e-40], dtype))
+    words = gm._table(x)
+    width = -(-k // 2) if dtype == jnp.bfloat16 else k
+    assert words.shape == (5, 1, width)
+    assert words.dtype == (jnp.uint32 if dtype == jnp.bfloat16 else dtype)
+    back = gm._rows(words[:, 0, :], dtype, k)
+    assert back.dtype == dtype
+    bits = jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32
+    assert np.array_equal(np.asarray(lax.bitcast_convert_type(back, bits)),
+                          np.asarray(lax.bitcast_convert_type(x, bits)))
+
+
 def values_outside_kernels(jaxpr, found=None):
     """(shape, dtype) of every value a jaxpr's equations produce, the
     nested jaxprs' too, a Pallas kernel's own (they live in VMEM) left
@@ -206,18 +366,83 @@ def values_outside_kernels(jaxpr, found=None):
 def test_on_the_chip_it_is_one_pallas_call(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     x, w_gate, w_up = _operands(jnp.bfloat16)
+    u = x[:TOKENS]
+    tok = _ids("repeated_across_groups", SIZES["tiles_whole"])
     into = jnp.zeros((PASSES * M, N), jnp.bfloat16)
     sizes = jnp.asarray(SIZES["tiles_whole"], jnp.int32)
-    # outside the kernel no float32 (rows, width) value exists
-    assert ((M, N), jnp.float32) not in values_outside_kernels(
-        jax.make_jaxpr(lambda *a: gm.grouped_swiglu(*a))(
-            x, w_gate, w_up, sizes, into, jnp.int32(M)).jaxpr)
+
+    def through_ids(u, *rest):
+        return gm.grouped_swiglu(gm.row_table(u, M), *rest, tok=tok)
+    jaxpr = jax.make_jaxpr(through_ids)(u, w_gate, w_up, sizes, into,
+                                        jnp.int32(M)).jaxpr
+    values = values_outside_kernels(jaxpr)
+    # outside the kernel no float32 (rows, width) value exists, and
+    # the pass's rows are never gathered: no (rows, k) value, no gather
+    assert ((M, N), jnp.float32) not in values
+    assert not [shape for shape, _ in values if shape == (M, K)]
+    assert not [e for e in _walk(jaxpr) if e.primitive.name == "gather"
+                and e.invars[0].aval.shape[0] == TOKENS]
+    # the table the rows come from: the tokens' bfloat16 rows as half
+    # as many 32-bit words, a row to a unit axis of its own
+    assert ((TOKENS, 1, K // 2), jnp.uint32) in values
+    assert not [shape for shape, dtype in values
+                if dtype == jnp.float32 and TOKENS in shape]
     # (a function of its own: a trace of ``grouped_swiglu`` itself may
     # be remembered from the test above)
-    text = str(jax.make_jaxpr(lambda *a: gm.grouped_swiglu(*a))(
-        x, w_gate, w_up, sizes, into, jnp.int32(M)))
+    text = str(jax.make_jaxpr(lambda *a: through_ids(*a))(
+        u, w_gate, w_up, sizes, into, jnp.int32(M)))
     assert text.count("pallas_call") == 1
     assert "ragged_dot" not in text
-    # the buffer goes in aliased to the output, after the four
-    # scalar-prefetch operands and x and the two weights
-    assert "input_output_aliases=((7, 0),)" in text
+    # the buffer goes in aliased to the output, after the five
+    # scalar-prefetch operands (the metadata's three, the first block,
+    # the ids) and the table and the two weights
+    assert "input_output_aliases=((8, 0),)" in text
+
+
+def _primitives(jaxpr, found=None):
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _primitives(sub, found)
+    return found
+
+
+def test_sorted_rows_on_the_chip_are_read_through_ids_in_order(monkeypatch):
+    """Rows already sorted are read through ids 0, 1, ... from their
+    own table: one kernel, the same one as for rows read through a
+    pass's token ids."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x, w_gate, w_up = _operands(jnp.float32, seed=6)
+    into = jnp.zeros((PASSES * M, N), jnp.float32)
+    sizes = jnp.asarray(SIZES["boundary_inside_a_tile"], jnp.int32)
+    text = str(jax.make_jaxpr(lambda x, *a: gm.grouped_swiglu(
+        gm.row_table(x, M), *a, tok=jnp.arange(M, dtype=jnp.int32)))(
+        x, w_gate, w_up, sizes, into, jnp.int32(0)))
+    assert text.count("pallas_call") == 1 and "iota" in text
+
+
+@pytest.mark.parametrize("tiles", [(16, 256), (64, 128)])
+def test_the_kernel_body_is_the_same_size_at_any_row_tile(tiles):
+    """The copies of a visit's rows are started from a rolled loop of a
+    few copies a trip: the kernel's body does not grow with its rows."""
+    x, w_gate, w_up = _operands(jnp.bfloat16)
+    sizes = jnp.asarray(SIZES["tiles_whole"], jnp.int32)
+    into = jnp.zeros((PASSES * M, N), jnp.bfloat16)
+
+    def kernel_eqns(tiles):
+        jaxpr = jax.make_jaxpr(lambda *a: gm._grouped_swiglu(
+            *a, tiles=tiles, interpret=True))(
+            gm._table(x), jnp.arange(M, dtype=jnp.int32), w_gate, w_up,
+            sizes, into, jnp.int32(0)).jaxpr
+        call, = [e for e in _walk(jaxpr) if e.primitive.name == "pallas_call"]
+        return len(_primitives(call.params["jaxpr"]))
+    assert kernel_eqns(tiles) == kernel_eqns((8, 256))
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)

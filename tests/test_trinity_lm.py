@@ -486,7 +486,11 @@ def test_an_expert_layer_with_a_shared_expert_is_the_plain_sum():
 
 # sha256 over the sorted "path:shape:dtype" lines of the parameter tree
 # that each configuration's networkSpec built at commit baf2ee0, and of
-# the whole step's jaxpr over bfloat16 parameters and these token rows
+# the whole step's jaxpr over bfloat16 parameters and these token rows.
+# Since commit 62cb4ce the passes where every expert is held read their
+# rows through their token ids on the chip; off it the gather ``u[tok]``
+# moved into ``grouped_swiglu`` and traces to the same operations in
+# the same order, so LFM2's and Mellum2's steps are still these
 _PARENT = {
     "lfm2-24b-a2b-stage": (
         96, "65c00033378bfde169e2cf627d115c8ec5b19611bc439edd06e9c07488864162",
