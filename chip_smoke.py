@@ -93,6 +93,15 @@ FULL = {
     "swa_wide": {"batch": 1, "length": 16384, "heads": 32, "kv_heads": 4,
                  "head_dim": 128, "window": 2048},
     "grouped_wide": {"rows": 262144, "groups": 128, "k": 1024, "n": 2048},
+    # Granite-4.0-H-Micro's shapes: the chunked scan of a bucket (8 rows
+    # of 1024, 64 heads of 64, a state of 128 in one group, chunks of
+    # 256) against the recurrence, and the causal flash call at 1024
+    # tokens, 32 query heads over 8 of 64, its scale of 1/64 folded
+    # into q as 1/8 against the masked einsum at 1/64
+    "ssd": {"batch": 8, "length": 1024, "heads": 64, "head_dim": 64,
+            "state": 128, "groups": 1, "chunk": 256},
+    "gqa_scaled": {"batch": 8, "length": 1024, "heads": 32, "kv_heads": 8,
+                   "head_dim": 64, "multiplier": 1 / 64},
 }
 
 # a bf16 forward against the float32 reference, as relative L2 error of
@@ -332,8 +341,10 @@ def leg_kernels(cfg: dict) -> dict:
     widths the 1024 tile does not divide (1536; 2304 and 896) and over 128
     groups of width 1024 against a loop over the groups, the routed experts' gather combine against
     the scatter-add form, their layer-wide down product against the
-    per-pass form, and a pass's gate and up products and silu * up as
-    one kernel against the float32 reference."""
+    per-pass form, a pass's gate and up products and silu * up as
+    one kernel against the float32 reference, and a Mamba-2 layer's
+    chunked scan against the recurrence with the flash call its
+    attention layers make (a scale of their own folded into q)."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops.grouped_matmul import _tile, grouped_matmul
@@ -377,7 +388,70 @@ def leg_kernels(cfg: dict) -> dict:
             "fused_swiglu_ids_differ": [f[2] for f in fused],
             "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"]),
             "layer_down_rel_l2": [_layer_down_gap(m)
-                                  for m in cfg["layer_down"]]}
+                                  for m in cfg["layer_down"]],
+            # [y, the final states] against the recurrence
+            "ssd_rel_l2_vs_recurrence": _ssd_gap(cfg["ssd"]),
+            "gqa_scaled_rel_l2_vs_f32": _scaled_gap(cfg["gqa_scaled"])}
+
+
+def _ssd_gap(g: dict) -> list:
+    """``ssd_scan`` as the model calls it (x, B and C in bfloat16, the
+    step and the decays float32, the D skip) against the recurrence in
+    float32 at highest precision: relative L2 of y and of the final
+    states."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.ssd_scan import recurrence, ssd_scan
+    keys = jax.random.split(jax.random.PRNGKey(4), 6)
+    shape = (g["batch"], g["length"])
+    x = jax.random.normal(keys[0], shape + (g["heads"], g["head_dim"]),
+                          jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(keys[1], shape + (g["heads"],))
+                         - 4.0)
+    a = -jnp.exp(jax.random.uniform(keys[2], (g["heads"],), minval=0.0,
+                                    maxval=math.log(16.0)))
+    b, c = (jax.random.normal(kk, shape + (g["groups"], g["state"]),
+                              jnp.bfloat16) for kk in keys[3:5])
+    d = jnp.ones((g["heads"],))
+    y, final = jax.jit(lambda *v: ssd_scan(*v, g["chunk"], d))(
+        x, dt, a, b, c)
+    with jax.default_matmul_precision("highest"):
+        want, state = jax.jit(lambda *v: recurrence(*v, d))(x, dt, a, b, c)
+    return [float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+            for got, ref in ((y, want), (final, state))]
+
+
+def _scaled_gap(g: dict) -> float:
+    """The causal grouped-query flash forward with q scaled by
+    multiplier x sqrt(head_dim) (the kernel's own 1 / sqrt(head_dim)
+    makes the rest) against softmax(q k^T multiplier) v written out in
+    float32, key/value head h // (H / H_kv) for query head h."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.parallel.ring_attention import attention
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    shape = (g["batch"], g["length"])
+    q = jax.random.normal(keys[0], shape + (g["heads"], g["head_dim"]),
+                          jnp.bfloat16)
+    k, v = (jax.random.normal(kk, shape + (g["kv_heads"], g["head_dim"]),
+                              jnp.bfloat16) for kk in keys[1:3])
+    fold = g["multiplier"] * math.sqrt(g["head_dim"])
+    got = jax.jit(lambda q, k, v: attention(
+        (q.astype(jnp.float32) * fold).astype(q.dtype), k, v,
+        causal=True))(q, k, v)
+
+    def plain(q, k, v):
+        group = g["heads"] // g["kv_heads"]
+        k, v = (jnp.repeat(t.astype(jnp.float32), group, axis=2)
+                for t in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
+                       precision="highest") * g["multiplier"]
+        seen = jnp.tril(jnp.ones((g["length"], g["length"]), bool))
+        prob = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", prob, v, precision="highest")
+    want = jax.jit(plain)(q, k, v)
+    return float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                 / jnp.linalg.norm(want))
 
 
 def _windowed_gap(g: dict) -> float:

@@ -290,6 +290,19 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "shared experts that an expert layer of the "
                         "model adds beside its routed ones",
                         m["moe_shared_experts"], labels)
+            if "ssm_layers" in m:
+                r.gauge("serving_model_ssm_layers",
+                        "Mamba-2 state-space layers of the model",
+                        m["ssm_layers"], labels)
+                r.gauge("serving_model_ssm_chunks",
+                        "chunks of the state-space layers' chunked "
+                        "scan at the model's longest row",
+                        m["ssm_chunks"], labels)
+                r.gauge("serving_model_ssm_state_bytes",
+                        "bytes of a row's final scan states and conv "
+                        "tails over the state-space layers: what a "
+                        "decode step carries", m["ssm_state_bytes"],
+                        labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

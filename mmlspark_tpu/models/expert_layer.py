@@ -298,7 +298,10 @@ def _gather_combine(out_all, order, gates):
 def _pass_rows(pairs: int, held: int, total: int) -> int:
     """Rows a pass takes: ``PASS_SHARE`` of the mean number of pairs
     routed here, a multiple of 512 (the kernel's row tile), no more than
-    the pairs there are and no more than ``PASS_ROWS_MAX``."""
+    the pairs there are and no more than ``PASS_ROWS_MAX``; 0 where
+    there is no expert."""
+    if not total:
+        return 0
     want = int(pairs * held / total * PASS_SHARE)
     if want >= 512:
         return min(-(-want // 512) * 512, -(-pairs // 512) * 512,

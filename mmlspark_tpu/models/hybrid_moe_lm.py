@@ -2,7 +2,7 @@
 
 A third network family beside ``networks.Transformer`` and
 ``latent_moe_lm``: each layer's sequence operator comes from
-``layer_types``, one entry a layer, and one kind is not attention at
+``layer_types``, one entry a layer, and two kinds are not attention at
 all. "conv" is a gated short convolution (a per-channel causal filter of
 ``conv_L_cache`` taps between two gates and two projections);
 "full_attention" is grouped-query attention with a per-head RMSNorm on
@@ -17,22 +17,31 @@ order reaches them through the causal mask and the other kind's
 layers); ``head_dim`` is ``hidden_size / num_attention_heads`` unless
 the spec gives it. With ``attention_output_gate`` a fifth projection of
 the same normed input, through a sigmoid, multiplies the heads' outputs
-before the output projection. The first
+before the output projection; ``attention_qk_norm`` false drops the
+per-head norms, and ``attention_multiplier`` scales the scores in place of
+1 / sqrt(head_dim). "mamba" is a Mamba-2 (SSD) layer: a causal conv, a
+selective scan over a state of ``mamba_d_state`` a channel computed by
+the chunked algorithm of ops/ssd_scan.py, and a gated norm. The first
 ``num_dense_layers`` layers have a gated (SwiGLU) feed-forward, the
 others the expert layer of expert_layer.py (sigmoid scores and a bias
 that enters the choice only, or by ``scoring_func`` and
 ``use_expert_bias`` a softmax over every expert and no bias;
 ``num_shared_experts`` shared experts beside the routed ones), with
-every expert on this chip. The field names are those of the
-published ``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B), of
-the ``mellum`` family (Mellum2-12B-A2.5B) and of the ``afmoe`` family
-(Trinity-Mini).
+every expert on this chip; with ``num_dense_layers`` the depth there is
+no expert layer at all. The field names are those of the published
+``config.json`` of the ``lfm2_moe`` family (LFM2-24B-A2B), of the
+``mellum`` family (Mellum2-12B-A2.5B), of the ``afmoe`` family
+(Trinity-Mini) and of the ``granitemoehybrid`` family
+(Granite-4.0-H-Micro).
 
     h = x + Op_i(RMSNorm(x));  x' = h + FFN_i(RMSNorm(h))
     with ``sandwich_norms``: h = x + RMSNorm(Op_i(RMSNorm(x))), and the
         same around FFN_i: four gains a layer
-    x_0 = embed[tokens], times sqrt(hidden_size) with ``mup_enabled``
-    logits = W RMSNorm(x[last])     W the tied (vocab, hidden) embedding,
+    x_0 = embed[tokens], times ``embedding_multiplier`` where given, else
+        times sqrt(hidden_size) with ``mup_enabled``
+    with ``residual_multiplier`` m: h = x + m Op_i(...), x' = h + m FFN_i(...)
+    logits = W RMSNorm(x[last]) / logits_scaling
+                                    W the tied (vocab, hidden) embedding,
                                     or ``lm_head`` where it is not tied
 
 It is a scorer: token ids (b, l) in, float32 next-token logits of the
@@ -42,7 +51,8 @@ keys, what ``capture`` returns, the scopes and the counters.
 Parameters are held in ``dtype`` (bfloat16 behind the server). Matrix
 products take ``dtype`` operands and accumulate in float32; norms,
 rotary angles, the convolution's gates and taps, router scores, top-k
-and softmax are float32.
+and softmax are float32, and so are a Mamba-2 layer's conv, step,
+decays, states and gated norm.
 """
 
 from __future__ import annotations
@@ -62,8 +72,12 @@ from mmlspark_tpu.models.expert_layer import (
     _row_loads, gather_combines, rms_norm)
 
 Dtype = Any
-OPERATORS = ("conv", "full_attention", "sliding_attention")
-ATTENTION = OPERATORS[1:]
+OPERATORS = ("conv", "full_attention", "sliding_attention", "mamba")
+ATTENTION = OPERATORS[1:3]
+# the Mamba-2 mixer's sizes, each required where a layer is "mamba"
+MAMBA_SIZES = ("mamba_n_heads", "mamba_d_head", "mamba_d_state",
+               "mamba_n_groups", "mamba_d_conv", "mamba_expand",
+               "mamba_chunk_size")
 ROPE_TYPES = ("default", "yarn", "none")
 # positions at a row's end whose chosen experts ride out of the step
 # (``routed_tail``): a choice at position t reaches the last position's
@@ -126,6 +140,36 @@ class HybridMoEConfig:
     # shared experts of width moe_intermediate_size beside the routed
     # ones, added unweighted
     num_shared_experts: int = 0
+    # a per-head RMSNorm on queries and keys before the rotary step
+    attention_qk_norm: bool = True
+    # the scores' scale where it is not 1 / sqrt(head_dim): folded into
+    # q as attention_multiplier * sqrt(head_dim), the kernel's own
+    # 1 / sqrt(head_dim) left as it is
+    attention_multiplier: Optional[float] = None
+    # the embedding times this (mup_enabled's sqrt(hidden_size) where it
+    # is not given), drawn at variance 1 / hidden_size where either is
+    embedding_multiplier: Optional[float] = None
+    # x + residual_multiplier * branch, for both branches of a layer
+    residual_multiplier: float = 1.0
+    # the logits divided by this
+    logits_scaling: float = 1.0
+    # a "mamba" layer's Mamba-2 mixer, under the names of the published
+    # configs (granitemoehybrid): inner width mamba_expand * hidden_size
+    # in mamba_n_heads heads of mamba_d_head, a state of mamba_d_state a
+    # channel, B and C shared by the heads of each of mamba_n_groups
+    # groups, a causal conv of mamba_d_conv taps, the chunked scan at
+    # mamba_chunk_size positions; each is required where layer_types
+    # holds "mamba". The two bias keys are read as published: a bias on
+    # the conv and none on the projections is the one form built
+    mamba_n_heads: Optional[int] = None
+    mamba_d_head: Optional[int] = None
+    mamba_d_state: Optional[int] = None
+    mamba_n_groups: Optional[int] = None
+    mamba_d_conv: Optional[int] = None
+    mamba_expand: Optional[int] = None
+    mamba_chunk_size: Optional[int] = None
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -151,6 +195,20 @@ class HybridMoEConfig:
                 and self.sliding_window <= 0:
             raise ValueError("a sliding_attention layer needs "
                              "sliding_window > 0")
+        if not self.mamba_conv_bias or self.mamba_proj_bias:
+            raise ValueError("a Mamba-2 mixer is built with a bias on its "
+                             "conv and none on its projections")
+        missing = [k for k in MAMBA_SIZES if getattr(self, k) is None]
+        if "mamba" in self.layer_types and missing:
+            raise ValueError(f"a mamba layer needs {missing}")
+        if "mamba" in self.layer_types and (
+                self.mamba_n_heads * self.mamba_d_head
+                != self.mamba_expand * self.hidden_size
+                or self.mamba_n_heads % self.mamba_n_groups):
+            raise ValueError(
+                f"{self.mamba_n_heads} mamba heads of {self.mamba_d_head} "
+                f"in {self.mamba_n_groups} groups: not the inner width "
+                f"{self.mamba_expand} x {self.hidden_size} in whole groups")
         if self.scoring_func not in SCORING:
             raise ValueError(f"scoring_func {self.scoring_func!r}; one "
                              f"of {sorted(SCORING)}")
@@ -269,9 +327,98 @@ class ShortConv(nn.Module):
             return _mm("bld,de->ble", y, w_out, dt)
 
 
+def _conv_bias(fan_in: int):
+    """A conv layer's default bias: uniform within 1 / sqrt(fan in)."""
+    def init(key, shape, dtype):
+        bound = fan_in ** -0.5
+        return jax.random.uniform(key, shape, _F32, -bound,
+                                  bound).astype(dtype)
+    return init
+
+
+def _a_log(key, shape, dtype):
+    """log a for a drawn uniformly from [1, 16] (A = -a)."""
+    return jnp.log(jax.random.uniform(key, shape, _F32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+def _dt_bias(key, shape, dtype):
+    """The inverse softplus of a step drawn log-uniformly from [1e-3,
+    1e-1], so that softplus(0 + dt_bias) starts there."""
+    step = jnp.exp(jax.random.uniform(key, shape, _F32, math.log(1e-3),
+                                      math.log(1e-1)))
+    return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 (SSD) layer's operator, H heads of P channels, a state
+    of N a channel, G groups sharing B and C:
+
+        [z | xBC | dt] = W_in u
+        xBC = silu(conv(xBC) + b_conv)      causal, depthwise (short_conv)
+        [x | B | C] = xBC
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+        Op = W_out RMSNorm(y * silu(z))     a norm over each group's channels
+
+    by the chunked scan of ops/ssd_scan.py at ``mamba_chunk_size``.
+    Scopes: ``ssm_mixer`` around it all, ``ssm_conv``, ``ssm_scan`` and
+    ``ssm_gated_norm`` inside, the two projections under ``ssm_mixer``
+    alone. The conv, the step, the decays, the scan's states and sums
+    and the gated norm are float32; the scan's products take ``dtype``
+    operands."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.ops.ssd_scan import ssd_scan
+        c = self.cfg
+        dim, dt = c.hidden_size, c.dtype
+        heads, width, state, groups = (c.mamba_n_heads, c.mamba_d_head,
+                                       c.mamba_d_state, c.mamba_n_groups)
+        inner = c.mamba_expand * dim
+        channels = inner + 2 * groups * state
+        w_in = self.param("in_proj", _fan_in(dim),
+                          (dim, inner + channels + heads), dt)
+        taps = self.param("conv", _fan_in(c.mamba_d_conv),
+                          (c.mamba_d_conv, channels), dt)
+        conv_bias = self.param("conv_bias", _conv_bias(c.mamba_d_conv),
+                               (channels,), dt)
+        dt_bias = self.param("dt_bias", _dt_bias, (heads,), dt)
+        a_log = self.param("A_log", _a_log, (heads,), dt)
+        skip = self.param("D", _ones, (heads,), dt)
+        gain = self.param("norm", _ones, (inner,), dt)
+        w_out = self.param("out_proj", _fan_in(inner), (inner, dim), dt)
+        b, length = u.shape[:2]
+        with jax.named_scope("ssm_mixer"):
+            zxd = _mm("bld,de->ble", u, w_in)
+            z, xbc, step = jnp.split(zxd, [inner, inner + channels], -1)
+            with jax.named_scope("ssm_conv"):
+                xbc = jax.nn.silu(short_conv(xbc, taps)
+                                  + conv_bias.astype(_F32))
+            x, bb, cc = jnp.split(xbc, [inner, inner + groups * state], -1)
+            with jax.named_scope("ssm_scan"):
+                step = jax.nn.softplus(step + dt_bias.astype(_F32))
+                y, _ = ssd_scan(
+                    x.reshape(b, length, heads, width).astype(dt), step,
+                    -jnp.exp(a_log.astype(_F32)),
+                    bb.reshape(b, length, groups, state).astype(dt),
+                    cc.reshape(b, length, groups, state).astype(dt),
+                    c.mamba_chunk_size, skip)
+            with jax.named_scope("ssm_gated_norm"):
+                g = (y.reshape(b, length, inner) * jax.nn.silu(z)).reshape(
+                    b, length, groups, inner // groups)
+                y = rms_norm(g, gain.reshape(groups, -1),
+                             c.norm_eps).reshape(b, length, inner)
+            return _mm("ble,ed->bld", y.astype(dt), w_out, dt)
+
+
 class GroupedQueryAttention(nn.Module):
     """Causal attention, H query heads over H_kv key/value heads, a
-    per-head RMSNorm on q and k before the rotary step. ``kind`` is the
+    per-head RMSNorm on q and k before the rotary step (none without
+    ``attention_qk_norm``), scores scaled by 1 / sqrt(head_dim) or by
+    ``attention_multiplier``, folded into q. ``kind`` is the
     layer's: "sliding_attention" holds a query to its ``sliding_window``
     newest keys (scope ``swa_attend``), and each kind turns by its own
     rotary table (``HybridMoEConfig.rope_for``) or, where that is
@@ -303,13 +450,24 @@ class GroupedQueryAttention(nn.Module):
         w_o = self.param("out_proj", _fan_in(h * d), (h, d, dim), dt)
         if c.attention_output_gate:
             w_g = self.param("gate_proj", _fan_in(dim), (dim, h, d), dt)
-        q_norm = self.param("q_layernorm", _ones, (d,), dt)
-        k_norm = self.param("k_layernorm", _ones, (d,), dt)
+        q_norm = k_norm = None
+        if c.attention_qk_norm:
+            q_norm = self.param("q_layernorm", _ones, (d,), dt)
+            k_norm = self.param("k_layernorm", _ones, (d,), dt)
+
+        def projected(w, gain):
+            y = _mm("bld,dhk->blhk", u, w)
+            return y if gain is None else rms_norm(y, gain, c.norm_eps)
         pos = jnp.arange(u.shape[1])
         with jax.named_scope("gqa_project"):
-            q = rms_norm(_mm("bld,dhk->blhk", u, w_q), q_norm, c.norm_eps)
-            k = rms_norm(_mm("bld,dhk->blhk", u, w_k), k_norm, c.norm_eps)
-            q = turned(q).astype(dt)
+            q = projected(w_q, q_norm)
+            k = projected(w_k, k_norm)
+            q = turned(q)
+            if c.attention_multiplier is not None:
+                # the kernel's 1 / sqrt(d) times this is the multiplier
+                # (exact in any dtype where the ratio is a power of two)
+                q = q * (c.attention_multiplier * math.sqrt(d))
+            q = q.astype(dt)
             k = turned(k).astype(dt)
             v = _mm("bld,dhk->blhk", u, w_v, dt)
         with jax.named_scope("swa_attend" if sliding else "gqa_attend"):
@@ -332,7 +490,9 @@ class HybridMoELM(nn.Module):
     residual add, and before its post norm where ``sandwich_norms``),
     ``block_<i>`` the hidden state after layer i,
     ``routed_<i>`` the (b, l, k) experts an expert layer chose,
-    ``final`` the normed last position."""
+    ``final`` the normed last position. With ``num_dense_layers`` the
+    depth there is no expert layer at all: no ``ExpertLayer`` is built
+    and the ``moe_*`` row stats read 0."""
 
     int_input = True  # consumes token ids, not float features
     # per-row numbers that ride out with the logits (TPUModel observes
@@ -344,8 +504,13 @@ class HybridMoELM(nn.Module):
     # (a comparison with a reference has to know them: where score +
     # bias nearly tie, bfloat16 rightly chooses otherwise now and then),
     # and ``attention_tail`` (b, attention layers, ATTENTION_TAIL,
-    # hidden), every attention operator's output at those positions
-    row_outputs = ("routed_tail", "attention_tail")
+    # hidden), every attention operator's output at those positions,
+    # and where the model has Mamba-2 layers ``ssm_tail`` (b, mamba
+    # layers, ATTENTION_TAIL, hidden), every Mamba-2 operator's
+    @property
+    def row_outputs(self) -> Tuple[str, ...]:
+        return ("routed_tail", "attention_tail") \
+            + (("ssm_tail",) if self.ssm_layers else ())
 
     cfg: HybridMoEConfig = HybridMoEConfig()
 
@@ -396,6 +561,33 @@ class HybridMoELM(nn.Module):
         return c.num_shared_experts \
             if len(c.layer_types) > c.num_dense_layers else 0
 
+    # Mamba-2 layers, the chunks of the scan a row of ``max_len`` takes,
+    # and the bytes of a row's final scan states (float32) and conv tails
+    # (the d_conv - 1 last positions of the conv's float32 input) over
+    # those layers: what a decode step would carry from one token to the
+    # next (``TPUModel.metrics()`` carries the three; static, from the
+    # spec)
+    @property
+    def ssm_layers(self) -> int:
+        return sum(k == "mamba" for k in self.cfg.layer_types)
+
+    @property
+    def ssm_chunks(self) -> int:
+        c = self.cfg
+        return -(-c.max_len // c.mamba_chunk_size) if self.ssm_layers \
+            else 0
+
+    @property
+    def ssm_state_bytes(self) -> int:
+        c = self.cfg
+        if not self.ssm_layers:
+            return 0
+        channels = c.mamba_expand * c.hidden_size \
+            + 2 * c.mamba_n_groups * c.mamba_d_state
+        per_layer = 4 * (c.mamba_n_heads * c.mamba_d_head * c.mamba_d_state
+                         + (c.mamba_d_conv - 1) * channels)
+        return self.ssm_layers * per_layer
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  capture: Optional[str] = None):
@@ -404,21 +596,27 @@ class HybridMoELM(nn.Module):
         if l > cfg.max_len:
             raise ValueError(f"sequence {l} exceeds max_len={cfg.max_len}")
         dt, dim = cfg.dtype, cfg.hidden_size
+        scale = cfg.embedding_multiplier
+        if scale is None and cfg.mup_enabled:
+            scale = math.sqrt(dim)
         embed = self.param(
-            "embed", _fan_in(dim) if cfg.mup_enabled
+            "embed", _fan_in(dim) if scale is not None
             else nn.initializers.normal(1.0), (cfg.vocab_size, dim), dt)
         x = embed[tokens.astype(jnp.int32)]
-        if cfg.mup_enabled:
-            x = (x.astype(_F32) * math.sqrt(dim)).astype(dt)
+        if scale is not None:
+            x = (x.astype(_F32) * scale).astype(dt)
 
-        def post(y, name):      # the branch's output, normed (or as is)
-            if not cfg.sandwich_norms:
-                return y
-            return rms_norm(y, self.param(name, _ones, (dim,), dt),
-                            cfg.norm_eps).astype(dt)
+        def add(x, y, name):    # x + the branch's output, normed (or as
+            if cfg.sandwich_norms:      # is), times residual_multiplier
+                y = rms_norm(y, self.param(name, _ones, (dim,), dt),
+                             cfg.norm_eps).astype(dt)
+            if cfg.residual_multiplier == 1.0:
+                return x + y
+            return (x.astype(_F32) + cfg.residual_multiplier
+                    * y.astype(_F32)).astype(dt)
         held_tokens = jnp.zeros((b,), _F32)
         imbalance, passes, expert_layers = jnp.zeros((b,), _F32), 0.0, 0
-        tails, attended = [], []
+        tails, attended, mixed = [], [], []
         first = cfg.expert_rank * cfg.experts_held
         pass_rows = _pass_rows(b * l * cfg.num_experts_per_tok,
                                cfg.experts_held, cfg.num_experts)
@@ -427,13 +625,16 @@ class HybridMoELM(nn.Module):
                                        (dim,), dt), cfg.norm_eps).astype(dt)
             if kind == "conv":
                 a = ShortConv(cfg, name=f"layer_{i}_conv")(u)
+            elif kind == "mamba":
+                a = Mamba2Mixer(cfg, name=f"layer_{i}_mamba")(u)
+                mixed.append(a[:, -ATTENTION_TAIL:])
             else:
                 a = GroupedQueryAttention(cfg, kind,
                                           name=f"layer_{i}_attn")(u)
                 attended.append(a[:, -ATTENTION_TAIL:])
             if capture == f"operator_{i}":
                 return a
-            x = x + post(a, f"layer_{i}_operator_post_norm")
+            x = add(x, a, f"layer_{i}_operator_post_norm")
             u = rms_norm(x, self.param(f"layer_{i}_ffn_norm", _ones,
                                        (dim,), dt), cfg.norm_eps).astype(dt)
             u = u.reshape(b * l, dim)
@@ -452,8 +653,8 @@ class HybridMoELM(nn.Module):
                     by_row.mean(-1), 1.0)
                 passes += jnp.ceil(jnp.sum(load) / pass_rows)
                 expert_layers += 1
-            x = x + post(y.reshape(b, l, dim).astype(dt),
-                         f"layer_{i}_ffn_post_norm")
+            x = add(x, y.reshape(b, l, dim).astype(dt),
+                    f"layer_{i}_ffn_post_norm")
             if capture == f"block_{i}":
                 return x
         with jax.named_scope("lm_head_last"):
@@ -464,6 +665,8 @@ class HybridMoELM(nn.Module):
             head = embed if cfg.tie_word_embeddings else self.param(
                 "lm_head", _fan_in(dim), (cfg.vocab_size, dim), dt)
             logits = _mm("bd,vd->bv", last, head)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
         self.sow("stats", "moe_tokens_held", held_tokens)
         self.sow("stats", "moe_load_max_over_mean",
                  imbalance / max(expert_layers, 1))
@@ -474,6 +677,8 @@ class HybridMoELM(nn.Module):
                      jnp.stack(tails, axis=1).astype(jnp.int32))
         if attended:
             self.sow("stats", "attention_tail", jnp.stack(attended, axis=1))
+        if mixed:
+            self.sow("stats", "ssm_tail", jnp.stack(mixed, axis=1))
         return logits
 
     def feature_layers(self) -> List[str]:

@@ -73,7 +73,10 @@ def forced_host_device_count():
 # reports outdate four of test_mellum2_cell.py's own), PR 40's six in
 # test_trinity_cell.py (a sixth cell and configuration, four more
 # per-layer entries and fifteen longer ``workloads`` lists outdate five of
-# test_occupancy.py's own). The ``benchmark`` PR that
+# test_occupancy.py's own), and six in test_granite_cell.py (a seventh
+# cell and configuration, four more per-layer entries and eleven longer
+# ``workloads`` lists outdate five of test_trinity_cell.py's own, and the
+# uncut configuration's keys are not GPT-2's). The ``benchmark`` PR that
 # relaxes those assertions deletes this hook and
 # tests/benchmark_cells/conftest.py together (PERF.md, Open questions 0i).
 # ---------------------------------------------------------------------------
@@ -159,6 +162,35 @@ _SUPERSEDED_BENCHMARK_CASES = {
         "end as PR 38 left it (test_trinity_cell.py::"
         "test_benchmark_json_is_still_well_formed makes the checks over "
         "six, with ISSUE 40's four entries at the end)",
+    # a seventh cell and configuration, four more per-layer
+    # entries, this cell's name at the end of eleven lists
+    ("test_benchmark_cells.py",
+     "test_config_entry_and_its_file[granite-4.0-h-micro]"):
+        "asserts GPT-2's spec keys; this configuration's widths have "
+        "its family's names (test_granite_cell.py::"
+        "test_config_entry_and_its_file_with_its_own_keys makes the checks)",
+    ("test_trinity_cell.py", "test_the_cell_and_what_it_reports"):
+        "asserts the readers the Trinity cell shares end with it "
+        "(test_granite_cell.py::test_the_trinity_cell_and_what_it_reports "
+        "makes the checks with the new cell after it)",
+    ("test_trinity_cell.py", "test_the_three_entries_as_issue_38_asks"):
+        "asserts the three entries end with the Trinity cell "
+        "(test_granite_cell.py::test_the_three_occupancy_entries "
+        "makes the checks with the new cell appended)",
+    ("test_trinity_cell.py", "test_the_mellum2_cell_and_what_it_reports"):
+        "asserts mellum2_flash_roofline and the generic readers end with "
+        "the Trinity cell (test_granite_cell.py::"
+        "test_the_mellum2_cell_and_what_it_reports makes the checks with "
+        "the new cell appended)",
+    ("test_trinity_cell.py", "test_the_lfm2_cell_reports_what_it_did"):
+        "asserts the generic readers list no cell after Trinity's "
+        "(test_granite_cell.py::test_the_lfm2_cell_reports_what_it_did "
+        "makes the checks with the new cell appended)",
+    ("test_trinity_cell.py", "test_benchmark_json_is_still_well_formed"):
+        "asserts six cells, six configurations and the per-layer list's "
+        "end as the sixth cell left them (test_granite_cell.py::"
+        "test_benchmark_json_is_still_well_formed makes the checks over "
+        "seven, with the granite cell's four entries at the end)",
 }
 
 

@@ -43,6 +43,10 @@ TINY = {
     "swa_wide": {"batch": 1, "length": 96, "heads": 4, "kv_heads": 1,
                  "head_dim": 16, "window": 40},
     "grouped_wide": {"rows": 64, "groups": 16, "k": 8, "n": 16},
+    "ssd": {"batch": 2, "length": 40, "heads": 4, "head_dim": 8,
+            "state": 16, "groups": 1, "chunk": 16},
+    "gqa_scaled": {"batch": 1, "length": 48, "heads": 4, "kv_heads": 2,
+                   "head_dim": 16, "multiplier": 1 / 16},
 }
 
 
@@ -104,6 +108,15 @@ def test_kernels_leg():
             wide["kv_heads"], wide["head_dim"]) == (16384, 2048, 32, 4, 128)
     assert chip_smoke.FULL["grouped_wide"] == {
         "rows": 262144, "groups": 128, "k": 1024, "n": 2048}
+    # Granite-4.0-H-Micro's scan against the recurrence, and its flash
+    # call with the scale folded into q
+    assert max(facts["ssd_rel_l2_vs_recurrence"]) < chip_smoke.BF16_REL_TOL
+    assert facts["gqa_scaled_rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
+    assert chip_smoke.FULL["ssd"] == {
+        "batch": 8, "length": 1024, "heads": 64, "head_dim": 64,
+        "state": 128, "groups": 1, "chunk": 256}
+    scaled = chip_smoke.FULL["gqa_scaled"]
+    assert scaled["multiplier"] * scaled["head_dim"] ** 0.5 == 0.125
 
 
 def test_gbdt_and_fused_pipeline():

@@ -37,7 +37,8 @@ def tiny():
 def test_registry_builds_the_family_with_its_new_keys():
     from mmlspark_tpu.models.hybrid_moe_lm import OPERATORS, HybridMoELM
     from mmlspark_tpu.models.networks import build_network
-    assert OPERATORS == ("conv", "full_attention", "sliding_attention")
+    assert OPERATORS == ("conv", "full_attention", "sliding_attention",
+                         "mamba")                 # the fourth: Mamba-2
     module = build_network({"dtype": "bfloat16", **TINY})
     assert isinstance(module, HybridMoELM)
     cfg = module.cfg
