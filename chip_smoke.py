@@ -370,6 +370,9 @@ def leg_kernels(cfg: dict) -> dict:
     gm_err = _grouped_gap(cfg["grouped"], keys[3:5])
     narrow = [_grouped_gap(m, keys[3:5]) for m in cfg["grouped_narrow"]]
     fused = [_fused_swiglu_gap(m, keys[3:5]) for m in cfg["fused_swiglu"]]
+    ssd = _ssd_gap(cfg["ssd"])
+    for what in ("vs_recurrence", "kernel_vs_jnp"):
+        assert max(ssd[what]) < BF16_REL_TOL, f"ssd_scan {what}: {ssd[what]}"
     m = cfg["grouped"]
     return {"gqa_rel_l2_vs_f32": gqa_err, "grouped_rel_l2": gm_err,
             "grouped_tiles_k_n": [_tile(m["k"], 1024), _tile(m["n"], 1024)],
@@ -389,19 +392,25 @@ def leg_kernels(cfg: dict) -> dict:
             "combine_rel_l2_vs_scatter_add": _combine_gap(cfg["combine"]),
             "layer_down_rel_l2": [_layer_down_gap(m)
                                   for m in cfg["layer_down"]],
-            # [y, the final states] against the recurrence
-            "ssd_rel_l2_vs_recurrence": _ssd_gap(cfg["ssd"]),
+            # [y, the final states] of ``ssd_scan`` against the
+            # recurrence, and of the kernel against the ``jax.numpy``
+            # steps (0 where the kernel does not run: off a TPU)
+            "ssd_rel_l2_vs_recurrence": ssd["vs_recurrence"],
+            "ssd_kernel_rel_l2_vs_jnp": ssd["kernel_vs_jnp"],
+            "ssd_kernel_runs": ssd["kernel_runs"],
             "gqa_scaled_rel_l2_vs_f32": _scaled_gap(cfg["gqa_scaled"])}
 
 
-def _ssd_gap(g: dict) -> list:
+def _ssd_gap(g: dict) -> dict:
     """``ssd_scan`` as the model calls it (x, B and C in bfloat16, the
-    step and the decays float32, the D skip) against the recurrence in
-    float32 at highest precision: relative L2 of y and of the final
+    step and the decays float32, the D skip; on a TPU the kernel)
+    against the recurrence in float32 at highest precision, and against
+    its own ``jax.numpy`` steps: relative L2 of y and of the final
     states."""
     import jax
     import jax.numpy as jnp
-    from mmlspark_tpu.ops.ssd_scan import recurrence, ssd_scan
+    from mmlspark_tpu.ops.ssd_scan import (
+        _chunked, kernel_runs, recurrence, ssd_scan)
     keys = jax.random.split(jax.random.PRNGKey(4), 6)
     shape = (g["batch"], g["length"])
     x = jax.random.normal(keys[0], shape + (g["heads"], g["head_dim"]),
@@ -415,10 +424,16 @@ def _ssd_gap(g: dict) -> list:
     d = jnp.ones((g["heads"],))
     y, final = jax.jit(lambda *v: ssd_scan(*v, g["chunk"], d))(
         x, dt, a, b, c)
+    steps = jax.jit(lambda *v: _chunked(*v, g["chunk"], d))(x, dt, a, b, c)
     with jax.default_matmul_precision("highest"):
-        want, state = jax.jit(lambda *v: recurrence(*v, d))(x, dt, a, b, c)
-    return [float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
-            for got, ref in ((y, want), (final, state))]
+        want = jax.jit(lambda *v: recurrence(*v, d))(x, dt, a, b, c)
+
+    def gaps(refs):
+        return [float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+                for got, ref in zip((y, final), refs)]
+    return {"vs_recurrence": gaps(want), "kernel_vs_jnp": gaps(steps),
+            "kernel_runs": kernel_runs(g["heads"], g["head_dim"],
+                                       g["state"], g["groups"], g["chunk"])}
 
 
 def _scaled_gap(g: dict) -> float:

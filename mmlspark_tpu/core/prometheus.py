@@ -303,6 +303,11 @@ def pipeline_families(r: PromRenderer, pipeline: Any,
                         "tails over the state-space layers: what a "
                         "decode step carries", m["ssm_state_bytes"],
                         labels)
+                r.gauge("serving_model_ssm_scan_kernel_layers",
+                        "state-space layers whose chunked scan runs as "
+                        "one Pallas kernel, the states kept in VMEM: "
+                        "on a TPU, at shapes it takes",
+                        m["ssm_scan_kernel_layers"], labels)
         except Exception:  # noqa: BLE001 — stats stay partial
             pass
     monitor = getattr(pipeline, "drift_monitor", None)

@@ -362,17 +362,20 @@ class Mamba2Mixer(nn.Module):
         Op = W_out RMSNorm(y * silu(z))     a norm over each group's channels
 
     by the chunked scan of ops/ssd_scan.py at ``mamba_chunk_size``.
-    Scopes: ``ssm_mixer`` around it all, ``ssm_conv``, ``ssm_scan`` and
-    ``ssm_gated_norm`` inside, the two projections under ``ssm_mixer``
-    alone. The conv, the step, the decays, the scan's states and sums
-    and the gated norm are float32; the scan's products take ``dtype``
-    operands."""
+    Scopes: ``ssm_mixer`` around it all, ``ssm_conv`` (the conv, its
+    silu and the cast of xBC to ``dtype``), ``ssm_scan`` (the split of
+    xBC, the step, the scan) and ``ssm_gated_norm`` inside, the two
+    projections under ``ssm_mixer`` alone. The conv, the step, the
+    decays, the scan's states and sums and the gated norm are float32;
+    the scan's products take ``dtype`` operands. Under a jit over
+    several devices the scan's kernel runs per shard."""
 
     cfg: Any
 
     @nn.compact
     def __call__(self, u):
-        from mmlspark_tpu.ops.ssd_scan import ssd_scan
+        from mmlspark_tpu.ops.ssd_scan import kernel_runs, ssd_scan
+        from mmlspark_tpu.parallel.ring_attention import flash_per_shard
         c = self.cfg
         dim, dt = c.hidden_size, c.dtype
         heads, width, state, groups = (c.mamba_n_heads, c.mamba_d_head,
@@ -395,17 +398,32 @@ class Mamba2Mixer(nn.Module):
             zxd = _mm("bld,de->ble", u, w_in)
             z, xbc, step = jnp.split(zxd, [inner, inner + channels], -1)
             with jax.named_scope("ssm_conv"):
+                # x, B and C enter the scan in ``dtype``: cast here, whole,
+                # so that the conv's one pass ends in a step of this scope
                 xbc = jax.nn.silu(short_conv(xbc, taps)
-                                  + conv_bias.astype(_F32))
-            x, bb, cc = jnp.split(xbc, [inner, inner + groups * state], -1)
+                                  + conv_bias.astype(_F32)).astype(dt)
             with jax.named_scope("ssm_scan"):
+                # the split's slices are copies the kernel reads: the
+                # scan's own
+                x, bb, cc = jnp.split(xbc, [inner, inner + groups * state],
+                                      -1)
                 step = jax.nn.softplus(step + dt_bias.astype(_F32))
-                y, _ = ssd_scan(
-                    x.reshape(b, length, heads, width).astype(dt), step,
-                    -jnp.exp(a_log.astype(_F32)),
-                    bb.reshape(b, length, groups, state).astype(dt),
-                    cc.reshape(b, length, groups, state).astype(dt),
-                    c.mamba_chunk_size, skip)
+                operands = (x.reshape(b, length, heads, width), step,
+                            -jnp.exp(a_log.astype(_F32)),
+                            bb.reshape(b, length, groups, state),
+                            cc.reshape(b, length, groups, state), skip)
+
+                def scan(x, step, a, bb, cc, skip):
+                    return ssd_scan(x, step, a, bb, cc, c.mamba_chunk_size,
+                                    skip)
+                mesh = jax.sharding.get_abstract_mesh()
+                if mesh.size > 1 and not mesh.manual_axes and kernel_runs(
+                        heads, width, state, groups, c.mamba_chunk_size):
+                    # rows over the data axis, A and D whole
+                    y, _ = flash_per_shard(scan, mesh, *operands,
+                                           whole=(2, 5))
+                else:
+                    y, _ = scan(*operands)
             with jax.named_scope("ssm_gated_norm"):
                 g = (y.reshape(b, length, inner) * jax.nn.silu(z)).reshape(
                     b, length, groups, inner // groups)
@@ -576,6 +594,19 @@ class HybridMoELM(nn.Module):
         c = self.cfg
         return -(-c.max_len // c.mamba_chunk_size) if self.ssm_layers \
             else 0
+
+    # Mamba-2 layers whose scan runs as one Pallas kernel (ops/ssd_scan.py
+    # ``kernel_runs``: on a TPU, at shapes it takes; ``TPUModel.metrics()``
+    # carries it): all of them or none
+    @property
+    def ssm_scan_kernel_layers(self) -> int:
+        from mmlspark_tpu.ops.ssd_scan import kernel_runs
+        c = self.cfg
+        if not self.ssm_layers or not kernel_runs(
+                c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+                c.mamba_n_groups, c.mamba_chunk_size):
+            return 0
+        return self.ssm_layers
 
     @property
     def ssm_state_bytes(self) -> int:

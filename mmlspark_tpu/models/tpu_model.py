@@ -582,9 +582,11 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                      "moe_shared_experts"):
             out[name] = int(getattr(module, name, 0))
         # Mamba-2 layers of the module, the chunks of their scan at its
-        # longest row, and the bytes of a row's final scan states and
-        # conv tails over them (hybrid_moe_lm); 0 for a module without
-        for name in ("ssm_layers", "ssm_chunks", "ssm_state_bytes"):
+        # longest row, the bytes of a row's final scan states and conv
+        # tails over them, and the layers whose scan runs as one kernel
+        # (hybrid_moe_lm); 0 for a module without
+        for name in ("ssm_layers", "ssm_chunks", "ssm_state_bytes",
+                     "ssm_scan_kernel_layers"):
             out[name] = int(getattr(module, name, 0))
         out["precision"] = self.get("precision")
         out["aot"] = bool(self.aot)

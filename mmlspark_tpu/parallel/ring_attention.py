@@ -163,16 +163,20 @@ def selected_attention(q, k, v, keep, causal: bool = True) -> jnp.ndarray:
     return dense_selected_attention(q, k, v, keep)
 
 
-def flash_per_shard(flash, mesh, *operands):
+def flash_per_shard(flash, mesh, *operands, whole=()):
     """XLA cannot partition a Mosaic kernel: inside a jit over several
     devices the call must sit in a shard_map or it does not lower. The
     caller says which devices by tracing under ``jax.set_mesh`` (the
     learner and TPUModel do); the batch splits over the data axis when
     it divides and everything else is replicated — the layout both of
-    them feed."""
+    them feed. The operands at the positions ``whole`` have no batch
+    axis (a kernel's per-head weights) and are replicated; every
+    output leads with the batch."""
     n = mesh.shape.get(DATA_AXIS, 1)
     spec = P(DATA_AXIS) if n > 1 and operands[0].shape[0] % n == 0 else P()
-    return shard_map(flash, mesh=mesh, in_specs=(spec,) * len(operands),
+    in_specs = tuple(P() if i in whole else spec
+                     for i in range(len(operands)))
+    return shard_map(flash, mesh=mesh, in_specs=in_specs,
                      out_specs=spec, check_vma=False)(*operands)
 
 

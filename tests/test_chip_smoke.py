@@ -111,6 +111,9 @@ def test_kernels_leg():
     # Granite-4.0-H-Micro's scan against the recurrence, and its flash
     # call with the scale folded into q
     assert max(facts["ssd_rel_l2_vs_recurrence"]) < chip_smoke.BF16_REL_TOL
+    # off a TPU ``ssd_scan`` is its own ``jax.numpy`` steps
+    assert facts["ssd_kernel_rel_l2_vs_jnp"] == [0.0, 0.0]
+    assert facts["ssd_kernel_runs"] is False
     assert facts["gqa_scaled_rel_l2_vs_f32"] < chip_smoke.BF16_REL_TOL
     assert chip_smoke.FULL["ssd"] == {
         "batch": 8, "length": 1024, "heads": 64, "head_dim": 64,
